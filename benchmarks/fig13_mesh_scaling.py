@@ -22,7 +22,7 @@ Sections (all modeled — this container has no multi-host TPU):
       on the survivors (serve/engine._reshard_after_failure) and the
       preempted slots' private pages are refilled by swap-in or
       recompute; we model the swap-in path as moving those pages over
-      ICI (bytes / ICI_BW) per storage mode.
+      ICI (bytes / V5E_PEAKS.ici_bw) per storage mode.
 
 Both smoke and full runs refresh the top-level BENCH_mesh.json artifact
 (the acceptance criterion is that it records the modeled curve).
@@ -34,8 +34,10 @@ import json
 import os
 
 from benchmarks.common import markdown_table, save_result
-from repro.launch.mesh import HBM_BYTES, ICI_BW
+from repro.launch.mesh import V5E, chip_peaks
 from repro.launch.roofline import kv_page_bytes, sharded_pool_slots
+
+V5E_PEAKS = chip_peaks(V5E)     # the chip these rooflines model
 
 # qwen3-14b serving geometry (matches fig6/fig9/fig11)
 LAYERS, HKV, N_REP, DH = 40, 8, 5, 128
@@ -58,7 +60,7 @@ def modeled_curve() -> dict:
             row = {"kv_quant": mode, "ctx": ctx}
             for n in HOSTS:
                 cap = sharded_pool_slots(
-                    n, HBM_BYTES, WEIGHT_BYTES, LAYERS, HKV, BK, DH,
+                    n, V5E_PEAKS.hbm_bytes, WEIGHT_BYTES, LAYERS, HKV, BK, DH,
                     pages_per_slot=ctx // BK, kv_quant=mode, sla2=True)
                 row[f"slots_h{n}"] = cap["slots"]
                 if n == 1:
@@ -76,14 +78,15 @@ def modeled_reshard() -> dict:
         page_b = LAYERS * kv_page_bytes(HKV, BK, DH, mode, sla2=True)
         for n in (4, 8, 16):
             cap = sharded_pool_slots(
-                n, HBM_BYTES, WEIGHT_BYTES, LAYERS, HKV, BK, DH,
+                n, V5E_PEAKS.hbm_bytes, WEIGHT_BYTES, LAYERS, HKV, BK, DH,
                 pages_per_slot=1, kv_quant=mode, sla2=True)
             lost_pages = cap["pages_per_host"]
             rows.append({
                 "kv_quant": mode, "hosts": n,
                 "lost_pages": lost_pages,
                 "lost_gib": round(lost_pages * page_b / 2 ** 30, 2),
-                "swap_in_ms": round(lost_pages * page_b / ICI_BW * 1e3, 1),
+                "swap_in_ms": round(
+                    lost_pages * page_b / V5E_PEAKS.ici_bw * 1e3, 1),
             })
     return {"rows": rows}
 
@@ -100,7 +103,7 @@ def run(smoke: bool = False) -> dict:
     payload = {
         "geometry": {"layers": LAYERS, "hkv": HKV, "n_rep": N_REP,
                      "dh": DH, "page_tokens": BK,
-                     "hbm_per_host_gib": HBM_BYTES / 2 ** 30,
+                     "hbm_per_host_gib": V5E_PEAKS.hbm_bytes / 2 ** 30,
                      "weight_replica_gib": round(WEIGHT_BYTES / 2 ** 30, 2)},
         "hosts": list(HOSTS),
         "modeled_slots_vs_hosts": curve,

@@ -28,7 +28,9 @@ from repro.core import sla2 as sla2lib
 from repro.core.attention import full_attention
 from repro.core.router import RouterConfig
 from repro.core.sla2 import SLA2Config
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16, PEAK_FLOPS_INT8
+from repro.launch.mesh import V5E, chip_peaks
+
+V5E_PEAKS = chip_peaks(V5E)     # the chip these rooflines model
 
 N_MODEL, D = 32768, 128
 BQ, BK = 128, 64
@@ -38,21 +40,21 @@ def modeled_time(n: int, d: int, *, sparsity: float | None, quant: bool,
                  linear: bool) -> float:
     """Roofline time (s) of one attention head forward on one v5e chip."""
     def t_of(flops, bytes_, peak):
-        return max(flops / peak, bytes_ / HBM_BW)
+        return max(flops / peak, bytes_ / V5E_PEAKS.hbm_bw)
 
     if sparsity is None:  # dense FlashAttention
         flops = 4.0 * n * n * d
         bytes_ = 3 * n * d * 2 + n * d * 2         # q,k,v in + o out (bf16)
-        return t_of(flops, bytes_, PEAK_FLOPS_BF16)
+        return t_of(flops, bytes_, V5E_PEAKS.flops_bf16)
     keep = 1.0 - sparsity
-    peak = PEAK_FLOPS_INT8 if quant else PEAK_FLOPS_BF16
+    peak = V5E_PEAKS.flops_int8 if quant else V5E_PEAKS.flops_bf16
     t = t_of(keep * 4.0 * n * n * d,
              (2 + keep) * n * d * 2 + n * d * 2, peak)  # kv tiles ~ keep
     # router: pooled scores + topk
     t += t_of(2.0 * (n / BQ) * (n / BK) * d, 2 * (n / BQ + n / BK) * d * 4,
-              PEAK_FLOPS_BF16)
+              V5E_PEAKS.flops_bf16)
     if linear:
-        t += t_of(6.0 * n * d * d, 4 * n * d * 2, PEAK_FLOPS_BF16)
+        t += t_of(6.0 * n * d * d, 4 * n * d * 2, V5E_PEAKS.flops_bf16)
     return t
 
 

@@ -19,7 +19,9 @@ import time
 
 from benchmarks.common import markdown_table, save_result
 from benchmarks.fig4_kernel_speed import modeled_time
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
+
+V5E_PEAKS = chip_peaks(V5E)     # the chip these rooflines model
 
 MODELS = {
     # name: (N tokens, d_model, heads, head_dim, d_ff, layers, steps)
@@ -35,7 +37,7 @@ def rest_time(n, d_model, d_ff, layers) -> float:
                           + 2 * 2 * d_model * d_ff       # ffn
                           + 2 * 4 * d_model * d_model)   # cross-attn proj
     bytes_ = layers * n * d_model * 2 * 12
-    return max(flops / PEAK_FLOPS_BF16, bytes_ / HBM_BW)
+    return max(flops / V5E_PEAKS.flops_bf16, bytes_ / V5E_PEAKS.hbm_bw)
 
 
 def serve_throughput(arch: str = "qwen3_14b", seed: int = 0) -> dict:

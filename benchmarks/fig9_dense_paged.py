@@ -43,7 +43,9 @@ import time
 import numpy as np
 
 from benchmarks.common import markdown_table, save_result
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
+
+V5E_PEAKS = chip_peaks(V5E)     # the chip these rooflines model
 
 # qwen3-14b serving geometry (dense attention)
 LAYERS, HKV, N_REP, DH = 40, 8, 5, 128
@@ -83,7 +85,7 @@ def modeled_step(batch: int, ctx: int, method: str,
         bytes_ = 2 * full_bytes + page_bytes    # copy write + re-read + use
     else:
         raise ValueError(method)
-    t = max(flops / PEAK_FLOPS_BF16, bytes_ / HBM_BW)
+    t = max(flops / V5E_PEAKS.flops_bf16, bytes_ / V5E_PEAKS.hbm_bw)
     return LAYERS * t
 
 

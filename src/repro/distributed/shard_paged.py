@@ -28,6 +28,19 @@ tests/test_mesh_serving.py).  A production kernel would DMA only the
 pages the slot's table references; the roofline treats the pool bytes as
 HBM-local either way (benchmarks/fig13_mesh_scaling.py).
 
+Only the kernel itself runs split.  Its other operands and its output,
+and in the model the residual stream, the rope positions and q/k/v, are
+constrained whole on every device (``replicate``).  Without that GSPMD
+carries the split into the XLA code around the kernel: after the
+head-split prefill the output projection sums bf16 partial products in
+an all-reduce, and the decode projections run on one slot per device as
+multiply + reduce instead of a matmul.  Either changes the rounding, and
+at bf16 on a TPU that flips greedy tokens
+(tests/test_tpu_compile.py checks the compiled matmul sizes).  At bf16,
+identity also needs ``--xla_allow_excess_precision=false`` in
+``XLA_FLAGS``: otherwise XLA may skip bf16 roundings inside a fusion,
+and the two programs fuse differently.
+
 Wrappers gate on divisibility at call time: when the sharded axis does
 not divide the mesh size (e.g. 2 kv heads on a 4-device mesh) the bare
 entry runs instead and GSPMD alone places the computation — same math,
@@ -35,9 +48,9 @@ same tokens, just without the explicit per-device kernel dispatch.
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # fused entry name -> which axis its wrapper shards across the mesh.
 # tools/gen_path_matrix.py probes this table for the docs/paths.md shard
@@ -59,6 +72,15 @@ def mesh_size(mesh: Mesh) -> int:
 def _all_axes(mesh: Mesh):
     names = tuple(mesh.axis_names)
     return names if len(names) > 1 else names[0]
+
+
+def replicate(x, mesh: Mesh | None):
+    """``x`` constrained whole on every device of ``mesh``; unchanged
+    without a mesh.  The serving layers call this on the residual stream
+    and the q/k/v projections so the split stays inside the kernels."""
+    if mesh is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P()))
 
 
 def _wrap_slots(fn, mesh: Mesh):
@@ -88,9 +110,11 @@ def _wrap_slots(fn, mesh: Mesh):
 
         slot = P(ax)
         in_specs = (slot, P(), P()) + (slot,) * nrest + (P(),) * len(scales)
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=slot,
-                       check_rep=False)
-        return sm(q, k_pages, v_pages, *rest, *scales)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=slot, check_vma=False)
+        rest = [replicate(r, mesh) for r in rest]
+        out = sm(replicate(q, mesh), k_pages, v_pages, *rest, *scales)
+        return replicate(out, mesh)
     return wrapped
 
 
@@ -124,9 +148,11 @@ def _wrap_prefill(fn, mesh: Mesh):
         pool = P(None, ax, None, None)
         in_specs = (heads, pool, pool, P(), P()) \
             + (P(None, ax, None),) * len(scales)
-        sm = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=heads,
-                       check_rep=False)
-        return sm(q, k_pages, v_pages, page_row, offset, *scales)
+        sm = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=heads, check_vma=False)
+        out = sm(replicate(q, mesh), k_pages, v_pages, page_row, offset,
+                 *scales)
+        return replicate(out, mesh)
     return wrapped
 
 

@@ -116,6 +116,41 @@ def dequant_rows(codes, scale):
     return codes.astype(jnp.float32) * scale[..., None].astype(jnp.float32)
 
 
+# ---------------------------------------------------------------------------
+# Mosaic layout rules shared by the kernel wrappers
+# ---------------------------------------------------------------------------
+# Scalar-prefetch operands live in SMEM (1 MiB per core on v5e), where
+# Mosaic pads the last dim of a multi-dimensional array to 128 words.  The
+# kernels therefore prefetch flat 1-D tables.  The sparse-branch forward
+# and backward tables grow with heads x sequence; where one outgrows this
+# budget, the wrapper splits its leading (B*H) axis over several calls.
+SMEM_PREFETCH_BYTES = 512 * 1024
+# Row statistics (running max / sum) are kept lane-replicated in 2-D
+# (rows, STAT_LANES) VMEM scratch, the layout Mosaic tiles natively.
+STAT_LANES = 128
+
+
+def pack_selection(idx, valid):
+    """Fold routed block ids and their {0,1} validity flags into one flat
+    int32 table (``2 * idx + valid``): one SMEM word per routed entry."""
+    return (idx.astype(jnp.int32) * 2 + valid.astype(jnp.int32)).reshape(-1)
+
+
+def row_groups(rows: int, words_per_row: int) -> list[tuple[int, int]]:
+    """Split ``rows`` into equal ``(start, size)`` groups, as few as the
+    scalar-prefetch budget allows: each group's tables
+    (``words_per_row`` int32 words per row) fit SMEM_PREFETCH_BYTES."""
+    cap = max(1, SMEM_PREFETCH_BYTES // (4 * words_per_row))
+    g = max(d for d in range(1, min(rows, cap) + 1) if rows % d == 0)
+    return [(s, g) for s in range(0, rows, g)]
+
+
+def stat_col(row):
+    """A lane-dense ``(1, n)`` row of per-row statistics as the ``(n, 1)``
+    column that broadcasts against an ``(n, x)`` tile."""
+    return row.reshape(row.shape[-1], 1)
+
+
 def default_interpret(interpret: bool | None = None) -> bool:
     """Resolve a kernel's ``interpret`` argument: every Pallas entry point
     falls back to interpret mode off-TPU (CPU CI, tests, smoke benches) and

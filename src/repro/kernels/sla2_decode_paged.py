@@ -86,16 +86,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import (INT8_MAX, NEG_INF, default_interpret,
-                               qdot as _qdot, quantize_tile as _quantize_tile)
+from repro.kernels.ops import (INT8_MAX, NEG_INF, STAT_LANES,
+                               default_interpret, qdot as _qdot,
+                               quantize_tile as _quantize_tile, stat_col)
 
 
 # ---------------------------------------------------------------------------
 # Fused decode: sparse flash + linear complement correction + alpha combine
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
-                   kv_quant: str, sm_scale: float):
+def _decode_kernel(*refs, block_k: int, wdw: int, k_sel: int, hkv: int,
+                   quant_bits: str, kv_quant: str, sm_scale: float):
     """Shared decode/verify kernel body over grid ``(B*Hkv, W, K_sel)``.
 
     ``W`` is the query-window axis: single-token decode runs it at 1, the
@@ -105,19 +106,24 @@ def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
     the intra-window causal mask — window token w+1 sits at position t_w and
     is invisible to row w's queries.
 
+    The scalar-prefetch tables are flat (one SMEM word per routed entry):
+    ``phys`` holds the routed physical page ids and ``sel`` packs the
+    logical block id with the valid / complete flags
+    (``(jlog * 2 + valid) * 2 + complete``).
+
     With ``kv_quant != 'none'`` the K/V pool holds low-bit codes and two
     extra operands carry the per-row scales, prefetched by the SAME routed
     physical page id as the K/V tiles; the tiles are dequantized in
     registers (codes * scale, ops.dequant_rows' formula) before the MXU
     dots."""
     if kv_quant == "none":
-        (phys_ref, jlog_ref, valid_ref, comp_ref, tnew_ref,     # SMEM
+        (phys_ref, sel_ref, tnew_ref,                           # SMEM
          q_ref, k_ref, v_ref, h_ref, z_ref, a_ref,              # in
          o_ref,                                                 # out
          acc, m_i, l_i, lnum, lden) = refs                      # VMEM
         ks_ref = vs_ref = None
     else:
-        (phys_ref, jlog_ref, valid_ref, comp_ref, tnew_ref,
+        (phys_ref, sel_ref, tnew_ref,
          q_ref, k_ref, v_ref, ks_ref, vs_ref, h_ref, z_ref, a_ref,
          o_ref,
          acc, m_i, l_i, lnum, lden) = refs
@@ -133,9 +139,10 @@ def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
         lnum[...] = jnp.zeros_like(lnum)
         lden[...] = jnp.zeros_like(lden)
 
-    is_valid = valid_ref[g, w, jj] == 1
-    j = jlog_ref[g, w, jj]         # logical block id (for positions)
-    t = tnew_ref[g, w]             # row length incl. this window token
+    sel = sel_ref[(g * wdw + w) * k_sel + jj]
+    is_valid = ((sel >> 1) & 1) == 1
+    j = sel >> 2                   # logical block id (for positions)
+    t = tnew_ref[(g // hkv) * wdw + w]   # row length incl. this token
 
     @pl.when(is_valid)
     def _step():
@@ -144,8 +151,8 @@ def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
         v = v_ref[0, 0].astype(jnp.float32)
         if kv_quant != "none":
             # in-register dequant of the pool codes (per token row)
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
+            k = k * _head_scale(ks_ref, g % hkv)
+            v = v * _head_scale(vs_ref, g % hkv)
         if quant_bits == "none":
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -156,18 +163,18 @@ def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
             s = _qdot(q_c, q_s, k_c, k_s, transpose_b=True) * sm_scale
 
         cols = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)[0]
-        vis = cols < t                           # ragged page tail
-        s = jnp.where(vis[None, :], s, NEG_INF)
+            jnp.int32, (1, block_k), 1)
+        s = jnp.where(cols < t, s, NEG_INF)     # ragged page tail
 
-        m_prev = m_i[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_i[:, :1]                     # (n_rep, 1) of the lanes
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
-        p = jnp.exp(s - m_safe[:, None])
+        p = jnp.exp(s - m_safe)
         p = jnp.where(s > NEG_INF * 0.5, p, 0.0)
         corr = jnp.exp(jnp.where(m_prev > NEG_INF * 0.5, m_prev, m_safe)
                        - m_safe)
-        l_i[...] = l_i[...] * corr + p.sum(axis=-1)
+        l_new = l_i[:, :1] * corr + p.sum(axis=-1, keepdims=True)
+        l_i[...] = jnp.broadcast_to(l_new, l_i.shape)
         if quant_bits == "none":
             o_tmp = jax.lax.dot_general(
                 p, v, (((1,), (0,)), ((), ())),
@@ -180,13 +187,13 @@ def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
             p_c, p_s = _quantize_tile(p, "fp8")
             v_c, v_s = _quantize_tile(v, "fp8")
             o_tmp = _qdot(p_c, p_s, v_c, v_s, transpose_b=False)
-        acc[...] = acc[...] * corr[:, None] + o_tmp
-        m_i[...] = m_new
+        acc[...] = acc[...] * corr + o_tmp
+        m_i[...] = jnp.broadcast_to(m_new, m_i.shape)
 
         # linear-branch correction: this page is a selected COMPLETE block,
         # so its phi(k).v / phi(k) mass must leave the complement totals.
         # The tiles are already resident — no second gather.  fp32 always.
-        @pl.when(comp_ref[g, w, jj] == 1)
+        @pl.when((sel & 1) == 1)
         def _linear_sub():
             qf = jax.nn.softmax(q, axis=-1)      # phi(q), (n_rep, Dh)
             kf = jax.nn.softmax(k, axis=-1)      # phi(k), (bk, Dh)
@@ -196,25 +203,39 @@ def _decode_kernel(*refs, block_k: int, k_sel: int, quant_bits: str,
             lnum[...] += jax.lax.dot_general(
                 ls, v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            lden[...] += ls.sum(axis=-1)
+            lden[...] += ls.sum(axis=-1, keepdims=True)
 
     @pl.when(jj == k_sel - 1)
     def _finalize():
-        l_safe = jnp.maximum(l_i[...], 1e-20)
-        o_s = acc[...] / l_safe[:, None]
+        l_safe = jnp.maximum(l_i[:, :1], 1e-20)
+        o_s = acc[...] / l_safe
         qf = jax.nn.softmax(q_ref[0, 0].astype(jnp.float32), axis=-1)
-        den_tot = (qf * z_ref[0, 0][None, :]).sum(axis=-1)     # (n_rep,)
+        den_tot = (qf * z_ref[0, 0]).sum(axis=-1, keepdims=True)  # (n_rep,1)
         num = jax.lax.dot_general(
             qf, h_ref[0, 0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) - lnum[...]
-        den = den_tot - lden[...]
+        den = den_tot - lden[:, :1]
         # relative empty-complement threshold (cancellation residuals != 0)
         den = jnp.where(den > 1e-4 * den_tot + 1e-12, den, 0.0)
-        o_l = jnp.where(den[:, None] > 0,
-                        num / jnp.maximum(den[:, None], 1e-12), 0.0)
-        a = jax.nn.sigmoid(a_ref[0].astype(jnp.float32))       # (n_rep,)
-        a_eff = jnp.where(den > 0, a, 1.0)[:, None]
+        o_l = jnp.where(den > 0, num / jnp.maximum(den, 1e-12), 0.0)
+        a = jax.nn.sigmoid(stat_col(a_ref[0]).astype(jnp.float32))
+        a_eff = jnp.where(den > 0, a, 1.0)                      # (n_rep, 1)
         o_ref[0, 0] = (a_eff * o_s + (1.0 - a_eff) * o_l).astype(o_ref.dtype)
+
+
+def _head_scale(scale_ref, h):
+    """The per-row scales of kv head ``h`` from a ``(1, Hkv, bk)`` block
+    of the scale pool, as a ``(bk, 1)`` column.  The block spans every kv
+    head because a one-head ``(1, 1, bk)`` block breaks Mosaic's tiling
+    rule on the last two dims."""
+    return stat_col(scale_ref[0, pl.ds(h, 1), :])
+
+
+def _scale_spec(block_k, hkv, page_of):
+    """BlockSpec of a ``(P, Hkv, bk)`` scale pool: the whole page row of
+    the physical page ``page_of(*grid_indices, *prefetch_refs)``."""
+    return pl.BlockSpec((1, hkv, block_k),
+                        lambda *a: (page_of(*a), 0, 0))
 
 
 def _call_decode_kernel(q, k_pages, v_pages, phys, jlog, valid, complete,
@@ -238,63 +259,53 @@ def _call_decode_kernel(q, k_pages, v_pages, phys, jlog, valid, complete,
     g_tot = b * hkv
     sm_scale = 1.0 / (dh ** 0.5)
 
-    flat = lambda x: x.reshape(g_tot, *x.shape[2:])
-    phys_f = flat(phys).astype(jnp.int32)
-    jlog_f = flat(jlog).astype(jnp.int32)
-    valid_f = flat(valid).astype(jnp.int32)
-    comp_f = flat(complete).astype(jnp.int32)
-    tnew_f = jnp.broadcast_to(t_new.astype(jnp.int32)[:, None],
-                              (b, hkv, wdw)).reshape(g_tot, wdw)
-    q_f = flat(q)
-    h_f = flat(h_tot)
-    z_f = flat(z_tot)
-    a_f = flat(alpha)
+    # flat scalar-prefetch tables (SMEM pads a 2-D table's rows to 128
+    # words): routed page ids, and the packed logical id / valid /
+    # complete flags, in (slot, kv head, window row, routed page) order
+    phys_t = phys.reshape(-1).astype(jnp.int32)
+    sel_t = ((jlog.astype(jnp.int32) * 2 + valid.astype(jnp.int32)) * 2
+             + complete.astype(jnp.int32)).reshape(-1)
+    q_f = q.reshape(g_tot, wdw, n_rep, dh)
+    h_f = h_tot.reshape(g_tot, wdw, dh, dh)
+    z_f = z_tot.reshape(g_tot, wdw, 1, dh)          # lane-dense rows
+    a_f = alpha.reshape(g_tot, 1, n_rep)
 
-    grid = (g_tot, wdw, k_sel)
-    kernel = functools.partial(
-        _decode_kernel, block_k=bk, k_sel=k_sel, quant_bits=quant_bits,
-        kv_quant=kv_quant, sm_scale=sm_scale)
-    page_spec = pl.BlockSpec((1, 1, bk, dh),
-                             lambda g, w, jj, ph, jl, va, co, tn:
-                             (ph[g, w, jj], g % hkv, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, bk),
-                              lambda g, w, jj, ph, jl, va, co, tn:
-                              (ph[g, w, jj], g % hkv, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, n_rep, dh),
-                     lambda g, w, jj, ph, jl, va, co, tn: (g, w, 0, 0)),
-        page_spec,      # K pages
-        page_spec,      # V pages
-    ]
+    def page(g, w, jj, ph, se, tn):
+        return ph[(g * wdw + w) * k_sel + jj]
+
+    page_spec = pl.BlockSpec(
+        (1, 1, bk, dh),
+        lambda g, w, jj, ph, se, tn: (page(g, w, jj, ph, se, tn), g % hkv,
+                                      0, 0))
+    row_spec = lambda shape: pl.BlockSpec(
+        (1, 1) + shape, lambda g, w, jj, *_: (g, w, 0, 0))
+    in_specs = [row_spec((n_rep, dh)), page_spec, page_spec]
     operands = [q_f, k_pages, v_pages]
     if kv_quant != "none":
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [_scale_spec(bk, hkv, page)] * 2
         operands += [k_scale, v_scale]
     in_specs += [
-        pl.BlockSpec((1, 1, dh, dh),
-                     lambda g, w, jj, ph, jl, va, co, tn: (g, w, 0, 0)),
-        pl.BlockSpec((1, 1, dh),
-                     lambda g, w, jj, ph, jl, va, co, tn: (g, w, 0)),
-        pl.BlockSpec((1, n_rep),
-                     lambda g, w, jj, ph, jl, va, co, tn: (g, 0)),
+        row_spec((dh, dh)),
+        row_spec((1, dh)),
+        pl.BlockSpec((1, 1, n_rep), lambda g, w, jj, *_: (g, 0, 0)),
     ]
     operands += [h_f, z_f, a_f]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=grid,
+        num_scalar_prefetch=3,
+        grid=(g_tot, wdw, k_sel),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, n_rep, dh),
-                         lambda g, w, jj, ph, jl, va, co, tn: (g, w, 0, 0)),
-        ],
+        out_specs=[row_spec((n_rep, dh))],
         scratch_shapes=[
-            pltpu.VMEM((n_rep, dh), jnp.float32),   # acc
-            pltpu.VMEM((n_rep,), jnp.float32),      # m_i
-            pltpu.VMEM((n_rep,), jnp.float32),      # l_i
-            pltpu.VMEM((n_rep, dh), jnp.float32),   # lnum
-            pltpu.VMEM((n_rep,), jnp.float32),      # lden
+            pltpu.VMEM((n_rep, dh), jnp.float32),           # acc
+            pltpu.VMEM((n_rep, STAT_LANES), jnp.float32),   # m_i
+            pltpu.VMEM((n_rep, STAT_LANES), jnp.float32),   # l_i
+            pltpu.VMEM((n_rep, dh), jnp.float32),           # lnum
+            pltpu.VMEM((n_rep, STAT_LANES), jnp.float32),   # lden
         ],
     )
+    kernel = functools.partial(
+        _decode_kernel, block_k=bk, wdw=wdw, k_sel=k_sel, hkv=hkv,
+        quant_bits=quant_bits, kv_quant=kv_quant, sm_scale=sm_scale)
     (o,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -302,7 +313,7 @@ def _call_decode_kernel(q, k_pages, v_pages, phys, jlog, valid, complete,
                                         jnp.float32)],
         interpret=interpret,
         name=f"sla2_decode_paged_{quant_bits}_kv_{kv_quant}",
-    )(phys_f, jlog_f, valid_f, comp_f, tnew_f, *operands)
+    )(phys_t, sel_t, t_new.astype(jnp.int32).reshape(-1), *operands)
     return o.reshape(b, hkv, wdw, n_rep, dh)
 
 
@@ -390,8 +401,8 @@ def sla2_decode_verify(q, k_pages, v_pages, phys, jlog, valid, complete,
 # sla / sparse_only baselines): online softmax over the page-table pages
 # ---------------------------------------------------------------------------
 
-def _dense_decode_kernel(*refs, block_k: int, max_p: int, hkv: int,
-                         window, prefix_len: int, quant_bits: str,
+def _dense_decode_kernel(*refs, block_k: int, wdw: int, max_p: int,
+                         hkv: int, window, prefix_len: int, quant_bits: str,
                          kv_quant: str, sm_scale: float):
     """Dense decode/verify kernel body over grid ``(B*Hkv, W, maxP)``.
 
@@ -399,9 +410,10 @@ def _dense_decode_kernel(*refs, block_k: int, max_p: int, hkv: int,
     slot streams through the online softmax.  Pages with no position
     visible to a row (beyond its length — or, with a sliding window,
     wholly below its window start) are masked to the TRASH page in
-    ``phys`` by the caller and flagged invalid: the repeated trash index
-    collapses to one resident block (no per-page DMA) and ``valid`` skips
-    their compute.  The per-row position mask ``cols < t`` doubles as the
+    ``phys`` by the caller and flagged invisible (``phys * 2 + visible``
+    per flat prefetch word): the repeated trash index collapses to one
+    resident block (no per-page DMA) and the flag skips their compute.
+    The per-row position mask ``cols < t`` doubles as the
     causal intra-window mask exactly as in the SLA2 verify grid;
     ``window``/``prefix_len`` fold the sliding-window and prefix-LM
     constraints into the same in-register mask.
@@ -412,13 +424,13 @@ def _dense_decode_kernel(*refs, block_k: int, max_p: int, hkv: int,
     low-bit pool codes in registers via the per-row scales prefetched
     through the same physical page id as K/V."""
     if kv_quant == "none":
-        (phys_ref, valid_ref, tnew_ref,                        # SMEM
+        (phys_ref, tnew_ref,                                   # SMEM
          q_ref, k_ref, v_ref,                                  # in
          o_ref,                                                # out
          acc, m_i, l_i) = refs                                 # VMEM
         ks_ref = vs_ref = None
     else:
-        (phys_ref, valid_ref, tnew_ref,
+        (phys_ref, tnew_ref,
          q_ref, k_ref, v_ref, ks_ref, vs_ref,
          o_ref,
          acc, m_i, l_i) = refs
@@ -433,17 +445,18 @@ def _dense_decode_kernel(*refs, block_k: int, max_p: int, hkv: int,
         m_i[...] = jnp.full_like(m_i, NEG_INF)
         l_i[...] = jnp.zeros_like(l_i)
 
-    t = tnew_ref[b, w]             # row length incl. this window token
+    t = tnew_ref[b * wdw + w]      # row length incl. this window token
 
-    @pl.when(valid_ref[b, w, p] == 1)
+    # phys packs the page id with its visibility flag (phys * 2 + valid)
+    @pl.when((phys_ref[(b * wdw + w) * max_p + p] & 1) == 1)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)     # (n_rep, Dh)
         k = k_ref[0, 0].astype(jnp.float32)     # (bk, Dh)
         v = v_ref[0, 0].astype(jnp.float32)
         if kv_quant != "none":
             # in-register dequant of the pool codes (per token row)
-            k = k * ks_ref[0, 0][:, None]
-            v = v * vs_ref[0, 0][:, None]
+            k = k * _head_scale(ks_ref, g % hkv)
+            v = v * _head_scale(vs_ref, g % hkv)
         if quant_bits == "none":
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
@@ -454,23 +467,24 @@ def _dense_decode_kernel(*refs, block_k: int, max_p: int, hkv: int,
             s = _qdot(q_c, q_s, k_c, k_s, transpose_b=True) * sm_scale
 
         cols = p * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)[0]
+            jnp.int32, (1, block_k), 1)
         vis = cols < t
         if window is not None:
             sw = cols >= t - window
             if prefix_len:
                 sw = jnp.logical_or(sw, cols < prefix_len)
             vis = jnp.logical_and(vis, sw)
-        s = jnp.where(vis[None, :], s, NEG_INF)
+        s = jnp.where(vis, s, NEG_INF)
 
-        m_prev = m_i[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_i[:, :1]                     # (n_rep, 1) of the lanes
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
-        pr = jnp.exp(s - m_safe[:, None])
+        pr = jnp.exp(s - m_safe)
         pr = jnp.where(s > NEG_INF * 0.5, pr, 0.0)
         corr = jnp.exp(jnp.where(m_prev > NEG_INF * 0.5, m_prev, m_safe)
                        - m_safe)
-        l_i[...] = l_i[...] * corr + pr.sum(axis=-1)
+        l_new = l_i[:, :1] * corr + pr.sum(axis=-1, keepdims=True)
+        l_i[...] = jnp.broadcast_to(l_new, l_i.shape)
         if quant_bits == "none":
             o_tmp = jax.lax.dot_general(
                 pr, v, (((1,), (0,)), ((), ())),
@@ -483,13 +497,13 @@ def _dense_decode_kernel(*refs, block_k: int, max_p: int, hkv: int,
             p_c, p_s = _quantize_tile(pr, "fp8")
             v_c, v_s = _quantize_tile(v, "fp8")
             o_tmp = _qdot(p_c, p_s, v_c, v_s, transpose_b=False)
-        acc[...] = acc[...] * corr[:, None] + o_tmp
-        m_i[...] = m_new
+        acc[...] = acc[...] * corr + o_tmp
+        m_i[...] = jnp.broadcast_to(m_new, m_i.shape)
 
     @pl.when(p == max_p - 1)
     def _finalize():
-        l_safe = jnp.maximum(l_i[...], 1e-20)
-        o_ref[0, 0] = (acc[...] / l_safe[:, None]).astype(o_ref.dtype)
+        l_safe = jnp.maximum(l_i[:, :1], 1e-20)
+        o_ref[0, 0] = (acc[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -527,7 +541,7 @@ def dense_decode_verify(q, k_pages, v_pages, page_table, t_new, *,
     window start) are masked to the trash page in the per-row ``phys``
     prefetch operand — the repeated index elides their DMA, so a
     sliding-window layer's page traffic scales with the window, not the
-    context — and their compute is skipped via the ``valid`` flags."""
+    context — and their compute is skipped via the visibility flags."""
     interpret = default_interpret(interpret)
     b, hkv, wdw, n_rep, dh = q.shape
     max_p = page_table.shape[1]
@@ -543,48 +557,40 @@ def dense_decode_verify(q, k_pages, v_pages, page_table, t_new, *,
         if prefix_len:
             w_ok = w_ok | (pages[None, None, :] * bk < prefix_len)
         vis_any = vis_any & w_ok
-    valid = vis_any.astype(jnp.int32)
     # per-row physical ids with invisible pages pointed at the trash page:
-    # masking the TABLE (not just the compute) is what saves the traffic
-    phys = jnp.where(vis_any,
-                     page_table.astype(jnp.int32)[:, None, :], 0)
+    # masking the TABLE (not just the compute) is what saves the traffic.
+    # One flat SMEM word per (slot, row, page): phys * 2 + visible.
+    phys = jnp.where(vis_any, page_table.astype(jnp.int32)[:, None, :], 0)
+    phys_t = (phys * 2 + vis_any.astype(jnp.int32)).reshape(-1)
 
-    q_f = q.reshape(g_tot, wdw, n_rep, dh)
-    grid = (g_tot, wdw, max_p)
-    kernel = functools.partial(
-        _dense_decode_kernel, block_k=bk, max_p=max_p, hkv=hkv,
-        window=window, prefix_len=prefix_len, quant_bits=quant_bits,
-        kv_quant=kv_quant, sm_scale=sm_scale)
-    page_spec = pl.BlockSpec((1, 1, bk, dh),
-                             lambda g, w, p, ph, va, tn:
-                             (ph[g // hkv, w, p], g % hkv, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, bk),
-                              lambda g, w, p, ph, va, tn:
-                              (ph[g // hkv, w, p], g % hkv, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, n_rep, dh),
-                     lambda g, w, p, ph, va, tn: (g, w, 0, 0)),
-        page_spec,      # K pages
-        page_spec,      # V pages
-    ]
-    operands = [q_f, k_pages, v_pages]
+    def page(g, w, p, ph, tn):
+        return ph[((g // hkv) * wdw + w) * max_p + p] >> 1
+
+    page_spec = pl.BlockSpec(
+        (1, 1, bk, dh),
+        lambda g, w, p, ph, tn: (page(g, w, p, ph, tn), g % hkv, 0, 0))
+    row_spec = pl.BlockSpec((1, 1, n_rep, dh),
+                            lambda g, w, p, ph, tn: (g, w, 0, 0))
+    in_specs = [row_spec, page_spec, page_spec]
+    operands = [q.reshape(g_tot, wdw, n_rep, dh), k_pages, v_pages]
     if kv_quant != "none":
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [_scale_spec(bk, hkv, page)] * 2
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=grid,
+        num_scalar_prefetch=2,
+        grid=(g_tot, wdw, max_p),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, n_rep, dh),
-                         lambda g, w, p, ph, va, tn: (g, w, 0, 0)),
-        ],
+        out_specs=[row_spec],
         scratch_shapes=[
-            pltpu.VMEM((n_rep, dh), jnp.float32),   # acc
-            pltpu.VMEM((n_rep,), jnp.float32),      # m_i
-            pltpu.VMEM((n_rep,), jnp.float32),      # l_i
+            pltpu.VMEM((n_rep, dh), jnp.float32),           # acc
+            pltpu.VMEM((n_rep, STAT_LANES), jnp.float32),   # m_i
+            pltpu.VMEM((n_rep, STAT_LANES), jnp.float32),   # l_i
         ],
     )
+    kernel = functools.partial(
+        _dense_decode_kernel, block_k=bk, wdw=wdw, max_p=max_p, hkv=hkv,
+        window=window, prefix_len=prefix_len, quant_bits=quant_bits,
+        kv_quant=kv_quant, sm_scale=sm_scale)
     (o,) = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -592,7 +598,7 @@ def dense_decode_verify(q, k_pages, v_pages, page_table, t_new, *,
                                         jnp.float32)],
         interpret=interpret,
         name=f"dense_decode_paged_{quant_bits}_kv_{kv_quant}",
-    )(phys, valid, t_new, *operands)
+    )(phys_t, t_new.reshape(-1), *operands)
     return o.reshape(b, hkv, wdw, n_rep, dh)
 
 
@@ -633,6 +639,8 @@ def dense_decode_fused(q, k_pages, v_pages, page_table, t_new, *,
 def _prefill_kernel(*refs, block_k: int, max_p: int, chunk: int,
                     window, prefix_len: int, kv_quant: str,
                     sm_scale: float):
+    """Prefill kernel body over grid ``(Hkv, maxP)``; see
+    ``paged_flash_prefill``."""
     if kv_quant == "none":
         (phys_ref, vpg_ref, off_ref,                              # SMEM
          q_ref, k_ref, v_ref,                                     # in
@@ -644,6 +652,7 @@ def _prefill_kernel(*refs, block_k: int, max_p: int, chunk: int,
          q_ref, k_ref, v_ref, ks_ref, vs_ref,
          o_ref,
          acc, m_i, l_i) = refs
+    hh = pl.program_id(0)          # kv head
     p = pl.program_id(1)           # logical page of this slot's history
 
     @pl.when(p == 0)
@@ -658,7 +667,7 @@ def _prefill_kernel(*refs, block_k: int, max_p: int, chunk: int,
         k = k_ref[0, 0].astype(jnp.float32)     # (bk, Dh)
         if kv_quant != "none":
             # in-register dequant of the pool codes (per token row)
-            k = k * ks_ref[0, 0][:, None]
+            k = k * _head_scale(ks_ref, hh)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
@@ -679,26 +688,27 @@ def _prefill_kernel(*refs, block_k: int, max_p: int, chunk: int,
             vis = jnp.logical_or(vis, cols < prefix_len)
         s = jnp.where(vis, s, NEG_INF)
 
-        m_prev = m_i[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_i[:, :1]                     # (rows, 1) of the lanes
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         m_safe = jnp.where(m_new > NEG_INF * 0.5, m_new, 0.0)
-        pr = jnp.exp(s - m_safe[:, None])
+        pr = jnp.exp(s - m_safe)
         pr = jnp.where(s > NEG_INF * 0.5, pr, 0.0)
         corr = jnp.exp(jnp.where(m_prev > NEG_INF * 0.5, m_prev, m_safe)
                        - m_safe)
-        l_i[...] = l_i[...] * corr + pr.sum(axis=-1)
+        l_new = l_i[:, :1] * corr + pr.sum(axis=-1, keepdims=True)
+        l_i[...] = jnp.broadcast_to(l_new, l_i.shape)
         v = v_ref[0, 0].astype(jnp.float32)
         if kv_quant != "none":
-            v = v * vs_ref[0, 0][:, None]
-        acc[...] = acc[...] * corr[:, None] + jax.lax.dot_general(
+            v = v * _head_scale(vs_ref, hh)
+        acc[...] = acc[...] * corr + jax.lax.dot_general(
             pr, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_i[...] = m_new
+        m_i[...] = jnp.broadcast_to(m_new, m_i.shape)
 
     @pl.when(p == max_p - 1)
     def _finalize():
-        l_safe = jnp.maximum(l_i[...], 1e-20)
-        o_ref[0] = (acc[...] / l_safe[:, None]).astype(o_ref.dtype)
+        l_safe = jnp.maximum(l_i[:, :1], 1e-20)
+        o_ref[0] = (acc[...] / l_safe).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -768,8 +778,6 @@ def paged_flash_prefill(q, k_pages, v_pages, page_row, *, offset,
         sm_scale=sm_scale)
     page_spec = pl.BlockSpec((1, 1, bk, dh),
                              lambda hh, p, ph, vp, of: (ph[p], hh, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, bk),
-                              lambda hh, p, ph, vp, of: (ph[p], hh, 0))
     in_specs = [
         pl.BlockSpec((1, n_rep * c, dh),
                      lambda hh, p, ph, vp, of: (hh, 0, 0)),
@@ -778,7 +786,8 @@ def paged_flash_prefill(q, k_pages, v_pages, page_row, *, offset,
     ]
     operands = [q_g, k_pages, v_pages]
     if kv_quant != "none":
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [_scale_spec(bk, hkv,
+                                 lambda hh, p, ph, vp, of: ph[p])] * 2
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -790,8 +799,8 @@ def paged_flash_prefill(q, k_pages, v_pages, page_row, *, offset,
         ],
         scratch_shapes=[
             pltpu.VMEM((n_rep * c, dh), jnp.float32),
-            pltpu.VMEM((n_rep * c,), jnp.float32),
-            pltpu.VMEM((n_rep * c,), jnp.float32),
+            pltpu.VMEM((n_rep * c, STAT_LANES), jnp.float32),
+            pltpu.VMEM((n_rep * c, STAT_LANES), jnp.float32),
         ],
     )
     (o,) = pl.pallas_call(

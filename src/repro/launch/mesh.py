@@ -14,6 +14,8 @@ compression (distributed/compression.py) applies.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 
 
@@ -27,22 +29,51 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_host_mesh(n: int | None = None, *, devices=None):
     """Whatever this host has — smoke tests, examples and the sharded
-    serving tests.  ``n`` takes the first n local devices (data axis);
-    ``devices`` builds the mesh from an explicit device list instead (the
-    engine's fault path re-meshes onto the survivors of a host failure).
-    Either way the mesh is (data=n, model=1)."""
+    serving tests.  ``n`` takes the first n local devices (data axis) and
+    raises when the host has fewer; ``devices`` builds the mesh from an
+    explicit device list instead (the engine's fault path re-meshes onto
+    the survivors of a host failure).  Either way the mesh is
+    (data=n, model=1)."""
     import numpy as np
     from jax.sharding import Mesh
     if devices is not None:
         devs = list(devices)
     else:
         devs = jax.devices() if n is None else jax.devices()[:n]
+        if n is not None and len(devs) < n:
+            raise ValueError(f"make_host_mesh({n}): this host has only "
+                             f"{len(devs)} {jax.default_backend()} device(s)")
     return Mesh(np.asarray(devs).reshape(len(devs), 1), ("data", "model"))
 
 
-# TPU v5e hardware constants used by the roofline (launch/roofline.py)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-PEAK_FLOPS_INT8 = 394e12        # MXU int8 path
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (~per-chip usable)
-HBM_BYTES = 16 * 1024 ** 3      # 16 GiB per chip
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind (rates per second,
+    bytes for capacity)."""
+    flops_bf16: float
+    flops_int8: float
+    hbm_bw: float               # bytes/s
+    hbm_bytes: int
+    ici_bw: float               # bytes/s of chip-to-chip interconnect
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud TPU
+# documentation, "TPU v5e" system architecture table — 197 TFLOP/s bf16,
+# 394 TOP/s int8, 16 GiB HBM2 at 819 GB/s, 1,600 Gbit/s (200 GB/s)
+# interchip interconnect per chip.
+V5E = "TPU v5 lite"
+CHIP_PEAKS = {
+    V5E: ChipPeaks(flops_bf16=197e12, flops_int8=394e12, hbm_bw=819e9,
+                   hbm_bytes=16 * 1024 ** 3, ici_bw=1600e9 / 8),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; an unknown kind is an error, never a
+    default, so no roofline is ever computed against the wrong chip."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                         ) from None

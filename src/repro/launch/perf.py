@@ -49,14 +49,15 @@ def variant_kwargs(variant: str) -> dict:
 
 
 def summarize(rec: dict) -> dict:
-    from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+    from repro.launch.mesh import V5E, chip_peaks
+    peaks = chip_peaks(V5E)
     if rec["status"] != "ok":
         return {"status": rec["status"], "error": rec.get("error")}
     c = rec["cost"]
     return {
-        "compute_s": c["flops"] / PEAK_FLOPS_BF16,
-        "memory_s": c["bytes_accessed"] / HBM_BW,
-        "collective_s": rec["collectives"]["total_bytes"] / ICI_BW,
+        "compute_s": c["flops"] / peaks.flops_bf16,
+        "memory_s": c["bytes_accessed"] / peaks.hbm_bw,
+        "collective_s": rec["collectives"]["total_bytes"] / peaks.ici_bw,
         "peak_gib": rec["memory"]["peak_bytes_per_device"] / 2 ** 30,
     }
 

@@ -25,11 +25,13 @@ import glob
 import json
 import os
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import V5E, chip_peaks
 
 # tokens-per-step and step kind per shape cell
 from repro.configs import SHAPES
 from repro.configs.wan_dit_1_3b import DIT_SHAPES
+
+V5E_PEAKS = chip_peaks(V5E)     # the chip these rooflines model
 
 
 def arch_param_counts(arch: str) -> dict:
@@ -182,7 +184,7 @@ def attention_roofline_s(flops: float, bytes_: float) -> float:
     is modeled upstream by ``benchmarks.common.attention_flops``'s
     ``quant_speed`` (it divides the sparse-branch FLOPs), so the peaks
     here stay bf16."""
-    return max(flops / PEAK_FLOPS_BF16, bytes_ / HBM_BW)
+    return max(flops / V5E_PEAKS.flops_bf16, bytes_ / V5E_PEAKS.hbm_bw)
 
 
 _NOTES = {
@@ -231,9 +233,9 @@ def analyze_cell(rec: dict, counts: dict) -> dict:
         if floor > flops_dev:
             flops_dev, analytic = floor, True
 
-    t_compute = flops_dev / PEAK_FLOPS_BF16
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / ICI_BW
+    t_compute = flops_dev / V5E_PEAKS.flops_bf16
+    t_memory = bytes_dev / V5E_PEAKS.hbm_bw
+    t_coll = coll_dev / V5E_PEAKS.ici_bw
     terms = {"compute": t_compute, "memory": t_memory,
              "collective": t_coll}
     dominant = max(terms, key=terms.get)
@@ -252,7 +254,7 @@ def analyze_cell(rec: dict, counts: dict) -> dict:
 
     # roofline fraction: how close the dominant term is to being the ONLY
     # cost => step_time ~= max(terms); efficiency = ideal_compute / max
-    ideal = model_flops / chips / PEAK_FLOPS_BF16
+    ideal = model_flops / chips / V5E_PEAKS.flops_bf16
     frac = ideal / max(max(terms.values()), 1e-30)
 
     return {

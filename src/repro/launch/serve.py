@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
+from repro.launch.cache import use_compile_cache
 from repro.models.api import build_model
 from repro.serve import EngineConfig, Request, ServeEngine
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--max-len", type=int, default=512)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

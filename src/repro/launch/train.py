@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.data import make_dataset
+from repro.launch.cache import use_compile_cache
 from repro.models.api import build_model
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, Trainer, TrainerConfig
@@ -38,6 +39,7 @@ def main():
                     default="none")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))

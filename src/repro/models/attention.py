@@ -115,6 +115,11 @@ def init_attention(key, cfg: AttentionConfig, dtype=jnp.float32) -> dict:
 
 
 def _project_qkv(params, cfg: AttentionConfig, x, positions):
+    if cfg.mesh is not None:
+        # per-slot positions arrive split on a serving mesh; a split rope
+        # operand would pull the projections onto one slot per device
+        from repro.distributed.shard_paged import replicate
+        positions = replicate(positions, cfg.mesh)
     b, n, _ = x.shape
     h, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = (x @ params["wq"]).reshape(b, n, h, dh)
@@ -126,6 +131,8 @@ def _project_qkv(params, cfg: AttentionConfig, x, positions):
     if cfg.use_rope:
         q = L.apply_rope(q, positions, theta=cfg.rope_theta)
         k = L.apply_rope(k, positions, theta=cfg.rope_theta)
+    if cfg.mesh is not None:
+        q, k, v = (replicate(t, cfg.mesh) for t in (q, k, v))
     return q, k, v
 
 

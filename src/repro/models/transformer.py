@@ -649,11 +649,15 @@ def copy_kv_page(cfg: ModelConfig, caches: dict, src, dst) -> dict:
 
 def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn):
     """Shared block body around a paged mixer call, dispatched on the layer
-    kind; recurrent-core kinds (mlstm/slstm) have no ln2/FFN half."""
+    kind; recurrent-core kinds (mlstm/slstm) have no ln2/FFN half.  On a
+    serving mesh the residual stream stays whole on every device
+    (distributed/shard_paged.replicate)."""
+    from repro.distributed.shard_paged import replicate
+    x = replicate(x, cfg.mesh)
     h = L.rmsnorm(lp["ln1"], x)
     key = KIND_CACHE_KEY[kind]
     y, c = mix_fn(kind, lp, h, lc[key])
-    x = x + y
+    x = replicate(x + y, cfg.mesh)
     if kind in ("mlstm", "slstm"):
         return x, {key: c}
     h2 = L.rmsnorm(lp["ln2"], x)

@@ -34,8 +34,9 @@ SALAD/SVG-EAR-style ablations run on the same harness.
 
 Batched interleaved serving is **bit-identical** to per-request
 sequential denoising (``denoise_sequential``): every op in the denoise
-step is independent per batch row, and the cached constants are computed
-per request with batch-1 shapes in both paths.  tests/test_diffusion.py
+step is independent per batch row, the oracle runs the engine's compiled
+batch width, and the cached constants are computed per request with
+batch-1 shapes in both paths.  tests/test_diffusion.py
 and every benchmarks/fig12_diffusion.py run assert this with
 ``np.array_equal``.
 """
@@ -192,10 +193,10 @@ def _resolved_model(model, mechanism: Optional[str], attn_impl: str):
 
 def _step_fns(model):
     """Jitted (denoise step, text-KV precompute, step-mods precompute)
-    for an override model, built once and cached on it.  The step fn is
-    shape-polymorphic through jit's shape cache: the engine calls it at
-    batch ``max_slots``, the sequential oracle at batch 1 — same code,
-    per-row-independent ops, hence bit-identical rows."""
+    for an override model, built once and cached on it.  The engine and
+    the sequential oracle both call the step fn at batch ``max_slots`` —
+    one compiled program, per-row-independent ops, hence bit-identical
+    rows."""
     if "_diffusion_fns" in model.__dict__:
         return model.__dict__["_diffusion_fns"]
 
@@ -373,28 +374,37 @@ class DiffusionEngine:
 def denoise_sequential(model, params, requests,
                        cfg: Optional[DiffusionEngineConfig] = None
                        ) -> Dict[int, np.ndarray]:
-    """The exactness oracle: denoise each request alone, one batch-1
-    dispatch per step, through the same cached-constants path as the
-    engine.  Returns {uid: final latents}.  DiffusionEngine's batched
-    interleaved outputs must match this bit-for-bit."""
+    """The exactness oracle: denoise each request alone, one dispatch per
+    step, through the same cached-constants path as the engine.  Returns
+    {uid: final latents}.  DiffusionEngine's batched interleaved outputs
+    must match this bit-for-bit.
+
+    The request fills every row of a ``cfg.max_slots``-row batch, so the
+    oracle runs the program the engine compiled.  XLA does not promise
+    the same rounding for programs compiled at different batch sizes (on
+    a TPU v5e the batch-1 and batch-2 denoise steps differ), while within
+    one program every row is computed alike."""
     cfg = cfg or DiffusionEngineConfig()
     m = _resolved_model(model, cfg.mechanism, cfg.attn_impl)
     step_fn, kv_fn, mods_fn = _step_fns(m)
+    s = cfg.max_slots
     out: Dict[int, np.ndarray] = {}
     for req in requests:
         _check_request(req, m.cfg, cfg)
         kk, vv = kv_fn(params, jnp.asarray(req.text)[None])
+        kk, vv = jnp.repeat(kk, s, axis=1), jnp.repeat(vv, s, axis=1)
         sched = jnp.asarray(
             _timestep_schedule(req.n_steps, cfg.max_steps))
         mods = mods_fn(params, sched)
-        mods_b = mods["blocks"][:, None]          # (L, 1, S, 6d)
-        mods_f = mods["final"][None]              # (1, S, 2d)
-        lat = jnp.asarray(req.latents, jnp.float32)[None]
-        dt = jnp.full((1,), 1.0 / req.n_steps, jnp.float32)
-        active = jnp.ones((1,), bool)
+        mods_b = jnp.repeat(mods["blocks"][:, None], s, axis=1)  # (L,s,S,6d)
+        mods_f = jnp.repeat(mods["final"][None], s, axis=0)      # (s,S,2d)
+        lat = jnp.repeat(jnp.asarray(req.latents, jnp.float32)[None], s,
+                         axis=0)
+        dt = jnp.full((s,), 1.0 / req.n_steps, jnp.float32)
+        active = jnp.ones((s,), bool)
         for i in range(req.n_steps):
             lat = step_fn(params, lat, kk, vv, mods_b, mods_f,
-                          jnp.full((1,), i, jnp.int32), dt, active)
+                          jnp.full((s,), i, jnp.int32), dt, active)
         out[req.uid] = np.asarray(lat[0])
     return out
 

@@ -742,24 +742,7 @@ class ServeEngine:
                  in dead_set} if n_shards > 1 else set())
         # parked swap states that lean on shared trie pages lose them with
         # the pool: demote them to recompute before rebuilding anything
-        for arr, res in list(self.scheduler._resume.items()):
-            if res.mode == "swap" and res.n_shared > 0:
-                self.swap.pop(arr)
-                s = res.slot
-                if s.decoding:
-                    s.replay = list(s.req.output)
-                    s.decoding = False
-                s.pos = 0
-                s.n_pages = 0
-                s.n_shared = 0
-                s.cache_node = None
-                s.snaps = None
-                if s.pinned_node is not None:
-                    self._pcache.unpin(s.pinned_node)
-                    s.pinned_node = None
-                self.stats["recomputes"] += 1
-                self.scheduler._resume[arr] = _ResumeState(
-                    mode="recompute", slot=s)
+        self._demote_trie_swaps()
         # preempt every occupied slot, oldest first (oldest carry the most
         # computed state, so they get first claim on the swap pool)
         for slot in sorted(self._slots,
@@ -1087,8 +1070,45 @@ class ServeEngine:
             n += self._pcache.evictable_pages(self.allocator)
         return n
 
+    def _demote_trie_swaps(self) -> int:
+        """Turn every parked swap state that maps shared trie pages into a
+        recompute, releasing its pin on the trie; returns how many."""
+        n = 0
+        for arr, res in list(self.scheduler._resume.items()):
+            if res.mode == "swap" and res.n_shared > 0:
+                self.swap.pop(arr)
+                s = res.slot
+                if s.decoding:
+                    s.replay = list(s.req.output)
+                    s.decoding = False
+                s.pos = 0
+                s.n_pages = 0
+                s.n_shared = 0
+                s.cache_node = None
+                s.snaps = None
+                if s.pinned_node is not None:
+                    self._pcache.unpin(s.pinned_node)
+                    s.pinned_node = None
+                self.stats["recomputes"] += 1
+                self.scheduler._resume[arr] = _ResumeState(
+                    mode="recompute", slot=s)
+                n += 1
+        return n
+
     def _admit(self):
-        free = [s for s in range(self.cfg.max_slots) if s not in self._slots]
+        self._admit_fcfs(self.cfg.max_slots)
+        if not self._slots and self.scheduler.head() is not None \
+                and self._demote_trie_swaps():
+            # Idle with the head blocked: the cached pages it needs are
+            # pinned by swapped requests queued behind it, and no running
+            # slot will ever free them.  Their swap states are demoted to
+            # recompute (unpinned) and the head alone is admitted, so the
+            # demoted requests cannot re-pin those pages before it runs.
+            self._admit_fcfs(1)
+
+    def _admit_fcfs(self, limit: int):
+        free = [s for s in range(self.cfg.max_slots)
+                if s not in self._slots][:limit]
         conservative = self.cfg.admission == "conservative"
         for slot in free:
             req = self.scheduler.head()

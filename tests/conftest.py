@@ -5,20 +5,18 @@ state leaks between tests), so the heavyweight pieces — param init and the
 jit caches that accumulate on the model's closures — are shared across the
 whole session instead of being rebuilt per test module.
 """
-import os
-
 import jax
 import pytest
+
+from repro.configs import get_smoke_config
+from repro.launch.cache import use_compile_cache
+from repro.models.api import build_model
 
 # Persistent XLA compilation cache: the suite is compile-bound on CPU, and
 # most of it is identical between runs.  Cold runs pay full price; the
 # edit-test loop and cached CI runs skip recompiling unchanged graphs.
-_CACHE = os.path.join(os.path.dirname(__file__), os.pardir, ".jax_cache")
-jax.config.update("jax_compilation_cache_dir", os.path.abspath(_CACHE))
+use_compile_cache()
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
-from repro.configs import get_smoke_config          # noqa: E402
-from repro.models.api import build_model            # noqa: E402
 
 
 @pytest.fixture(scope="session")

@@ -273,7 +273,6 @@ def scenario_calibration() -> dict:
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.distributed import sharding as shardlib
     from repro.distributed.compression import (bf16_all_reduce_mean,
@@ -301,11 +300,11 @@ def scenario_calibration() -> dict:
     rng = np.random.default_rng(0)
     g = jnp.asarray(rng.standard_normal((n, 64, 8)), jnp.float32)
     kw = dict(mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-              check_rep=False)
-    q = shard_map(lambda v: int8_all_reduce_mean(v[0], "data")[None],
-                  **kw)(g)
-    b = shard_map(lambda v: bf16_all_reduce_mean(v[0], "data")[None],
-                  **kw)(g)
+              check_vma=False)
+    q = jax.shard_map(lambda v: int8_all_reduce_mean(v[0], "data")[None],
+                      **kw)(g)
+    b = jax.shard_map(lambda v: bf16_all_reduce_mean(v[0], "data")[None],
+                      **kw)(g)
     err = float(jnp.max(jnp.abs(q - b)))
     amax = float(jnp.max(jnp.abs(g)))
     assert err <= 2.5 * amax / 127, (err, amax)

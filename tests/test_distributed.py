@@ -261,23 +261,22 @@ def test_elastic_plan_shrinks_dp_only():
 # ===========================================================================
 
 def test_int8_all_reduce_matches_bf16_baseline(mesh2d):
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import (bf16_all_reduce_mean,
                                                int8_all_reduce_mean)
     n = len(jax.devices())
     rng = np.random.default_rng(0)
     g = jnp.asarray(rng.standard_normal((n, 64, 8)), jnp.float32)
     kw = dict(mesh=mesh2d, in_specs=P("data"), out_specs=P("data"),
-              check_rep=False)
-    q = shard_map(lambda v: int8_all_reduce_mean(v[0], "data")[None],
-                  **kw)(g)
-    b = shard_map(lambda v: bf16_all_reduce_mean(v[0], "data")[None],
-                  **kw)(g)
+              check_vma=False)
+    q = jax.shard_map(lambda v: int8_all_reduce_mean(v[0], "data")[None],
+                      **kw)(g)
+    b = jax.shard_map(lambda v: bf16_all_reduce_mean(v[0], "data")[None],
+                      **kw)(g)
     # two quantisation roundings, each bounded by half an int8 step
     amax = float(jnp.max(jnp.abs(g)))
     assert float(jnp.max(jnp.abs(q - b))) <= 2.5 * amax / 127
     # the odd-size padding path round-trips exactly
     g3 = jnp.asarray(rng.standard_normal((n, 7)), jnp.float32)
-    q3 = shard_map(lambda v: int8_all_reduce_mean(v[0], "data")[None],
-                   **kw)(g3)
+    q3 = jax.shard_map(lambda v: int8_all_reduce_mean(v[0], "data")[None],
+                       **kw)(g3)
     assert q3.shape == g3.shape

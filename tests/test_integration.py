@@ -151,12 +151,11 @@ def test_int8_all_to_all_reduce_roundtrip():
     """The wire-compressed all-reduce ~= psum mean (single-device uses a
     trivial 1-member axis via shard_map over a 1-sized mesh)."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.compression import int8_all_reduce_mean
     mesh = jax.make_mesh((1,), ("pod",))
     x = jax.random.normal(jax.random.PRNGKey(0), (64,))
-    f = shard_map(lambda a: int8_all_reduce_mean(a, "pod"), mesh=mesh,
-                  in_specs=P(), out_specs=P(), check_rep=False)
+    f = jax.shard_map(lambda a: int8_all_reduce_mean(a, "pod"), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     y = f(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(x), atol=0.02)
 
@@ -188,3 +187,32 @@ def test_straggler_and_heartbeat_policies():
     plan = ElasticPlan(512, 256)
     assert plan.new_mesh_shape(16) == (16, 16)
     assert plan.reshardable
+
+
+def test_compile_cache_dir_env_wins_else_repo_dir(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR is JAX's own to read: the helper sets no
+    directory then.  Unset, the cache goes to the fixed <repo>/.jax_cache."""
+    from repro.launch.cache import REPO_CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert (REPO_CACHE_DIR.parent / "pyproject.toml").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    from repro.launch.mesh import V5E, chip_peaks, make_host_mesh
+    v5e = chip_peaks(V5E)
+    assert v5e.ici_bw == 200e9                 # 1,600 Gbit/s
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")
+    with pytest.raises(ValueError, match="has only"):
+        make_host_mesh(len(jax.devices()) + 1)

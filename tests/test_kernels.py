@@ -1,4 +1,6 @@
 """Pallas kernel allclose sweeps vs kernels/ref.py oracles (interpret mode)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -283,3 +285,48 @@ def test_sla2_decode_fused_skips_invalid_pages():
     pad = lambda x, v: jnp.concatenate([x, jnp.full_like(x[..., :1], v)], -1)
     o_pad = run(pad(phys, 0), pad(jlog, 0), pad(valid, 0))
     np.testing.assert_allclose(o_pad, o, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# SMEM row groups: a scalar-prefetch table too large for one call splits
+# the kernel's leading rows over several calls with identical results
+# ---------------------------------------------------------------------------
+
+def test_row_groups_fit_budget_and_cover_rows():
+    from repro.kernels.ops import SMEM_PREFETCH_BYTES, row_groups
+    for rows, words in [(24, 256 * 26), (48, 256 * 26), (64, 53), (7, 10**6)]:
+        groups = row_groups(rows, words)
+        assert [s for s, _ in groups] == list(range(0, rows, groups[0][1]))
+        assert sum(n for _, n in groups) == rows
+        size = groups[0][1]
+        assert size == 1 or size * words * 4 <= SMEM_PREFETCH_BYTES
+    assert row_groups(24, 256 * 26) == [(0, 12), (12, 12)]
+
+
+def _one_row_per_call(monkeypatch, words_per_row):
+    """Shrink the SMEM budget so every row group holds a single row."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "SMEM_PREFETCH_BYTES", 4 * words_per_row)
+
+
+def _retraced(entry, **static):
+    """``entry`` jitted afresh, so it traces under the patched budget."""
+    return jax.jit(functools.partial(entry.__wrapped__, **static))
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_sparse_flash_split_rows_match_one_call(monkeypatch, causal):
+    bh, n, d, bq, bk, kf = 3, 128, 32, 32, 16, 0.3
+    q, k, v = make_qkv(bh, n, d, seed=3)
+    do = jax.random.normal(jax.random.PRNGKey(8), (bh, n, d), jnp.float32)
+    idx, valid = route(q, k, bq, bk, kf, causal)
+    valid = valid.astype(jnp.int32)
+    kw = dict(block_q=bq, block_k=bk, causal=causal)
+    whole = sparse_flash_fwd(q, k, v, idx, valid, **kw)
+    grads = sparse_flash_bwd(q, k, v, idx, valid, *whole, do, **kw)
+    _one_row_per_call(monkeypatch, idx.shape[1] * idx.shape[2])
+    split = _retraced(sparse_flash_fwd, **kw)(q, k, v, idx, valid)
+    split_grads = _retraced(sparse_flash_bwd, **kw)(q, k, v, idx, valid,
+                                                    *split, do)
+    for a, b in zip((*whole, *grads), (*split, *split_grads)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -1,0 +1,221 @@
+"""Ahead-of-time compiles of the Pallas kernels for a described TPU v5e.
+
+Interpret mode (every other kernel test) cannot see Mosaic's layout rules:
+block shapes whose last two dims break the (8, 128) tiling, 1-D VMEM
+scratch, or scalar-prefetch tables larger than SMEM.  These tests lower
+each entry point with ``interpret=False`` at the widths the engines serve
+(wan-dit-1.3b denoise, qwen3-14b paged serving) against a described
+``v5e:2x2`` topology and compile it for one chip: the TPU compiler runs
+here, no chip is needed, and nothing executes.
+
+The topology is described inside a fixture (never at import), so only the
+pytest worker that runs this file loads the TPU compiler library.
+"""
+import collections
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.distributed import sharding as shardlib
+from repro.kernels import sla2_decode_paged as KP
+from repro.kernels.sla2_bwd import sparse_flash_bwd
+from repro.kernels.sla2_fwd import sparse_flash_fwd
+
+# wan-dit-1.3b denoise: 2 slots x 12 heads, 32k latent tokens, 128x64
+# blocks, k_frac 0.05 (configs/wan_dit_1_3b.py)
+DIT_BH, DIT_N, DIT_D, DIT_BQ, DIT_BK = 2 * 12, 32768, 128, 128, 64
+DIT_KSEL = round(0.05 * (DIT_N // DIT_BK))
+# qwen3-14b paged serving: 8 kv heads x 5 query heads, Dh 128, 64-token
+# pages, 8 slots of 32k context, a 64-token prefill chunk
+LM_B, LM_HKV, LM_REP, LM_DH, LM_BK = 8, 8, 5, 128, 64
+LM_MAXP = 32768 // LM_BK
+LM_PAGES = LM_B * LM_MAXP + 1          # + the trash page
+LM_KSEL = round(0.05 * LM_MAXP)
+LM_CHUNK = 64
+LM_WINDOW = 4                          # speculative verify rows
+POOL_DTYPES = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure: cannot describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    """``spec(shape, dtype)``: an abstract argument placed on one chip of
+    the described topology, with the persistent compile cache off while
+    the module runs (an entry compiled for an absent chip cannot be read
+    back, and would only warn on the next lookup)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _pool(spec, kv_quant):
+    """A qwen3-14b-width page pool and, for a low-bit pool, its scales."""
+    dt = jnp.bfloat16 if kv_quant == "none" else POOL_DTYPES[kv_quant]
+    pages = spec((LM_PAGES, LM_HKV, LM_BK, LM_DH), dt)
+    scale = (None if kv_quant == "none"
+             else spec((LM_PAGES, LM_HKV, LM_BK), jnp.float32))
+    return pages, scale
+
+
+@pytest.mark.parametrize("quant_bits", ["none", "int8", "fp8"])
+def test_sparse_flash_fwd_compiles_at_dit_width(spec, quant_bits):
+    x = spec((DIT_BH, DIT_N, DIT_D), jnp.bfloat16)
+    sel = spec((DIT_BH, DIT_N // DIT_BQ, DIT_KSEL), jnp.int32)
+    _compile(lambda q, k, v, i, va: sparse_flash_fwd(
+        q, k, v, i, va, block_q=DIT_BQ, block_k=DIT_BK, causal=False,
+        quant_bits=quant_bits, interpret=False), x, x, x, sel, sel)
+
+
+def test_sparse_flash_bwd_compiles_at_dit_width(spec):
+    x = spec((DIT_BH, DIT_N, DIT_D), jnp.bfloat16)
+    sel = spec((DIT_BH, DIT_N // DIT_BQ, DIT_KSEL), jnp.int32)
+    lse = spec((DIT_BH, DIT_N), jnp.float32)
+    _compile(lambda q, k, v, i, va, o, l, do: sparse_flash_bwd(
+        q, k, v, i, va, o, l, do, block_q=DIT_BQ, block_k=DIT_BK,
+        causal=False, interpret=False), x, x, x, sel, sel, x, lse, x)
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "int8"])
+def test_paged_flash_prefill_compiles_at_qwen3_width(spec, kv_quant):
+    pages, scale = _pool(spec, kv_quant)
+    q = spec((LM_HKV * LM_REP, LM_CHUNK, LM_DH), jnp.bfloat16)
+    row = spec((LM_MAXP,), jnp.int32)
+    off = spec((), jnp.int32)
+    _compile(lambda q, kp, vp, r, o, ks, vs: KP.paged_flash_prefill(
+        q, kp, vp, r, offset=o, block_k=LM_BK, n_rep=LM_REP,
+        kv_quant=kv_quant, k_scale=ks, v_scale=vs, interpret=False),
+        q, pages, pages, row, off, scale, scale)
+
+
+@pytest.mark.parametrize("window", [1, LM_WINDOW])
+@pytest.mark.parametrize("quant_bits,kv_quant",
+                         [("none", "none"), ("int8", "none"),
+                          ("none", "int8")])
+def test_sla2_decode_compiles_at_qwen3_width(spec, window, quant_bits,
+                                             kv_quant):
+    pages, scale = _pool(spec, kv_quant)
+    w = () if window == 1 else (window,)
+    q = spec((LM_B, LM_HKV, *w, LM_REP, LM_DH), jnp.bfloat16)
+    sel = spec((LM_B, LM_HKV, *w, LM_KSEL), jnp.int32)
+    t_new = spec((LM_B, *w), jnp.int32)
+    h_tot = spec((LM_B, LM_HKV, *w, LM_DH, LM_DH), jnp.float32)
+    z_tot = spec((LM_B, LM_HKV, *w, LM_DH), jnp.float32)
+    alpha = spec((LM_B, LM_HKV, LM_REP), jnp.float32)
+    entry = KP.sla2_decode_fused if window == 1 else KP.sla2_decode_verify
+    _compile(lambda q, kp, vp, ph, jl, va, co, tn, h, z, a, ks, vs: entry(
+        q, kp, vp, ph, jl, va, co, tn, h, z, a, block_k=LM_BK,
+        quant_bits=quant_bits, kv_quant=kv_quant, k_scale=ks, v_scale=vs,
+        interpret=False),
+        q, pages, pages, sel, sel, sel, sel, t_new, h_tot, z_tot, alpha,
+        scale, scale)
+
+
+@pytest.mark.parametrize("window", [1, LM_WINDOW])
+@pytest.mark.parametrize("quant_bits,kv_quant",
+                         [("none", "none"), ("int8", "none"),
+                          ("none", "int8")])
+def test_dense_decode_compiles_at_qwen3_width(spec, window, quant_bits,
+                                              kv_quant):
+    pages, scale = _pool(spec, kv_quant)
+    w = () if window == 1 else (window,)
+    q = spec((LM_B, LM_HKV, *w, LM_REP, LM_DH), jnp.bfloat16)
+    table = spec((LM_B, LM_MAXP), jnp.int32)
+    t_new = spec((LM_B, *w), jnp.int32)
+    entry = KP.dense_decode_fused if window == 1 else KP.dense_decode_verify
+    _compile(lambda q, kp, vp, pt, tn, ks, vs: entry(
+        q, kp, vp, pt, tn, block_k=LM_BK, quant_bits=quant_bits,
+        kv_quant=kv_quant, k_scale=ks, v_scale=vs, interpret=False),
+        q, pages, pages, table, t_new, scale, scale)
+
+
+# bf16 matmul outputs and float all-reduces fed by a dot in compiled HLO
+_MATMUL = re.compile(r"= bf16\[([0-9,]*)\]\S* convolution\(")
+_DOT_ALL_REDUCE = re.compile(
+    r"= (?:f32|bf16)\[[0-9,]*\]\S* all-reduce\(.*op_name=\"[^\"]*dot_general")
+
+
+def _sharded_lm_steps(topo, n: int):
+    """Compiled HLO of the qwen3-14b-width (one layer) prefill and decode
+    steps on an (n, 1) serving mesh of the described topology, placed as
+    ``ServeEngine`` places them; n == 1 is the single-chip engine."""
+    from repro.models.api import build_model
+    mesh = Mesh(np.asarray(topo.devices[:n]).reshape(n, 1), ("data", "model"))
+    base = build_model(get_config("qwen3_14b", n_layers=1))
+    model = base.with_overrides(paged_impl="fused",
+                                mesh=mesh if n > 1 else None)
+
+    def placed(tree, shardings):
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=s), tree, shardings)
+    pshape = jax.eval_shape(base.init, jax.random.PRNGKey(0))
+    params = placed(pshape, shardlib.serving_param_shardings(pshape, mesh))
+    cshape = jax.eval_shape(lambda: model.init_paged_caches(4, 136, window=1))
+    csh = shardlib.logical_to_shardings(shardlib.cache_specs(cshape, mesh),
+                                        mesh)
+    caches = placed(cshape, csh)
+    rep = NamedSharding(mesh, P())
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    def pinned(step):
+        def fn(p, b, c):
+            out, c = step(p, b, c)
+            return out, jax.lax.with_sharding_constraint(c, csh)
+        return jax.jit(fn)
+    prefill = {"tokens": arg((1, LM_CHUNK), jnp.int32),
+               "page_row": arg((33,), jnp.int32),
+               "offset": arg((), jnp.int32),
+               "chunk_len": arg((), jnp.int32), "slot": arg((), jnp.int32)}
+    decode = {"token": arg((4,), jnp.int32),
+              "page_table": arg((4, 33), jnp.int32),
+              "lengths": arg((4,), jnp.int32), "active": arg((4,), jnp.bool_)}
+    return {name: pinned(step).lower(params, batch, caches).compile().as_text()
+            for name, step, batch in (("prefill", model.prefill_chunk, prefill),
+                                      ("decode", model.decode_paged, decode))}
+
+
+def test_sharded_serving_keeps_single_chip_matmuls(spec, monkeypatch, topo):
+    """On a 4-chip serving mesh only the fused kernels run split.  Every
+    bf16 matmul of the prefill and decode steps has as many elements as on
+    one chip (no projection runs on one slot or one head group per chip),
+    and no all-reduce sums partial products of a split contraction.  Either
+    would change the rounding, and the sharded engine would stop matching
+    the single-chip engine token for token."""
+    monkeypatch.setattr(KP, "default_interpret", lambda i=None: bool(i))
+    one, four = _sharded_lm_steps(topo, 1), _sharded_lm_steps(topo, 4)
+    for name in ("prefill", "decode"):
+        assert "tpu_custom_call" in four[name]
+        sizes = [collections.Counter(
+            math.prod(map(int, m.split(","))) for m in _MATMUL.findall(t))
+            for t in (one[name], four[name])]
+        assert sizes[0] and sizes[0] == sizes[1], (name, sizes)
+        assert not _DOT_ALL_REDUCE.search(four[name]), name
