@@ -108,7 +108,9 @@ def sla2_attention(params: dict, q: jax.Array, k: jax.Array, v: jax.Array,
     if mask_override is not None:
         mask_c = mask_override
     else:
-        mask_c = routerlib.route(params.get("router", {}), q, k, rcfg, soft=soft)
+        with jax.named_scope("sla2.router"):
+            mask_c = routerlib.route(params.get("router", {}), q, k, rcfg,
+                                     soft=soft)
 
     if cfg.impl == "kernel" and not soft:
         from repro.kernels import ops as kops  # lazy: keeps core import-light
@@ -118,8 +120,9 @@ def sla2_attention(params: dict, q: jax.Array, k: jax.Array, v: jax.Array,
         from repro.core import block_sparse
         flat = lambda x: x.reshape(b * h, *x.shape[2:])
         qf, kf, vf = flat(q), flat(k), flat(v)
-        idx, valid = routerlib.route_indices(
-            params.get("router", {}), qf, kf, rcfg)
+        with jax.named_scope("sla2.router"):
+            idx, valid = routerlib.route_indices(
+                params.get("router", {}), qf, kf, rcfg)
         t_m = n // rcfg.block_q
         a = _expand_alpha(alpha_for_blocks(params, t_m, h), rcfg.block_q, n)
         a_tok = jnp.broadcast_to(a[None], (b, h, n, 1)).reshape(b * h, n, 1)
@@ -131,31 +134,39 @@ def sla2_attention(params: dict, q: jax.Array, k: jax.Array, v: jax.Array,
         o = o.reshape(b, h, n, vf.shape[-1])
         aux = {"idx": idx, "valid": valid}
     else:
-        o_s = attn.sparse_attention(
-            q, k, v, mask_c, block_q=rcfg.block_q, block_k=rcfg.block_k,
-            causal=rcfg.causal, soft=soft, quant_bits=cfg.quant_bits,
-            prefix_len=rcfg.prefix_len)
-        o_l = attn.linear_attention(
-            q, k, v, mask_c, block_q=rcfg.block_q, block_k=rcfg.block_k,
-            causal=rcfg.causal, soft=soft, prefix_len=rcfg.prefix_len)
-        t_m = n // rcfg.block_q
-        a = _expand_alpha(alpha_for_blocks(params, t_m, h), rcfg.block_q, n)
-        # where the routed complement is empty the row is fully sparse: the
-        # decomposition P = P1 + P2 degenerates to P = P1, so alpha must be 1
-        # regardless of its learned value (matches the kernel path).
-        comp = 1.0 - mask_c.astype(jnp.float32)
-        if rcfg.causal:
-            i_arr = jnp.arange(t_m)
-            n_full = (i_arr * rcfg.block_q + 1) // rcfg.block_k
-            if rcfg.prefix_len:
-                n_full = jnp.maximum(n_full, rcfg.prefix_len // rcfg.block_k)
-            fully = jnp.arange(mask_c.shape[-1])[None, :] < n_full[:, None]
-            comp = comp * fully.astype(comp.dtype)
-        nonempty = comp.sum(-1) > 1e-6                   # (B, H, T_m)
-        nonempty = jnp.repeat(nonempty, rcfg.block_q, axis=-1)[..., None]
-        a = jnp.where(nonempty, a, 1.0)
-        o = (a * o_s.astype(jnp.float32)
-             + (1.0 - a) * o_l.astype(jnp.float32)).astype(q.dtype)
+        with jax.named_scope("sla2.sparse"):
+            o_s = attn.sparse_attention(
+                q, k, v, mask_c, block_q=rcfg.block_q, block_k=rcfg.block_k,
+                causal=rcfg.causal, soft=soft, quant_bits=cfg.quant_bits,
+                prefix_len=rcfg.prefix_len)
+        with jax.named_scope("sla2.linear"):
+            o_l = attn.linear_attention(
+                q, k, v, mask_c, block_q=rcfg.block_q, block_k=rcfg.block_k,
+                causal=rcfg.causal, soft=soft, prefix_len=rcfg.prefix_len)
+        with jax.named_scope("sla2.combine"):
+            t_m = n // rcfg.block_q
+            a = _expand_alpha(alpha_for_blocks(params, t_m, h),
+                              rcfg.block_q, n)
+            # where the routed complement is empty the row is fully sparse:
+            # the decomposition P = P1 + P2 degenerates to P = P1, so alpha
+            # must be 1 regardless of its learned value (matches the kernel
+            # path).
+            comp = 1.0 - mask_c.astype(jnp.float32)
+            if rcfg.causal:
+                i_arr = jnp.arange(t_m)
+                n_full = (i_arr * rcfg.block_q + 1) // rcfg.block_k
+                if rcfg.prefix_len:
+                    n_full = jnp.maximum(n_full,
+                                         rcfg.prefix_len // rcfg.block_k)
+                fully = (jnp.arange(mask_c.shape[-1])[None, :]
+                         < n_full[:, None])
+                comp = comp * fully.astype(comp.dtype)
+            nonempty = comp.sum(-1) > 1e-6               # (B, H, T_m)
+            nonempty = jnp.repeat(nonempty, rcfg.block_q,
+                                  axis=-1)[..., None]
+            a = jnp.where(nonempty, a, 1.0)
+            o = (a * o_s.astype(jnp.float32)
+                 + (1.0 - a) * o_l.astype(jnp.float32)).astype(q.dtype)
         aux = {}
     if return_aux:
         from repro.core import masks as masklib

@@ -224,24 +224,30 @@ def sla2_block_sparse(params: dict, q, k, v, cfg, *, mask_c=None):
     flat = lambda x: x.reshape(b * h_num, *x.shape[2:])
     qf, kf, vf = flat(q), flat(k), flat(v)
 
-    idx, valid = routerlib.route_indices(
-        params.get("router", {}), qf, kf, rcfg)
+    with jax.named_scope("sla2.router"):
+        idx, valid = routerlib.route_indices(
+            params.get("router", {}), qf, kf, rcfg)
 
-    o_s, lse = sparse_attention_op(
-        qf, kf, vf, idx, valid, rcfg.block_q, rcfg.block_k, rcfg.causal,
-        cfg.quant_bits, rcfg.prefix_len)
-    o_l, den = linear_branch(
-        qf, kf, vf, idx, valid, block_q=rcfg.block_q, block_k=rcfg.block_k,
-        causal=rcfg.causal, prefix_len=rcfg.prefix_len)
+    with jax.named_scope("sla2.sparse"):
+        o_s, lse = sparse_attention_op(
+            qf, kf, vf, idx, valid, rcfg.block_q, rcfg.block_k, rcfg.causal,
+            cfg.quant_bits, rcfg.prefix_len)
+    with jax.named_scope("sla2.linear"):
+        o_l, den = linear_branch(
+            qf, kf, vf, idx, valid, block_q=rcfg.block_q,
+            block_k=rcfg.block_k, causal=rcfg.causal,
+            prefix_len=rcfg.prefix_len)
 
-    t_m = n // rcfg.block_q
-    a_blocks = sla2lib.alpha_for_blocks(params, t_m, h_num)   # (H, T_m)
-    a_tok = jnp.repeat(a_blocks, rcfg.block_q, axis=-1)        # (H, N)
-    a_tok = jnp.broadcast_to(a_tok[None], (b, h_num, n)).reshape(
-        b * h_num, n, 1)
-    a_eff = jnp.where(den > _EPS, a_tok, 1.0)  # empty complement => sparse only
-    o = (a_eff * o_s.astype(jnp.float32)
-         + (1.0 - a_eff) * o_l.astype(jnp.float32)).astype(q.dtype)
-    o = o.reshape(b, h_num, n, d)
+    with jax.named_scope("sla2.combine"):
+        t_m = n // rcfg.block_q
+        a_blocks = sla2lib.alpha_for_blocks(params, t_m, h_num)  # (H, T_m)
+        a_tok = jnp.repeat(a_blocks, rcfg.block_q, axis=-1)       # (H, N)
+        a_tok = jnp.broadcast_to(a_tok[None], (b, h_num, n)).reshape(
+            b * h_num, n, 1)
+        # empty complement => sparse only
+        a_eff = jnp.where(den > _EPS, a_tok, 1.0)
+        o = (a_eff * o_s.astype(jnp.float32)
+             + (1.0 - a_eff) * o_l.astype(jnp.float32)).astype(q.dtype)
+        o = o.reshape(b, h_num, n, d)
     aux = {"idx": idx, "valid": valid, "lse": lse.reshape(b, h_num, n)}
     return o, aux

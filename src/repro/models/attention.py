@@ -541,11 +541,12 @@ def _gather_blocks(pages, phys):
 def _kv_read(cache: dict, name: str, idx):
     """``cache[name][idx]`` dequantized to f32 (``idx`` indexes the page
     axis; any leading index shape works — the scale broadcasts per row)."""
-    out = cache[name][idx]
-    sk = _SCALE_OF[name]
-    if sk in cache:
-        return ops.dequant_rows(out, cache[sk][idx])
-    return out.astype(jnp.float32)
+    with jax.named_scope("lm.kv_read"):
+        out = cache[name][idx]
+        sk = _SCALE_OF[name]
+        if sk in cache:
+            return ops.dequant_rows(out, cache[sk][idx])
+        return out.astype(jnp.float32)
 
 
 def _kv_gather_pages(cache: dict, name: str, page_table):
@@ -559,11 +560,12 @@ def _kv_gather_pages(cache: dict, name: str, page_table):
 def _kv_gather_blocks(cache: dict, name: str, phys):
     """Dequantizing ``_gather_blocks``: (B, Hkv, K, bk, Dh) f32 from a
     (possibly quantized) page array and per-kv-head physical ids."""
-    out = _gather_blocks(cache[name], phys).astype(jnp.float32)
-    sk = _SCALE_OF[name]
-    if sk in cache:
-        out = out * _gather_blocks(cache[sk], phys)[..., None]
-    return out
+    with jax.named_scope("lm.kv_read"):
+        out = _gather_blocks(cache[name], phys).astype(jnp.float32)
+        sk = _SCALE_OF[name]
+        if sk in cache:
+            out = out * _gather_blocks(cache[sk], phys)[..., None]
+        return out
 
 
 def _store_kv_rows(cache: dict, cfg: AttentionConfig, phys, rows,
@@ -578,19 +580,20 @@ def _store_kv_rows(cache: dict, cfg: AttentionConfig, phys, rows,
     trip; the raw inputs when unquantized) — callers derive SLA2 block
     state from THESE so prefill-time state matches decode-time recompute
     from pages."""
-    if cfg.kv_quant == "none":
-        cache["k_pages"] = cache["k_pages"].at[phys, :, rows].set(
-            k_new.astype(cache["k_pages"].dtype))
-        cache["v_pages"] = cache["v_pages"].at[phys, :, rows].set(
-            v_new.astype(cache["v_pages"].dtype))
-        return cache, k_new, v_new
-    k_c, k_s = ops.quantize_rows(k_new, cfg.kv_quant)
-    v_c, v_s = ops.quantize_rows(v_new, cfg.kv_quant)
-    cache["k_pages"] = cache["k_pages"].at[phys, :, rows].set(k_c)
-    cache["v_pages"] = cache["v_pages"].at[phys, :, rows].set(v_c)
-    cache["k_scale"] = cache["k_scale"].at[phys, :, rows].set(k_s)
-    cache["v_scale"] = cache["v_scale"].at[phys, :, rows].set(v_s)
-    return cache, ops.dequant_rows(k_c, k_s), ops.dequant_rows(v_c, v_s)
+    with jax.named_scope("lm.kv_write"):
+        if cfg.kv_quant == "none":
+            cache["k_pages"] = cache["k_pages"].at[phys, :, rows].set(
+                k_new.astype(cache["k_pages"].dtype))
+            cache["v_pages"] = cache["v_pages"].at[phys, :, rows].set(
+                v_new.astype(cache["v_pages"].dtype))
+            return cache, k_new, v_new
+        k_c, k_s = ops.quantize_rows(k_new, cfg.kv_quant)
+        v_c, v_s = ops.quantize_rows(v_new, cfg.kv_quant)
+        cache["k_pages"] = cache["k_pages"].at[phys, :, rows].set(k_c)
+        cache["v_pages"] = cache["v_pages"].at[phys, :, rows].set(v_c)
+        cache["k_scale"] = cache["k_scale"].at[phys, :, rows].set(k_s)
+        cache["v_scale"] = cache["v_scale"].at[phys, :, rows].set(v_s)
+        return cache, ops.dequant_rows(k_c, k_s), ops.dequant_rows(v_c, v_s)
 
 
 def _store_pooled(cache: dict, cfg: AttentionConfig, phys, pooled,
@@ -599,19 +602,20 @@ def _store_pooled(cache: dict, cfg: AttentionConfig, phys, pooled,
     quantizing per (page, kv head) when the pool is quantized; rows where
     ``keep`` (leading shape of phys) is False retain the existing page
     content (the masked-write idiom of the trash-page scheme)."""
-    if cfg.kv_quant == "none":
+    with jax.named_scope("lm.kv_write"):
+        if cfg.kv_quant == "none":
+            cache["pooled_pages"] = cache["pooled_pages"].at[phys].set(
+                jnp.where(keep[..., None, None],
+                          pooled.astype(cache["pooled_pages"].dtype),
+                          cache["pooled_pages"][phys]))
+            return cache
+        codes, scale = ops.quantize_rows(pooled, cfg.kv_quant)
         cache["pooled_pages"] = cache["pooled_pages"].at[phys].set(
-            jnp.where(keep[..., None, None],
-                      pooled.astype(cache["pooled_pages"].dtype),
+            jnp.where(keep[..., None, None], codes,
                       cache["pooled_pages"][phys]))
+        cache["pooled_scale"] = cache["pooled_scale"].at[phys].set(
+            jnp.where(keep[..., None], scale, cache["pooled_scale"][phys]))
         return cache
-    codes, scale = ops.quantize_rows(pooled, cfg.kv_quant)
-    cache["pooled_pages"] = cache["pooled_pages"].at[phys].set(
-        jnp.where(keep[..., None, None], codes,
-                  cache["pooled_pages"][phys]))
-    cache["pooled_scale"] = cache["pooled_scale"].at[phys].set(
-        jnp.where(keep[..., None], scale, cache["pooled_scale"][phys]))
-    return cache
 
 
 def chunk_prefill_paged(params: dict, cfg: AttentionConfig, x: jax.Array,
@@ -817,28 +821,29 @@ def _sla2_decode_paged(params: dict, cfg: AttentionConfig, q, cache,
     cache["z_tot"] = cache["z_tot"] + jnp.where(upd[..., None], z_cur, 0.0)
 
     # --- route: group-shared over the slot's logical blocks ---
-    rp = sla2_p.get("router", {})
-    qr = q[:, :, 0].astype(jnp.float32)                  # (B, H, Dh)
-    pk = _kv_read(cache, "pooled_pages", page_table)     # (B, T_n, Hkv, Dh)
-    pk = pk.transpose(0, 2, 1, 3)                        # (B, Hkv, T_n, Dh)
-    if rp:
-        qr = qr @ rp["proj_q"].astype(jnp.float32)
-        pk = pk @ rp["proj_k"].astype(jnp.float32)
-    qr_g = qr.reshape(b, hkv, n_rep, dh).mean(axis=2)
-    scores = jnp.einsum("bhd,bhtd->bht", qr_g, pk) / jnp.sqrt(dh)
-    blk_ids = jnp.arange(t_n)
-    allowed = blk_ids[None, None, :] <= cur_blk[:, None, None]
-    scores = jnp.where(allowed, scores, masklib.NEG_INF)
-    scores = jnp.where(blk_ids[None, None, :] == cur_blk[:, None, None],
-                       jnp.inf, scores)
-    k_sel = max(1, round(cfg.k_frac * t_n))
-    top_vals, idx = jax.lax.top_k(scores, k_sel)         # (B, Hkv, K_sel)
-    valid = top_vals > masklib.NEG_INF * 0.5
+    with jax.named_scope("sla2.router"):
+        rp = sla2_p.get("router", {})
+        qr = q[:, :, 0].astype(jnp.float32)              # (B, H, Dh)
+        pk = _kv_read(cache, "pooled_pages", page_table)  # (B, T_n, Hkv, Dh)
+        pk = pk.transpose(0, 2, 1, 3)                    # (B, Hkv, T_n, Dh)
+        if rp:
+            qr = qr @ rp["proj_q"].astype(jnp.float32)
+            pk = pk @ rp["proj_k"].astype(jnp.float32)
+        qr_g = qr.reshape(b, hkv, n_rep, dh).mean(axis=2)
+        scores = jnp.einsum("bhd,bhtd->bht", qr_g, pk) / jnp.sqrt(dh)
+        blk_ids = jnp.arange(t_n)
+        allowed = blk_ids[None, None, :] <= cur_blk[:, None, None]
+        scores = jnp.where(allowed, scores, masklib.NEG_INF)
+        scores = jnp.where(blk_ids[None, None, :] == cur_blk[:, None, None],
+                           jnp.inf, scores)
+        k_sel = max(1, round(cfg.k_frac * t_n))
+        top_vals, idx = jax.lax.top_k(scores, k_sel)     # (B, Hkv, K_sel)
+        valid = top_vals > masklib.NEG_INF * 0.5
 
-    pt = jnp.broadcast_to(page_table[:, None, :], (b, hkv, t_n))
-    phys_sel = jnp.where(valid, jnp.take_along_axis(pt, idx, axis=2), 0)
-    complete_bound = cur_blk + jnp.where(completed, 1, 0)
-    sel_complete = valid & (idx < complete_bound[:, None, None])
+        pt = jnp.broadcast_to(page_table[:, None, :], (b, hkv, t_n))
+        phys_sel = jnp.where(valid, jnp.take_along_axis(pt, idx, axis=2), 0)
+        complete_bound = cur_blk + jnp.where(completed, 1, 0)
+        sel_complete = valid & (idx < complete_bound[:, None, None])
 
     if use_fused(cfg, "decode"):
         # fused Pallas kernel: one HBM traversal of the selected pages does

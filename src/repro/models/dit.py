@@ -222,12 +222,14 @@ MECHANISM_ATTENTION = {
 def _self_attention(bp: dict, cfg: DiTConfig, x: jax.Array) -> jax.Array:
     b, n, _ = x.shape
     h, dh = cfg.num_heads, cfg.head_dim
-    q = (x @ bp["wq"]).reshape(b, n, h, dh).transpose(0, 2, 1, 3)
-    k = (x @ bp["wk"]).reshape(b, n, h, dh).transpose(0, 2, 1, 3)
-    v = (x @ bp["wv"]).reshape(b, n, h, dh).transpose(0, 2, 1, 3)
+    with jax.named_scope("dit.qkv"):
+        q = (x @ bp["wq"]).reshape(b, n, h, dh).transpose(0, 2, 1, 3)
+        k = (x @ bp["wk"]).reshape(b, n, h, dh).transpose(0, 2, 1, 3)
+        v = (x @ bp["wv"]).reshape(b, n, h, dh).transpose(0, 2, 1, 3)
     o = MECHANISM_ATTENTION[cfg.mechanism](bp, cfg, q, k, v)
-    o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
-    return o @ bp["wo"]
+    with jax.named_scope("dit.out_proj"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
+        return o @ bp["wo"]
 
 
 def _cross_attention(bp: dict, cfg: DiTConfig, x: jax.Array,
@@ -301,13 +303,22 @@ def _block_forward(bp: dict, cfg: DiTConfig, x, text, t_emb,
     if mod is None:
         mod = (t_emb @ bp["ada"]["w"].astype(jnp.float32)
                + bp["ada"]["b"].astype(jnp.float32))
-    sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod.astype(x.dtype), 6, axis=-1)
-    h = _modulate(L.layernorm(bp["ln1"], x), sh1, sc1)
-    x = x + g1[:, None, :] * _self_attention(bp, cfg, h)
-    x = x + _cross_attention(bp, cfg, L.layernorm(bp["ln_x"], x), text, kv)
-    h2 = _modulate(L.layernorm(bp["ln2"], x), sh2, sc2)
-    x = x + g2[:, None, :] * L.mlp(bp["mlp"], h2, activation="gelu")
-    return x
+    with jax.named_scope("dit.modulate"):
+        sh1, sc1, g1, sh2, sc2, g2 = jnp.split(mod.astype(x.dtype), 6,
+                                               axis=-1)
+        h = _modulate(L.layernorm(bp["ln1"], x), sh1, sc1)
+    y = _self_attention(bp, cfg, h)
+    with jax.named_scope("dit.modulate"):
+        x = x + g1[:, None, :] * y
+    with jax.named_scope("dit.cross_attn"):
+        x = x + _cross_attention(bp, cfg, L.layernorm(bp["ln_x"], x), text,
+                                 kv)
+    with jax.named_scope("dit.modulate"):
+        h2 = _modulate(L.layernorm(bp["ln2"], x), sh2, sc2)
+    with jax.named_scope("dit.mlp"):
+        y = L.mlp(bp["mlp"], h2, activation="gelu")
+    with jax.named_scope("dit.modulate"):
+        return x + g2[:, None, :] * y
 
 
 def dit_forward(params: dict, cfg: DiTConfig, latents: jax.Array,
@@ -355,8 +366,9 @@ def dit_forward(params: dict, cfg: DiTConfig, latents: jax.Array,
                + params["final_ada"]["b"].astype(jnp.float32))
     else:
         mod = mods["final"]
-    sh, sc = jnp.split(mod.astype(x.dtype), 2, axis=-1)
-    x = _modulate(L.layernorm(params["final_ln"], x), sh, sc)
+    with jax.named_scope("dit.modulate"):
+        sh, sc = jnp.split(mod.astype(x.dtype), 2, axis=-1)
+        x = _modulate(L.layernorm(params["final_ln"], x), sh, sc)
     return (x @ params["patch_out"]["w"] + params["patch_out"]["b"]) \
         .astype(jnp.float32)
 

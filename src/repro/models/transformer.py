@@ -287,9 +287,11 @@ def forward(params: dict, cfg: ModelConfig, tokens=None, *,
 
 def logits_from_hidden(params: dict, cfg: ModelConfig, hidden):
     """Unembed hidden states to vocab logits (tied or untied head)."""
-    if cfg.tie_embeddings:
-        return L.unembed(params["embed"], hidden)
-    return hidden.astype(jnp.float32) @ params["lm_head"].astype(jnp.float32)
+    with jax.named_scope("lm.head"):
+        if cfg.tie_embeddings:
+            return L.unembed(params["embed"], hidden)
+        return (hidden.astype(jnp.float32)
+                @ params["lm_head"].astype(jnp.float32))
 
 
 # ===========================================================================
@@ -654,18 +656,22 @@ def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn):
     (distributed/shard_paged.replicate)."""
     from repro.distributed.shard_paged import replicate
     x = replicate(x, cfg.mesh)
-    h = L.rmsnorm(lp["ln1"], x)
+    with jax.named_scope("lm.norm"):
+        h = L.rmsnorm(lp["ln1"], x)
     key = KIND_CACHE_KEY[kind]
-    y, c = mix_fn(kind, lp, h, lc[key])
+    with jax.named_scope("lm.attn"):
+        y, c = mix_fn(kind, lp, h, lc[key])
     x = replicate(x + y, cfg.mesh)
     if kind in ("mlstm", "slstm"):
         return x, {key: c}
-    h2 = L.rmsnorm(lp["ln2"], x)
-    if kind.endswith("moe"):
-        y2, _ = MOE.moe_ffn(lp["moe"], h2, cfg.moe, ep_axis=cfg.ep_axis)
-        x = x + y2
-    else:
-        x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
+    with jax.named_scope("lm.norm"):
+        h2 = L.rmsnorm(lp["ln2"], x)
+    with jax.named_scope("lm.mlp"):
+        if kind.endswith("moe"):
+            y2, _ = MOE.moe_ffn(lp["moe"], h2, cfg.moe, ep_axis=cfg.ep_axis)
+            x = x + y2
+        else:
+            x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
     return x, {key: c}
 
 
@@ -691,9 +697,15 @@ def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn):
             new_gc[f"l{i}"] = lc
         return x, new_gc
 
-    x, new_groups = maps.scan(body, x, (params["groups"], caches["groups"]))
+    # ops of the scan outside the layer body's scopes are the loop's own:
+    # slicing each layer's weights and pool out of the stacked carry and
+    # writing the updated pool back
+    with jax.named_scope("lm.layers"):
+        x, new_groups = maps.scan(body, x, (params["groups"],
+                                            caches["groups"]))
     caches["groups"] = new_groups
-    return L.rmsnorm(params["final_norm"], x), caches
+    with jax.named_scope("lm.norm"):
+        return L.rmsnorm(params["final_norm"], x), caches
 
 
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens, caches, *,

@@ -49,6 +49,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # attn_impl -> models/dit.DiTConfig.sla2_impl.  'fused' is the Pallas
 # block-sparse flash kernel, 'gather' the jnp gathered-tiles parity
@@ -201,8 +202,8 @@ def _step_fns(model):
         return model.__dict__["_diffusion_fns"]
 
     @jax.jit
-    def step(params, lat, kv_k, kv_v, mods_b, mods_f, step_idx, dt,
-             active):
+    def dit_denoise_step(params, lat, kv_k, kv_v, mods_b, mods_f, step_idx,
+                         dt, active):
         bi = jnp.arange(lat.shape[0])
         mods = {"blocks": mods_b[:, bi, step_idx],   # (L, B, 6d)
                 "final": mods_f[bi, step_idx]}       # (B, 2d)
@@ -211,9 +212,15 @@ def _step_fns(model):
                      "text_kv": (kv_k, kv_v), "mods": mods}, None)
         return jnp.where(active[:, None, None], x, lat)
 
-    fns = (step,
-           jax.jit(model.precompute_text_kv),
-           jax.jit(model.precompute_step_mods))
+    @jax.jit
+    def dit_text_kv(params, text):
+        return model.precompute_text_kv(params, text)
+
+    @jax.jit
+    def dit_step_mods(params, t):
+        return model.precompute_step_mods(params, t)
+
+    fns = (dit_denoise_step, dit_text_kv, dit_step_mods)
     model.__dict__["_diffusion_fns"] = fns
     return fns
 
@@ -302,53 +309,57 @@ class DiffusionEngine:
 
     def _admit(self) -> None:
         for slot, req in self.scheduler.admit():
-            req.t_admit = self._clock
-            self._latents = self._latents.at[slot].set(
-                jnp.asarray(req.latents, jnp.float32))
-            kk, vv = self._kv_fn(self.params,
-                                 jnp.asarray(req.text)[None])
-            self._kv_k = self._kv_k.at[:, slot].set(kk[:, 0])
-            self._kv_v = self._kv_v.at[:, slot].set(vv[:, 0])
-            sched = jnp.asarray(
-                _timestep_schedule(req.n_steps, self.cfg.max_steps))
-            mods = self._mods_fn(self.params, sched)
-            self._mods_b = self._mods_b.at[:, slot].set(mods["blocks"])
-            self._mods_f = self._mods_f.at[slot].set(mods["final"])
-            self._dt[slot] = 1.0 / req.n_steps
-            self.stats["admitted"] += 1
+            with TraceAnnotation("dit.admit", uid=req.uid):
+                req.t_admit = self._clock
+                self._latents = self._latents.at[slot].set(
+                    jnp.asarray(req.latents, jnp.float32))
+                kk, vv = self._kv_fn(self.params,
+                                     jnp.asarray(req.text)[None])
+                self._kv_k = self._kv_k.at[:, slot].set(kk[:, 0])
+                self._kv_v = self._kv_v.at[:, slot].set(vv[:, 0])
+                sched = jnp.asarray(
+                    _timestep_schedule(req.n_steps, self.cfg.max_steps))
+                mods = self._mods_fn(self.params, sched)
+                self._mods_b = self._mods_b.at[:, slot].set(mods["blocks"])
+                self._mods_f = self._mods_f.at[slot].set(mods["final"])
+                self._dt[slot] = 1.0 / req.n_steps
+                self.stats["admitted"] += 1
 
     def step(self) -> List[VideoRequest]:
         """Admit + one batched denoise dispatch.  Returns the requests
         that completed their final step this engine step (their
         ``output`` is filled and their slot freed)."""
-        self._admit()
-        active_slots = sorted(self.scheduler.active)
-        if not active_slots:
-            return []
-        s = self.cfg.max_slots
-        active = np.zeros((s,), bool)
-        step_idx = np.zeros((s,), np.int32)
-        for slot in active_slots:
-            active[slot] = True
-            step_idx[slot] = self.scheduler.active[slot].steps_done
-        self._latents = self._step_fn(
-            self.params, self._latents, self._kv_k, self._kv_v,
-            self._mods_b, self._mods_f, jnp.asarray(step_idx),
-            jnp.asarray(self._dt), jnp.asarray(active))
-        self._clock += 1
-        self.stats["engine_steps"] += 1
-        self.stats["denoise_steps"] += len(active_slots)
-        self.stats["occupancy_sum"] += len(active_slots)
-        done = []
-        finished = self.scheduler.advance(active_slots)
-        if finished:
-            lat = np.asarray(self._latents)   # one device->host copy
-            for slot, req in finished:
-                req.output = lat[slot].copy()
-                req.t_finish = self._clock
-                self.stats["completed"] += 1
-                done.append(req)
-        return done
+        with TraceAnnotation("dit.step"):
+            self._admit()
+            active_slots = sorted(self.scheduler.active)
+            if not active_slots:
+                return []
+            s = self.cfg.max_slots
+            active = np.zeros((s,), bool)
+            step_idx = np.zeros((s,), np.int32)
+            for slot in active_slots:
+                active[slot] = True
+                step_idx[slot] = self.scheduler.active[slot].steps_done
+            with TraceAnnotation("dit.dispatch", rows=len(active_slots)):
+                self._latents = self._step_fn(
+                    self.params, self._latents, self._kv_k, self._kv_v,
+                    self._mods_b, self._mods_f, jnp.asarray(step_idx),
+                    jnp.asarray(self._dt), jnp.asarray(active))
+            self._clock += 1
+            self.stats["engine_steps"] += 1
+            self.stats["denoise_steps"] += len(active_slots)
+            self.stats["occupancy_sum"] += len(active_slots)
+            done = []
+            finished = self.scheduler.advance(active_slots)
+            if finished:
+                with TraceAnnotation("dit.latents_to_host"):
+                    lat = np.asarray(self._latents)   # one device->host copy
+                for slot, req in finished:
+                    req.output = lat[slot].copy()
+                    req.t_finish = self._clock
+                    self.stats["completed"] += 1
+                    done.append(req)
+            return done
 
     def run_to_completion(self, max_steps: int = 100_000,
                           livelock_after: int = 1_000

@@ -49,6 +49,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -529,7 +530,9 @@ class ServeEngine:
                       "host_failures": 0, "reshards": 0,
                       # pool-pressure / swap telemetry, refreshed each step
                       "swap_bytes": 0, "min_available": num_pages - 1,
-                      "pool_peak_pages": 0}
+                      "pool_peak_pages": 0,
+                      # bytes of every logits array the host pulled
+                      "logits_to_host_bytes": 0}
         self.mesh = mesh
         self.monitor = None
         if mesh is not None:
@@ -598,46 +601,63 @@ class ServeEngine:
                 caches, shardlib.logical_to_shardings(
                     shardlib.cache_specs(caches, mesh), mesh))
 
+        # every program has a function name of its own, so a profiler
+        # trace shows each as its own module (jit_serve_decode, ...)
         if not hasattr(model, "_paged_step_fns"):
-            model._paged_step_fns = (
-                jax.jit(lambda p, b, c:
-                        (lambda o, cc: (o, pin(cc)))(
-                            *model.prefill_chunk(p, b, c))),
-                jax.jit(lambda p, b, c:
-                        (lambda o, cc: (o, pin(cc)))(
-                            *model.decode_paged(p, b, c))))
+            def serve_prefill_chunk(p, b, c):
+                o, cc = model.prefill_chunk(p, b, c)
+                return o, pin(cc)
+
+            def serve_decode(p, b, c):
+                o, cc = model.decode_paged(p, b, c)
+                return o, pin(cc)
+
+            model._paged_step_fns = (jax.jit(serve_prefill_chunk),
+                                     jax.jit(serve_decode))
         self._prefill_fn, self._decode_fn = model._paged_step_fns
         if model.swap_out is not None:
             if not hasattr(model, "_swap_fns"):
-                model._swap_fns = (
-                    jax.jit(model.swap_out),
-                    jax.jit(lambda c, row, slot, st:
-                            pin(model.swap_in(c, row, slot, st))))
+                def serve_swap_out(c, row, slot):
+                    return model.swap_out(c, row, slot)
+
+                def serve_swap_in(c, row, slot, st):
+                    return pin(model.swap_in(c, row, slot, st))
+
+                model._swap_fns = (jax.jit(serve_swap_out),
+                                   jax.jit(serve_swap_in))
             self._swap_out_fn, self._swap_in_fn = model._swap_fns
         else:
             self._swap_out_fn = self._swap_in_fn = None
         if self._spec:
             if not hasattr(model, "_spec_step_fns"):
+                def serve_verify(p, b, c):
+                    o, cc = model.decode_verify(p, b, c)
+                    return o, pin(cc)
+
+                def serve_commit(c, pt, ln, acc, act, w):
+                    return pin(model.commit_window(c, pt, ln, acc, act, w))
+
                 model._spec_step_fns = (
-                    jax.jit(lambda p, b, c:
-                            (lambda o, cc: (o, pin(cc)))(
-                                *model.decode_verify(p, b, c))),
-                    jax.jit(lambda c, pt, ln, acc, act, w:
-                            pin(model.commit_window(c, pt, ln, acc, act,
-                                                    w)),
-                            static_argnums=(5,)))
+                    jax.jit(serve_verify),
+                    jax.jit(serve_commit, static_argnums=(5,)))
             self._verify_fn, self._commit_fn = model._spec_step_fns
             if self.cfg.speculative == "linear":
                 from repro.serve.speculative import LinearDrafter
                 self._drafter = LinearDrafter(model, self.cfg.temperature)
         if self._pcache is not None:
             if not hasattr(model, "_prefix_fns"):
-                model._prefix_fns = (
-                    jax.jit(model.extract_totals),
-                    jax.jit(lambda c, slot, st:
-                            pin(model.insert_totals(c, slot, st))),
-                    jax.jit(lambda c, src, dst:
-                            pin(model.copy_page(c, src, dst))))
+                def serve_extract_totals(c, slot):
+                    return model.extract_totals(c, slot)
+
+                def serve_insert_totals(c, slot, st):
+                    return pin(model.insert_totals(c, slot, st))
+
+                def serve_copy_page(c, src, dst):
+                    return pin(model.copy_page(c, src, dst))
+
+                model._prefix_fns = (jax.jit(serve_extract_totals),
+                                     jax.jit(serve_insert_totals),
+                                     jax.jit(serve_copy_page))
             (self._extract_totals_fn, self._insert_totals_fn,
              self._copy_page_fn) = model._prefix_fns
 
@@ -1142,6 +1162,36 @@ class ServeEngine:
         slot = self._prefill_order[0]
         s = self._slots[slot]
         n_chunk = min(self.chunk, len(s.tokens) - s.pos)
+        with TraceAnnotation("serve.prefill", uid=s.req.uid, offset=s.pos,
+                             n=n_chunk):
+            logits = self._prefill_chunk(slot, s, n_chunk)
+        if logits is None or s.pos < len(s.tokens):
+            return
+        # prompt done: first token
+        if self._pcache is not None:
+            self._insert_prefix(slot, s)
+        self._prefill_order.pop(0)
+        if s.replay:
+            # recompute-resume: everything after the prompt was already
+            # sampled before preemption; start teacher-forcing it back
+            # through the decode path (budget was saved at preemption)
+            s.last_token = s.replay.pop(0)
+            s.decoding = True
+            return
+        logits = self._logits_to_host(logits)
+        with TraceAnnotation("serve.sample"):
+            tok = int(self._sample(logits)[0])
+            s.req.output.append(tok)
+            s.last_token = tok
+            s.budget = s.req.max_new_tokens - 1
+            s.decoding = True
+            if s.budget <= 0 or (s.req.eos_id is not None
+                                 and tok == s.req.eos_id):
+                self._finish(slot)
+
+    def _prefill_chunk(self, slot: int, s: _Slot, n_chunk: int):
+        """Pages, batch and dispatch of one prefill chunk; the chunk's
+        logits (on the device), or None when the slot self-preempted."""
         lo = s.pos // self.page_size
         hi = (s.pos + n_chunk - 1) // self.page_size
         if lo < s.n_shared:
@@ -1154,10 +1204,10 @@ class ServeEngine:
             s.n_shared = lo
             for lg in range(lo, end):
                 if not self._cow_page(slot, lg):
-                    return                  # self-preempted; resumes later
+                    return None             # self-preempted; resumes later
         for lg in range(lo, hi + 1):
             if not self._ensure_page(slot, lg):
-                return                      # self-preempted; resumes later
+                return None                 # self-preempted; resumes later
         tokens = np.zeros((1, self.chunk), np.int32)
         tokens[0, :n_chunk] = s.tokens[s.pos:s.pos + n_chunk]
         batch = {
@@ -1180,25 +1230,15 @@ class ServeEngine:
                 jax.device_get(self._extract_totals_fn(
                     self.caches, jnp.asarray(slot, jnp.int32)))
                 if self._slot_state else None)
-        if s.pos == len(s.tokens):          # prompt done: first token
-            if self._pcache is not None:
-                self._insert_prefix(slot, s)
-            self._prefill_order.pop(0)
-            if s.replay:
-                # recompute-resume: everything after the prompt was already
-                # sampled before preemption; start teacher-forcing it back
-                # through the decode path (budget was saved at preemption)
-                s.last_token = s.replay.pop(0)
-                s.decoding = True
-                return
-            tok = int(self._sample(np.asarray(logits))[0])
-            s.req.output.append(tok)
-            s.last_token = tok
-            s.budget = s.req.max_new_tokens - 1
-            s.decoding = True
-            if s.budget <= 0 or (s.req.eos_id is not None
-                                 and tok == s.req.eos_id):
-                self._finish(slot)
+        return logits
+
+    def _logits_to_host(self, logits) -> np.ndarray:
+        """The blocking device->host copy of a step's logits, counted in
+        ``stats['logits_to_host_bytes']``."""
+        with TraceAnnotation("serve.logits_to_host"):
+            out = np.asarray(logits)
+        self.stats["logits_to_host_bytes"] += out.nbytes
+        return out
 
     def _emit(self, slot: int, tok: int) -> bool:
         """Record one generated token for a slot; returns False once the
@@ -1263,78 +1303,84 @@ class ServeEngine:
         w = self.cfg.draft_len + 1
         dec = sorted((s for s, st in self._slots.items() if st.decoding),
                      key=lambda s: self._slots[s].req.arrival)
-        ready = []
-        for slot in dec:
-            if slot not in self._slots:     # preempted by an older slot
-                continue
-            s = self._slots[slot]
-            wlen = self._window_len(s)
-            pos0 = int(self._lengths[slot])
-            ok = True
-            for lg in range(pos0 // self.page_size,
-                            (pos0 + wlen - 1) // self.page_size + 1):
-                if not self._ensure_page(slot, lg):
-                    ok = False              # self-preempted mid-window
-                    break
-            if ok and slot in self._slots:
-                ready.append(slot)
-        ready = [s for s in ready if s in self._slots]
-        if not ready:
+        if not dec:
             return
-        tokens = np.zeros((self.cfg.max_slots, w), np.int32)
-        wlens = np.zeros((self.cfg.max_slots,), np.int32)
-        active = np.zeros((self.cfg.max_slots,), bool)
-        draft_slots = []
-        for slot in ready:
-            s = self._slots[slot]
-            wlen = self._window_len(s)
-            tokens[slot, 0] = s.last_token
-            wlens[slot] = wlen
-            active[slot] = True
-            if s.replay:
-                tokens[slot, 1:wlen] = s.replay[:wlen - 1]
-            elif wlen > 1:
-                draft_slots.append(slot)
-        d_logits = None
-        if draft_slots:
-            d_toks, d_logits = self._draft(tokens[:, 0], active)
-            for slot in draft_slots:
-                k_i = int(wlens[slot]) - 1
-                tokens[slot, 1:1 + k_i] = d_toks[slot, :k_i]
-        batch = {
-            "tokens": jnp.asarray(tokens),
-            "page_table": jnp.asarray(self._page_table),
-            "lengths": jnp.asarray(self._lengths),
-            "active": jnp.asarray(active),
-            "window_len": jnp.asarray(wlens),
-        }
-        logits, self.caches = self._verify_fn(self.params, batch,
-                                              self.caches)
-        logits = np.asarray(logits)         # (B, W, V)
+        with TraceAnnotation("serve.decode", rows=len(dec)):
+            ready = []
+            for slot in dec:
+                if slot not in self._slots:     # preempted by an older slot
+                    continue
+                s = self._slots[slot]
+                wlen = self._window_len(s)
+                pos0 = int(self._lengths[slot])
+                ok = True
+                for lg in range(pos0 // self.page_size,
+                                (pos0 + wlen - 1) // self.page_size + 1):
+                    if not self._ensure_page(slot, lg):
+                        ok = False              # self-preempted mid-window
+                        break
+                if ok and slot in self._slots:
+                    ready.append(slot)
+            ready = [s for s in ready if s in self._slots]
+            if not ready:
+                return
+            tokens = np.zeros((self.cfg.max_slots, w), np.int32)
+            wlens = np.zeros((self.cfg.max_slots,), np.int32)
+            active = np.zeros((self.cfg.max_slots,), bool)
+            draft_slots = []
+            for slot in ready:
+                s = self._slots[slot]
+                wlen = self._window_len(s)
+                tokens[slot, 0] = s.last_token
+                wlens[slot] = wlen
+                active[slot] = True
+                if s.replay:
+                    tokens[slot, 1:wlen] = s.replay[:wlen - 1]
+                elif wlen > 1:
+                    draft_slots.append(slot)
+            d_logits = None
+            if draft_slots:
+                with TraceAnnotation("serve.draft", rows=len(draft_slots)):
+                    d_toks, d_logits = self._draft(tokens[:, 0], active)
+                for slot in draft_slots:
+                    k_i = int(wlens[slot]) - 1
+                    tokens[slot, 1:1 + k_i] = d_toks[slot, :k_i]
+            batch = {
+                "tokens": jnp.asarray(tokens),
+                "page_table": jnp.asarray(self._page_table),
+                "lengths": jnp.asarray(self._lengths),
+                "active": jnp.asarray(active),
+                "window_len": jnp.asarray(wlens),
+            }
+            logits, self.caches = self._verify_fn(self.params, batch,
+                                                  self.caches)
+        logits = self._logits_to_host(logits)     # (B, W, V)
 
         # --- host-side acceptance (greedy == plain decode, token-exact) --
         accepted = np.zeros((self.cfg.max_slots,), np.int32)
         plan = {}
         self.stats["spec_steps"] += 1
-        for slot in ready:
-            s = self._slots[slot]
-            wlen = int(wlens[slot])
-            if s.replay:
-                # teacher-forced rows are correct by construction: cache
-                # the whole fed window (bit-identical recompute-resume)
-                plan[slot] = ("replay", wlen - 1)
-                accepted[slot] = wlen
-            else:
-                k_i = wlen - 1
-                emitted, n_acc = speclib.rejection_sample(
-                    tokens[slot, 1:1 + k_i],
-                    None if d_logits is None else d_logits[slot, :k_i],
-                    logits[slot, :k_i + 1],
-                    temperature=self.cfg.temperature, rng=self._rng)
-                plan[slot] = ("emit", emitted)
-                accepted[slot] = n_acc + 1
-                self.stats["spec_drafted"] += k_i
-                self.stats["spec_accepted"] += n_acc
+        with TraceAnnotation("serve.sample"):
+            for slot in ready:
+                s = self._slots[slot]
+                wlen = int(wlens[slot])
+                if s.replay:
+                    # teacher-forced rows are correct by construction:
+                    # cache the whole fed window (bit-identical
+                    # recompute-resume)
+                    plan[slot] = ("replay", wlen - 1)
+                    accepted[slot] = wlen
+                else:
+                    k_i = wlen - 1
+                    emitted, n_acc = speclib.rejection_sample(
+                        tokens[slot, 1:1 + k_i],
+                        None if d_logits is None else d_logits[slot, :k_i],
+                        logits[slot, :k_i + 1],
+                        temperature=self.cfg.temperature, rng=self._rng)
+                    plan[slot] = ("emit", emitted)
+                    accepted[slot] = n_acc + 1
+                    self.stats["spec_drafted"] += k_i
+                    self.stats["spec_accepted"] += n_acc
 
         # --- commit the accepted prefixes, then advance lengths ---
         # snapshot the host arrays: the commit dispatch is ASYNC and
@@ -1342,32 +1388,34 @@ class ServeEngine:
         # below (and the next step's bookkeeping) mutate page table and
         # lengths — without the copies the in-flight commit may read the
         # advanced values (a rarely-losing data race)
-        self.caches = self._commit_fn(
-            self.caches, jnp.asarray(self._page_table.copy()),
-            jnp.asarray(self._lengths.copy()), jnp.asarray(accepted),
-            jnp.asarray(active), w)
-        for slot in ready:
-            self._lengths[slot] += int(accepted[slot])
+        with TraceAnnotation("serve.commit"):
+            self.caches = self._commit_fn(
+                self.caches, jnp.asarray(self._page_table.copy()),
+                jnp.asarray(self._lengths.copy()), jnp.asarray(accepted),
+                jnp.asarray(active), w)
+            for slot in ready:
+                self._lengths[slot] += int(accepted[slot])
 
         # --- apply emissions / replay bookkeeping ---
-        for slot in ready:
-            s = self._slots[slot]
-            kind, payload = plan[slot]
-            if kind == "replay":
-                m = payload
-                del s.replay[:m]
-                if s.replay:
-                    s.last_token = s.replay.pop(0)
+        with TraceAnnotation("serve.sample"):
+            for slot in ready:
+                s = self._slots[slot]
+                kind, payload = plan[slot]
+                if kind == "replay":
+                    m = payload
+                    del s.replay[:m]
+                    if s.replay:
+                        s.last_token = s.replay.pop(0)
+                    else:
+                        # replay drained inside the window: the next REAL
+                        # token comes from the last teacher-forced row
+                        s.replay = None
+                        t = int(self._sample(logits[slot, m][None])[0])
+                        self._emit(slot, t)
                 else:
-                    # replay drained inside the window: the next REAL
-                    # token comes from the last teacher-forced row
-                    s.replay = None
-                    t = int(self._sample(logits[slot, m][None])[0])
-                    self._emit(slot, t)
-            else:
-                for t in payload:
-                    if not self._emit(slot, t):
-                        break
+                    for t in payload:
+                        if not self._emit(slot, t):
+                            break
 
     def _decode_step_single(self):
         """One token for every decoding slot.  Page demand is served oldest
@@ -1375,54 +1423,62 @@ class ServeEngine:
         drop out of this step and resume via the scheduler)."""
         dec = sorted((s for s, st in self._slots.items() if st.decoding),
                      key=lambda s: self._slots[s].req.arrival)
-        ready = []
-        for slot in dec:
-            if slot not in self._slots:     # preempted by an older slot
-                continue
-            if self._lengths[slot] % self.page_size == 0 and \
-                    not self._ensure_page(
-                        slot, int(self._lengths[slot]) // self.page_size):
-                continue                    # self-preempted
-            ready.append(slot)
-        if not ready:
+        if not dec:
             return
-        tokens = np.zeros((self.cfg.max_slots,), np.int32)
-        active = np.zeros((self.cfg.max_slots,), bool)
-        for slot in ready:
-            tokens[slot] = self._slots[slot].last_token
-            active[slot] = True
-        batch = {
-            "token": jnp.asarray(tokens),
-            "page_table": jnp.asarray(self._page_table),
-            "lengths": jnp.asarray(self._lengths),
-            "active": jnp.asarray(active),
-        }
-        logits, self.caches = self._decode_fn(self.params, batch, self.caches)
-        tok = self._sample(np.asarray(logits))
-        for slot in ready:
-            st = self._slots[slot]
-            self._lengths[slot] += 1        # input token entered the cache
-            if st.replay:
-                # recompute catch-up: the sampled token is discarded — the
-                # real one was sampled before preemption and is next in line
-                st.last_token = st.replay.pop(0)
-                continue
-            self._emit(slot, int(tok[slot]))
+        with TraceAnnotation("serve.decode", rows=len(dec)):
+            ready = []
+            for slot in dec:
+                if slot not in self._slots:     # preempted by an older slot
+                    continue
+                if self._lengths[slot] % self.page_size == 0 and \
+                        not self._ensure_page(
+                            slot, int(self._lengths[slot]) // self.page_size):
+                    continue                    # self-preempted
+                ready.append(slot)
+            if not ready:
+                return
+            tokens = np.zeros((self.cfg.max_slots,), np.int32)
+            active = np.zeros((self.cfg.max_slots,), bool)
+            for slot in ready:
+                tokens[slot] = self._slots[slot].last_token
+                active[slot] = True
+            batch = {
+                "token": jnp.asarray(tokens),
+                "page_table": jnp.asarray(self._page_table),
+                "lengths": jnp.asarray(self._lengths),
+                "active": jnp.asarray(active),
+            }
+            logits, self.caches = self._decode_fn(self.params, batch,
+                                                  self.caches)
+        logits = self._logits_to_host(logits)
+        with TraceAnnotation("serve.sample"):
+            tok = self._sample(logits)
+            for slot in ready:
+                st = self._slots[slot]
+                self._lengths[slot] += 1    # input token entered the cache
+                if st.replay:
+                    # recompute catch-up: the sampled token is discarded —
+                    # the real one was sampled before preemption and is
+                    # next in line
+                    st.last_token = st.replay.pop(0)
+                    continue
+                self._emit(slot, int(tok[slot]))
 
     def _finish(self, slot: int):
         s = self._slots[slot]
-        if s.pinned_node is not None:
-            self._pcache.unpin(s.pinned_node)
-            s.pinned_node = None
-        self.allocator.free(self._page_table[slot][
-            self._page_table[slot] > 0])
-        self._page_table[slot] = 0
-        self._lengths[slot] = 0
-        req = self._slots.pop(slot).req
-        req.t_finish = time.perf_counter()
-        self.completed.append(req)
-        if slot in self._prefill_order:
-            self._prefill_order.remove(slot)
+        with TraceAnnotation("serve.finish", uid=s.req.uid):
+            if s.pinned_node is not None:
+                self._pcache.unpin(s.pinned_node)
+                s.pinned_node = None
+            self.allocator.free(self._page_table[slot][
+                self._page_table[slot] > 0])
+            self._page_table[slot] = 0
+            self._lengths[slot] = 0
+            req = self._slots.pop(slot).req
+            req.t_finish = time.perf_counter()
+            self.completed.append(req)
+            if slot in self._prefill_order:
+                self._prefill_order.remove(slot)
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -1432,16 +1488,18 @@ class ServeEngine:
         occupied slots.  Steps that had work to do are counted in
         ``stats['engine_steps']`` (trailing no-op calls are not) — the
         benchmarks' deterministic throughput denominator."""
-        if self._slots or self._queue:
-            self.stats["engine_steps"] += 1
-        self._admit()
-        self._prefill_step()
-        self._decode_step()
-        self.stats["swap_bytes"] = self.swap.used_bytes
-        self.stats["min_available"] = self.allocator.min_available
-        self.stats["pool_peak_pages"] = (self.allocator.num_pages - 1
-                                         - self.allocator.min_available)
-        return len(self._slots)
+        with TraceAnnotation("serve.step"):
+            if self._slots or self._queue:
+                self.stats["engine_steps"] += 1
+            with TraceAnnotation("serve.admit"):
+                self._admit()
+            self._prefill_step()
+            self._decode_step()
+            self.stats["swap_bytes"] = self.swap.used_bytes
+            self.stats["min_available"] = self.allocator.min_available
+            self.stats["pool_peak_pages"] = (self.allocator.num_pages - 1
+                                             - self.allocator.min_available)
+            return len(self._slots)
 
     def run_to_completion(self, max_steps: int = 10_000,
                           livelock_after: int = 50) -> list[Request]:
@@ -1490,9 +1548,14 @@ def _static_fns(model):
     """Jitted prefill/decode for the static cache path, cached on the model
     (prefill re-traces per prompt length)."""
     if not hasattr(model, "_static_step_fns"):
-        model._static_step_fns = (
-            jax.jit(lambda p, b, c: model.prefill(p, b, c)),
-            jax.jit(lambda p, b, c: model.decode(p, b, c)))
+        def static_prefill(p, b, c):
+            return model.prefill(p, b, c)
+
+        def static_decode(p, b, c):
+            return model.decode(p, b, c)
+
+        model._static_step_fns = (jax.jit(static_prefill),
+                                  jax.jit(static_decode))
     return model._static_step_fns
 
 
