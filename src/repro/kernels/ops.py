@@ -145,6 +145,26 @@ def row_groups(rows: int, words_per_row: int) -> list[tuple[int, int]]:
     return [(s, g) for s in range(0, rows, g)]
 
 
+# The sparse-branch forward copies each grid step's routed K and V tiles
+# into double-buffered VMEM scratch (v5e's default scoped VMEM limit is
+# 16 MiB); these buffers stay within this budget.  Its loop over a step's
+# tiles is unrolled, so the tile count is also capped to bound code size.
+VMEM_KV_BUFFER_BYTES = 4 * 1024 * 1024
+MAX_KV_TILES_PER_STEP = 64
+
+
+def kv_tiles_per_step(k_sel: int, block_k: int, d: int, dtype) -> int:
+    """Routed key blocks one sparse-forward grid step walks (G): all
+    ``k_sel`` where their double-buffered K and V tiles fit
+    VMEM_KV_BUFFER_BYTES and MAX_KV_TILES_PER_STEP, else the fewest equal
+    chunks that do."""
+    tile_bytes = 2 * 2 * block_k * d * jnp.dtype(dtype).itemsize
+    cap = max(1, min(MAX_KV_TILES_PER_STEP,
+                     VMEM_KV_BUFFER_BYTES // tile_bytes))
+    chunks = -(-k_sel // cap)
+    return -(-k_sel // chunks)
+
+
 def stat_col(row):
     """A lane-dense ``(1, n)`` row of per-row statistics as the ``(n, 1)``
     column that broadcasts against an ``(n, x)`` tile."""

@@ -330,3 +330,88 @@ def test_sparse_flash_split_rows_match_one_call(monkeypatch, causal):
                                                     *split, do)
     for a, b in zip((*whole, *grads), (*split, *split_grads)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# Sparse forward: one grid step walks G routed key blocks, copied from HBM
+# into double-buffered VMEM; G follows the shapes against a VMEM budget
+# ---------------------------------------------------------------------------
+
+def test_kv_tiles_per_step_fits_budget():
+    from repro.kernels.ops import (MAX_KV_TILES_PER_STEP,
+                                   VMEM_KV_BUFFER_BYTES, kv_tiles_per_step)
+    # wan-dit-1.3b: 24 rows of 32,768 tokens, 128 x 64 blocks, d 128, bf16
+    assert kv_tiles_per_step(round(0.05 * 32768 / 64), 64, 128,
+                             jnp.bfloat16) == 26
+    for k_sel, bk, d, dt in [(1, 16, 32, jnp.float32), (26, 64, 128,
+                             jnp.float32), (102, 64, 128, jnp.bfloat16),
+                             (128, 64, 128, jnp.int8), (512, 16, 128,
+                             jnp.bfloat16), (410, 128, 256, jnp.float32),
+                             (7, 4096, 512, jnp.float32)]:
+        g = kv_tiles_per_step(k_sel, bk, d, dt)
+        tile_bytes = 2 * 2 * bk * d * jnp.dtype(dt).itemsize
+        cap = max(1, min(MAX_KV_TILES_PER_STEP,
+                         VMEM_KV_BUFFER_BYTES // tile_bytes))
+        assert 1 <= g <= min(k_sel, MAX_KV_TILES_PER_STEP)
+        assert g == 1 or g * tile_bytes <= VMEM_KV_BUFFER_BYTES
+        # as few chunks as the budget allows
+        assert -(-k_sel // g) == -(-k_sel // cap)
+
+
+def _kv_tiles(monkeypatch, g, block_k, d, dtype):
+    """Shrink the VMEM budget to ``g`` double-buffered K/V tiles."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "VMEM_KV_BUFFER_BYTES",
+                        g * 2 * 2 * block_k * d * jnp.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("g", [1, 2])
+@pytest.mark.parametrize("quant_bits", ["none", "int8"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_sparse_flash_fwd_same_for_any_g(monkeypatch, g, quant_bits, causal):
+    from repro.kernels.ops import kv_tiles_per_step
+    bh, n, d, bq, bk, kf = 2, 256, 32, 32, 16, 0.3
+    q, k, v = make_qkv(bh, n, d, seed=5)
+    if quant_bits != "none":
+        k = smooth_k(k)
+    idx, valid = route(q, k, bq, bk, kf, causal)
+    valid = valid.astype(jnp.int32)
+    k_sel = idx.shape[-1]
+    kw = dict(block_q=bq, block_k=bk, causal=causal, quant_bits=quant_bits)
+    assert kv_tiles_per_step(k_sel, bk, d, q.dtype) == k_sel
+    whole = sparse_flash_fwd(q, k, v, idx, valid, **kw)
+    _kv_tiles(monkeypatch, g, bk, d, q.dtype)
+    # G = 1 walks one block a step; G = 2 leaves the last of k_sel = 5
+    # chunks with a surplus entry
+    assert k_sel == 5 and kv_tiles_per_step(k_sel, bk, d, q.dtype) == g
+    chunked = _retraced(sparse_flash_fwd, **kw)(q, k, v, idx, valid)
+    for a, b in zip(whole, chunked):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("g", [None, 1])
+@pytest.mark.parametrize("dma", ["eager", "on_wait"])
+def test_sparse_flash_fwd_padding_starts_no_copy(monkeypatch, g, dma):
+    """Padding entries point past the keys: the TPU interpreter raises on
+    any copy they start, and reads NaN from a tile never copied in."""
+    from jax.experimental.pallas import tpu as pltpu
+    bh, n, d, bq, bk, kf = 2, 128, 32, 16, 16, 0.3
+    q, k, v = make_qkv(bh, n, d, seed=6)
+    idx, valid = route(q, k, bq, bk, kf, True)
+    assert not bool(valid.all())
+    poisoned = jnp.where(valid, idx, n // bk + 7)
+    if g:
+        _kv_tiles(monkeypatch, g, bk, d, q.dtype)
+    kw = dict(block_q=bq, block_k=bk, causal=True,
+              interpret=pltpu.InterpretParams(dma_execution_mode=dma))
+    fwd = _retraced(sparse_flash_fwd, **kw)
+    o, lse = fwd(q, k, v, poisoned, valid.astype(jnp.int32))
+    o_r, lse_r = kref.sparse_flash_ref(q, k, v, idx, valid, block_q=bq,
+                                       block_k=bk, causal=True)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_r),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_r),
+                               atol=2e-5, rtol=2e-5)
+    with pytest.raises(Exception, match="ut-of-bounds"):
+        jax.block_until_ready(fwd(q, k, v, poisoned,
+                                  jnp.ones_like(valid, jnp.int32)))

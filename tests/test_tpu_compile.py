@@ -94,6 +94,20 @@ def test_sparse_flash_fwd_compiles_at_dit_width(spec, quant_bits):
         quant_bits=quant_bits, interpret=False), x, x, x, sel, sel)
 
 
+@pytest.mark.parametrize("quant_bits", ["none", "int8", "fp8"])
+def test_sparse_flash_fwd_compiles_with_chunked_selection(spec, quant_bits):
+    """A causal training shape whose routed K/V tiles outgrow the VMEM
+    budget: each query block walks its key blocks over two grid steps."""
+    from repro.kernels.ops import kv_tiles_per_step
+    bh, k_sel = 4, DIT_N // DIT_BK // 4
+    assert kv_tiles_per_step(k_sel, DIT_BK, DIT_D, jnp.bfloat16) < k_sel
+    x = spec((bh, DIT_N, DIT_D), jnp.bfloat16)
+    sel = spec((bh, DIT_N // DIT_BQ, k_sel), jnp.int32)
+    _compile(lambda q, k, v, i, va: sparse_flash_fwd(
+        q, k, v, i, va, block_q=DIT_BQ, block_k=DIT_BK, causal=True,
+        quant_bits=quant_bits, interpret=False), x, x, x, sel, sel)
+
+
 def test_sparse_flash_bwd_compiles_at_dit_width(spec):
     x = spec((DIT_BH, DIT_N, DIT_D), jnp.bfloat16)
     sel = spec((DIT_BH, DIT_N // DIT_BQ, DIT_KSEL), jnp.int32)
