@@ -2,6 +2,13 @@
 vocab=102400, MLA kv_lora=512, MoE 64 routed experts top-6 + 2 shared,
 first layer dense FFN (d_ff=10944). [arXiv:2405.04434; hf]
 
+Routing as published: softmax over the 64 experts, greedy top-6, the
+gates not renormalised (``norm_topk_prob: false``, scaling factor 1).
+Serving runs the dropless expert layer (models/moe.moe_layer): no
+capacity, so no routed token is dropped.  ``held_experts`` /
+``expert_offset`` give one device's share of an expert-parallel layer
+(bench/configs/deepseek_v2_lite.json holds 8 of the 64).
+
 SLA2 runs in MLA **latent space** (models/mla.py): scores are computed with
 W_uk absorbed into the query, the router pools latent keys (pooling commutes
 with the linear decompression), and the linear branch's phi-features live on
@@ -22,7 +29,7 @@ def config(**overrides):
         mla=MLAConfig(kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
                       v_head_dim=128, q_lora_rank=0),
         moe=MoEConfig(num_experts=64, top_k=6, d_ff_expert=1408,
-                      num_shared=2, capacity_factor=1.25),
+                      num_shared=2),
         rope_theta=10_000.0, tie_embeddings=False,
         mechanism="sla2", max_target_len=524288, ep_axis="model",
     )

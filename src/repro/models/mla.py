@@ -432,9 +432,10 @@ def mla_prefill_chunk_paged(params: dict, x: jax.Array, cache: dict, *,
     s = jnp.where(vis, s, masklib.NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o_lat = jnp.einsum("bhnm,bmr->bhnr", p, k_all[..., :r])
-    w_uv = params["w_uv"].reshape(r, h, mcfg.v_head_dim)
-    o = jnp.einsum("bhnr,rhv->bnhv", o_lat, w_uv.astype(jnp.float32))
-    o = o.reshape(1, c, h * mcfg.v_head_dim).astype(x.dtype)
+    with jax.named_scope("mla.uv"):
+        w_uv = params["w_uv"].reshape(r, h, mcfg.v_head_dim)
+        o = jnp.einsum("bhnr,rhv->bnhv", o_lat, w_uv.astype(jnp.float32))
+        o = o.reshape(1, c, h * mcfg.v_head_dim).astype(x.dtype)
 
     # --- SLA2 block states for the chunk's blocks (from the page-read
     # view k_eff, so decode-time recompute from pages agrees exactly) ---
@@ -510,61 +511,68 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
                                                 0.0)
     cache["z_tot"] = cache["z_tot"] + jnp.where(upd[:, None], z_cur, 0.0)
 
-    # --- route per q head over pooled latent pages ---
-    sla2_p = params["sla2"]
-    rp = sla2_p.get("router", {})
-    qr = q1
-    pk = _lat_read(cache, "pooled_pages", page_table)[:, :, 0]  # (B,T,d_lat)
-    if rp:
-        qr = qr @ rp["proj_q"].astype(jnp.float32)
-        pk = pk @ rp["proj_k"].astype(jnp.float32)
-    scores = jnp.einsum("bhd,btd->bht", qr, pk) / jnp.sqrt(d_lat)
-    blk_ids = jnp.arange(t_n)
-    allowed = blk_ids[None, None, :] <= cur_blk[:, None, None]
-    scores = jnp.where(allowed, scores, masklib.NEG_INF)
-    scores = jnp.where(blk_ids[None, None, :] == cur_blk[:, None, None],
-                       jnp.inf, scores)
-    k_sel = max(1, round(k_frac * t_n))
-    top_vals, idx = jax.lax.top_k(scores, k_sel)        # (B, H, K_sel)
-    valid = top_vals > masklib.NEG_INF * 0.5
-    pt = jnp.broadcast_to(page_table[:, None, :], (b, h, t_n))
-    phys_sel = jnp.where(valid, jnp.take_along_axis(pt, idx, axis=2), 0)
-    complete_bound = cur_blk + jnp.where(completed, 1, 0)
-    selc = (valid & (idx < complete_bound[:, None, None])) \
-        .astype(jnp.float32)
+    with jax.named_scope("sla2.router"):
+        # --- route per q head over pooled latent pages ---
+        sla2_p = params["sla2"]
+        rp = sla2_p.get("router", {})
+        qr = q1
+        # (B, T, d_lat)
+        pk = _lat_read(cache, "pooled_pages", page_table)[:, :, 0]
+        if rp:
+            qr = qr @ rp["proj_q"].astype(jnp.float32)
+            pk = pk @ rp["proj_k"].astype(jnp.float32)
+        scores = jnp.einsum("bhd,btd->bht", qr, pk) / jnp.sqrt(d_lat)
+        blk_ids = jnp.arange(t_n)
+        allowed = blk_ids[None, None, :] <= cur_blk[:, None, None]
+        scores = jnp.where(allowed, scores, masklib.NEG_INF)
+        scores = jnp.where(blk_ids[None, None, :] == cur_blk[:, None, None],
+                           jnp.inf, scores)
+        k_sel = max(1, round(k_frac * t_n))
+        top_vals, idx = jax.lax.top_k(scores, k_sel)        # (B, H, K_sel)
+        valid = top_vals > masklib.NEG_INF * 0.5
+        pt = jnp.broadcast_to(page_table[:, None, :], (b, h, t_n))
+        phys_sel = jnp.where(valid, jnp.take_along_axis(pt, idx, axis=2), 0)
+        complete_bound = cur_blk + jnp.where(completed, 1, 0)
+        selc = (valid & (idx < complete_bound[:, None, None])) \
+            .astype(jnp.float32)
 
-    # --- sparse branch over gathered latent pages ---
-    kg = _lat_read(cache, "k_pages", phys_sel)[..., 0, :, :]  # (B,H,K,bk,d)
-    s = jnp.einsum("bhd,bhjkd->bhjk", q1, kg) / jnp.sqrt(d_lat)
-    pos = idx[..., None] * bk + jnp.arange(bk)[None, None, None, :]
-    vis = (pos < t_new[:, None, None, None]) & valid[..., None]
-    s = jnp.where(vis, s, masklib.NEG_INF)
-    p = jax.nn.softmax(s.reshape(b, h, -1), axis=-1).reshape(s.shape)
-    vg = kg[..., :r]
-    o_s = jnp.einsum("bhjk,bhjkr->bhr", p, vg)
+    with jax.named_scope("sla2.sparse"):
+        # --- sparse branch over gathered latent pages ---
+        # (B, H, K_sel, bk, d_lat)
+        kg = _lat_read(cache, "k_pages", phys_sel)[..., 0, :, :]
+        s = jnp.einsum("bhd,bhjkd->bhjk", q1, kg) / jnp.sqrt(d_lat)
+        pos = idx[..., None] * bk + jnp.arange(bk)[None, None, None, :]
+        vis = (pos < t_new[:, None, None, None]) & valid[..., None]
+        s = jnp.where(vis, s, masklib.NEG_INF)
+        p = jax.nn.softmax(s.reshape(b, h, -1), axis=-1).reshape(s.shape)
+        vg = kg[..., :r]
+        o_s = jnp.einsum("bhjk,bhjkr->bhr", p, vg)
 
-    # --- linear branch: totals minus selected complete blocks ---
-    qf = phi(q1)
-    kf_sel = phi(kg)
-    ls = jnp.einsum("bhd,bhjkd->bhjk", qf, kf_sel) * selc[..., None]
-    sub_num = jnp.einsum("bhjk,bhjkr->bhr", ls, vg)
-    sub_den = ls.sum(axis=(-1, -2))
-    den_tot = jnp.einsum("bhd,bd->bh", qf, cache["z_tot"])
-    num = jnp.einsum("bhd,bdr->bhr", qf, cache["h_tot"]) - sub_num
-    den = den_tot - sub_den
-    den = jnp.where(den > 1e-4 * den_tot + 1e-12, den, 0.0)[..., None]
-    o_l = jnp.where(den > 0, num / jnp.maximum(den, 1e-12), 0.0)
+    with jax.named_scope("sla2.linear"):
+        # --- linear branch: totals minus selected complete blocks ---
+        qf = phi(q1)
+        kf_sel = phi(kg)
+        ls = jnp.einsum("bhd,bhjkd->bhjk", qf, kf_sel) * selc[..., None]
+        sub_num = jnp.einsum("bhjk,bhjkr->bhr", ls, vg)
+        sub_den = ls.sum(axis=(-1, -2))
+        den_tot = jnp.einsum("bhd,bd->bh", qf, cache["z_tot"])
+        num = jnp.einsum("bhd,bdr->bhr", qf, cache["h_tot"]) - sub_num
+        den = den_tot - sub_den
+        den = jnp.where(den > 1e-4 * den_tot + 1e-12, den, 0.0)[..., None]
+        o_l = jnp.where(den > 0, num / jnp.maximum(den, 1e-12), 0.0)
 
-    a = jax.nn.sigmoid(sla2_p["alpha_logit"].astype(jnp.float32))
-    if a.shape[0] == 1 and h > 1:
-        a = jnp.broadcast_to(a, (h, a.shape[1]))
-    a_last = a[:, -1][None, :, None]
-    a_eff = jnp.where(den > 0, a_last, 1.0)
-    o_lat = a_eff * o_s + (1.0 - a_eff) * o_l           # (B, H, r)
+    with jax.named_scope("sla2.combine"):
+        a = jax.nn.sigmoid(sla2_p["alpha_logit"].astype(jnp.float32))
+        if a.shape[0] == 1 and h > 1:
+            a = jnp.broadcast_to(a, (h, a.shape[1]))
+        a_last = a[:, -1][None, :, None]
+        a_eff = jnp.where(den > 0, a_last, 1.0)
+        o_lat = a_eff * o_s + (1.0 - a_eff) * o_l           # (B, H, r)
 
-    w_uv = params["w_uv"].reshape(r, h, mcfg.v_head_dim)
-    o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv.astype(jnp.float32))
-    o = o.reshape(b, 1, h * mcfg.v_head_dim).astype(x_t.dtype)
+    with jax.named_scope("mla.uv"):
+        w_uv = params["w_uv"].reshape(r, h, mcfg.v_head_dim)
+        o = jnp.einsum("bhr,rhv->bhv", o_lat, w_uv.astype(jnp.float32))
+        o = o.reshape(b, 1, h * mcfg.v_head_dim).astype(x_t.dtype)
     return o @ params["w_o"], cache
 
 

@@ -398,7 +398,7 @@ def _layer_prefill(lp, cfg: ModelConfig, kind, x, lc, positions):
         return x + y, {"core": st}
     h2 = L.rmsnorm(lp["ln2"], x)
     if kind.endswith("moe"):
-        y, _ = MOE.moe_ffn(lp["moe"], h2, cfg.moe, ep_axis=cfg.ep_axis)
+        y, _ = MOE.moe_layer(lp["moe"], h2, cfg.moe)
         x = x + y
     else:
         x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
@@ -431,7 +431,7 @@ def _layer_decode(lp, cfg: ModelConfig, kind, x_t, lc):
         return x_t + y, {"core": st}
     h2 = L.rmsnorm(lp["ln2"], x_t)
     if kind.endswith("moe"):
-        y, _ = MOE.moe_ffn(lp["moe"], h2, cfg.moe, ep_axis=cfg.ep_axis)
+        y, _ = MOE.moe_layer(lp["moe"], h2, cfg.moe)
         x_t = x_t + y
     else:
         x_t = x_t + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
@@ -649,11 +649,16 @@ def copy_kv_page(cfg: ModelConfig, caches: dict, src, dst) -> dict:
     return new
 
 
-def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn):
+def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn, rows,
+                 stack=None):
     """Shared block body around a paged mixer call, dispatched on the layer
     kind; recurrent-core kinds (mlstm/slstm) have no ln2/FFN half.  On a
     serving mesh the residual stream stays whole on every device
-    (distributed/shard_paged.replicate)."""
+    (distributed/shard_paged.replicate).  Returns (x, cache, counters):
+    the expert layer's counters (``MOE.moe_layer``), None for the other
+    kinds.  ``rows`` (B, S) bool marks the real tokens the experts route;
+    ``stack`` is the expert layer's whole-stack weights and index
+    (``MOE.moe_layer``), for a layer of the scanned groups."""
     from repro.distributed.shard_paged import replicate
     x = replicate(x, cfg.mesh)
     with jax.named_scope("lm.norm"):
@@ -663,55 +668,104 @@ def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn):
         y, c = mix_fn(kind, lp, h, lc[key])
     x = replicate(x + y, cfg.mesh)
     if kind in ("mlstm", "slstm"):
-        return x, {key: c}
+        return x, {key: c}, None
     with jax.named_scope("lm.norm"):
         h2 = L.rmsnorm(lp["ln2"], x)
+    counters = None
     with jax.named_scope("lm.mlp"):
         if kind.endswith("moe"):
-            y2, _ = MOE.moe_ffn(lp["moe"], h2, cfg.moe, ep_axis=cfg.ep_axis)
+            y2, counters = MOE.moe_layer(lp["moe"], h2, cfg.moe, rows,
+                                         stack)
             x = x + y2
         else:
             x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
-    return x, {key: c}
+    return x, {key: c}, counters
 
 
-def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn):
+def _add_counters(total, counters):
+    if counters is None:
+        return total
+    if total is None:
+        return counters
+    return jax.tree.map(jnp.add, total, counters)
+
+
+def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn, rows=None):
     """Run the layer stack (prefix layers + scanned groups) with ``mix_fn``
     (kind, layer_params, h, sub_cache) -> (y, sub_cache) as the mixer
-    body; returns (final hidden, new caches)."""
+    body; returns (final hidden, new caches, counters).  ``counters`` sums
+    the expert layers' counters over the stack (None for a stack without
+    expert layers, whose programs gain no outputs)."""
     caches = dict(caches)
+    total = None
     if cfg.first_kinds:
         new_pref = []
         for i, kind in enumerate(cfg.first_kinds):
-            x, lc = _layer_paged(params["prefix_layers"][i], cfg, kind, x,
-                                 caches["prefix_layers"][i], mix_fn)
+            x, lc, n = _layer_paged(params["prefix_layers"][i], cfg, kind,
+                                    x, caches["prefix_layers"][i], mix_fn,
+                                    rows)
             new_pref.append(lc)
+            total = _add_counters(total, n)
         caches["prefix_layers"] = new_pref
 
-    def body(x, pair):
-        gp, gc = pair
-        new_gc = {}
-        for i, kind in enumerate(cfg.layer_kinds):
-            x, lc = _layer_paged(gp[f"l{i}"], cfg, kind, x, gc[f"l{i}"],
-                                 mix_fn)
-            new_gc[f"l{i}"] = lc
-        return x, new_gc
+    # the scan slices each group's parameters but for what an expert layer
+    # reads out of the whole stack itself (MOE.split_stack)
+    groups, whole = dict(params["groups"]), {}
+    for i, kind in enumerate(cfg.layer_kinds):
+        if kind.endswith("moe"):
+            lp = groups[f"l{i}"]
+            moe, whole[f"l{i}"] = MOE.split_stack(lp["moe"])
+            groups[f"l{i}"] = {**lp, "moe": moe}
 
-    # ops of the scan outside the layer body's scopes are the loop's own:
-    # slicing each layer's weights and pool out of the stacked carry and
-    # writing the updated pool back
+    def body(carry, pair):
+        x, pool = carry
+        gp, g = pair
+        gc = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False),
+            pool)
+        new_gc, group = {}, None
+        for i, kind in enumerate(cfg.layer_kinds):
+            stack = (whole[f"l{i}"], g) if f"l{i}" in whole else None
+            x, lc, n = _layer_paged(gp[f"l{i}"], cfg, kind, x, gc[f"l{i}"],
+                                    mix_fn, rows, stack)
+            new_gc[f"l{i}"] = lc
+            group = _add_counters(group, n)
+        pool = jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, g, 0),
+            pool, new_gc)
+        return (x, pool), group
+
+    # the stacked pool rides the carry and each group's slice is written
+    # back in place, so a step holds one pool (a donated one: the
+    # engine's); ops of the scan outside the layer body's scopes are the
+    # loop's own: slicing each layer's weights and pool out of the stack
+    # and writing the updated pool back
     with jax.named_scope("lm.layers"):
-        x, new_groups = maps.scan(body, x, (params["groups"],
-                                            caches["groups"]))
+        (x, new_groups), per_group = maps.scan(
+            body, (x, caches["groups"]),
+            (groups, jnp.arange(cfg.n_groups)))
     caches["groups"] = new_groups
+    if per_group is not None:
+        total = _add_counters(total, jax.tree.map(
+            lambda a: a.sum(0), per_group))
     with jax.named_scope("lm.norm"):
-        return L.rmsnorm(params["final_norm"], x), caches
+        return L.rmsnorm(params["final_norm"], x), caches, total
+
+
+def _with_counters(logits, counters):
+    """A paged program's first output: the logits, or for a stack with
+    expert layers the logits with the counters beside them."""
+    if counters is None:
+        return logits
+    return {"logits": logits, **counters}
 
 
 def prefill_chunk(params: dict, cfg: ModelConfig, tokens, caches, *,
                   page_row, offset, chunk_len, slot):
     """Prefill one chunk of one slot's prompt (tokens (1, C), padded).
-    Returns (logits (1, V) at the last valid token, caches)."""
+    Returns (logits (1, V) at the last valid token, caches); with expert
+    layers the logits come as ``{"logits", <counters>}``
+    (``_with_counters``)."""
     acfg = cfg.attention_config()
     x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
     if cfg.embed_scale:
@@ -736,17 +790,20 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens, caches, *,
                                      offset=offset, chunk_len=chunk_len,
                                      slot=slot)
 
-    x, caches = _paged_stack(params, cfg, x, caches, mix_fn)
+    rows = (jnp.arange(tokens.shape[1]) < chunk_len)[None]
+    x, caches, counters = _paged_stack(params, cfg, x, caches, mix_fn, rows)
     last = jax.lax.dynamic_slice(x, (0, chunk_len - 1, 0),
                                  (1, 1, x.shape[-1]))
-    return logits_from_hidden(params, cfg, last)[:, 0], caches
+    return _with_counters(logits_from_hidden(params, cfg, last)[:, 0],
+                          counters), caches
 
 
 def decode_paged(params: dict, cfg: ModelConfig, token_t, caches, *,
                  page_table, lengths, active):
     """One decode step for the whole slot batch with per-slot offsets.
     token_t: (B,) int32; lengths: (B,) tokens already cached per slot;
-    active: (B,) bool.  Returns (logits (B, V), caches)."""
+    active: (B,) bool.  Returns (logits (B, V), caches), the logits with
+    the expert counters beside them as in ``prefill_chunk``."""
     acfg = cfg.attention_config()
     x = L.embed(params["embed"], token_t[:, None]).astype(cfg.param_dtype)
     if cfg.embed_scale:
@@ -770,8 +827,10 @@ def decode_paged(params: dict, cfg: ModelConfig, token_t, caches, *,
         return SSM.ssm_decode_paged(kind, lp["core"], cfg.ssm, h, lc,
                                     active=active)
 
-    x, caches = _paged_stack(params, cfg, x, caches, mix_fn)
-    return logits_from_hidden(params, cfg, x)[:, 0], caches
+    x, caches, counters = _paged_stack(params, cfg, x, caches, mix_fn,
+                                       active[:, None])
+    return _with_counters(logits_from_hidden(params, cfg, x)[:, 0],
+                          counters), caches
 
 
 def decode_verify(params: dict, cfg: ModelConfig, tokens_w, caches, *,
@@ -779,7 +838,8 @@ def decode_verify(params: dict, cfg: ModelConfig, tokens_w, caches, *,
     """Speculative verify: decode a W-token window for the whole slot batch
     in ONE pass.  tokens_w: (B, W) int32 — row 0 is the last accepted
     token, rows 1.. the draft; window_len: (B,) valid rows per slot.
-    Returns (logits (B, W, V), caches).  K/V (or latent) pages are written
+    Returns (logits (B, W, V), caches), with counters as in
+    ``prefill_chunk``.  K/V (or latent) pages are written
     for the whole window; block-state and recurrent-checkpoint commits are
     deferred to ``commit_window`` once host-side acceptance is decided."""
     acfg = cfg.attention_config()
@@ -807,8 +867,11 @@ def decode_verify(params: dict, cfg: ModelConfig, tokens_w, caches, *,
                                            lc, active=active,
                                            window_len=window_len)
 
-    x, caches = _paged_stack(params, cfg, x, caches, mix_fn)
-    return logits_from_hidden(params, cfg, x), caches
+    rows = (jnp.arange(tokens_w.shape[1])[None] < window_len[:, None]) \
+        & active[:, None]
+    x, caches, counters = _paged_stack(params, cfg, x, caches, mix_fn, rows)
+    return _with_counters(logits_from_hidden(params, cfg, x), counters), \
+        caches
 
 
 def commit_window(cfg: ModelConfig, caches, page_table, lengths, accepted,
@@ -886,7 +949,8 @@ def draft_step(params: dict, cfg: ModelConfig, token_t, states, *,
         return A.linear_draft_attention(lp["attn"], acfg, h, lc,
                                         positions=positions, active=active)
 
-    x, states = _paged_stack(params, cfg, x, states, mix_fn)
+    x, states, _ = _paged_stack(params, cfg, x, states, mix_fn,
+                                active[:, None])
     return logits_from_hidden(params, cfg, x)[:, 0], states
 
 

@@ -456,6 +456,16 @@ class Scheduler:
         return max(slots, key=lambda s: slots[s].req.arrival)
 
 
+def pool_donation() -> tuple:
+    """The arguments the prefill and decode programs donate.  On an
+    accelerator they donate the pool (argument 2) and update it in place,
+    so device memory holds one pool.  On the CPU they donate nothing, so a
+    step's input pool stays readable: a wrapped step that hands back its
+    input pool (the benchmark's planted stale-decode fault) keeps serving.
+    ``tests/test_preemption.py`` runs the donated path on the CPU too."""
+    return () if jax.default_backend() == "cpu" else (2,)
+
+
 class ServeEngine:
     """Mixed-length continuous batching over Model.prefill_chunk/decode_paged.
 
@@ -532,7 +542,14 @@ class ServeEngine:
                       "swap_bytes": 0, "min_available": num_pages - 1,
                       "pool_peak_pages": 0,
                       # bytes of every logits array the host pulled
-                      "logits_to_host_bytes": 0}
+                      "logits_to_host_bytes": 0,
+                      # expert stacks: (row, expert) pairs the held
+                      # experts computed, and held experts with a row,
+                      # summed over expert layers and programs
+                      "moe_held_rows": 0, "moe_active_experts": 0}
+        # counters of programs whose logits stayed on the device; they
+        # come to the host with the next logits the engine pulls
+        self._pending_counters: list = []
         self.mesh = mesh
         self.monitor = None
         if mesh is not None:
@@ -612,8 +629,10 @@ class ServeEngine:
                 o, cc = model.decode_paged(p, b, c)
                 return o, pin(cc)
 
-            model._paged_step_fns = (jax.jit(serve_prefill_chunk),
-                                     jax.jit(serve_decode))
+            donate = pool_donation()
+            model._paged_step_fns = (
+                jax.jit(serve_prefill_chunk, donate_argnums=donate),
+                jax.jit(serve_decode, donate_argnums=donate))
         self._prefill_fn, self._decode_fn = model._paged_step_fns
         if model.swap_out is not None:
             if not hasattr(model, "_swap_fns"):
@@ -1217,7 +1236,8 @@ class ServeEngine:
             "chunk_len": jnp.asarray(n_chunk, jnp.int32),
             "slot": jnp.asarray(slot, jnp.int32),
         }
-        logits, self.caches = self._prefill_fn(self.params, batch, self.caches)
+        out, self.caches = self._prefill_fn(self.params, batch, self.caches)
+        logits = self._take_counters(out)
         s.pos += n_chunk
         self._lengths[slot] = s.pos
         self.stats["prefill_tokens"] += n_chunk
@@ -1232,11 +1252,28 @@ class ServeEngine:
                 if self._slot_state else None)
         return logits
 
+    def _take_counters(self, out):
+        """The logits of a program's first output.  An expert stack's
+        programs return their counters beside the logits (a dict); they
+        wait on the device until ``_logits_to_host``."""
+        if isinstance(out, dict):
+            self._pending_counters.append(
+                {k: v for k, v in out.items() if k != "logits"})
+            return out["logits"]
+        return out
+
     def _logits_to_host(self, logits) -> np.ndarray:
         """The blocking device->host copy of a step's logits, counted in
-        ``stats['logits_to_host_bytes']``."""
+        ``stats['logits_to_host_bytes']``, and of the counters of the
+        programs dispatched since the last copy (they ran before these
+        logits, so the copy waits for nothing more)."""
         with TraceAnnotation("serve.logits_to_host"):
             out = np.asarray(logits)
+            pending = jax.device_get(self._pending_counters)
+        self._pending_counters = []
+        for counters in pending:
+            for k, v in counters.items():
+                self.stats[k] += int(v)
         self.stats["logits_to_host_bytes"] += out.nbytes
         return out
 
@@ -1352,9 +1389,9 @@ class ServeEngine:
                 "active": jnp.asarray(active),
                 "window_len": jnp.asarray(wlens),
             }
-            logits, self.caches = self._verify_fn(self.params, batch,
-                                                  self.caches)
-        logits = self._logits_to_host(logits)     # (B, W, V)
+            out, self.caches = self._verify_fn(self.params, batch,
+                                               self.caches)
+        logits = self._logits_to_host(self._take_counters(out))  # (B, W, V)
 
         # --- host-side acceptance (greedy == plain decode, token-exact) --
         accepted = np.zeros((self.cfg.max_slots,), np.int32)
@@ -1448,9 +1485,9 @@ class ServeEngine:
                 "lengths": jnp.asarray(self._lengths),
                 "active": jnp.asarray(active),
             }
-            logits, self.caches = self._decode_fn(self.params, batch,
-                                                  self.caches)
-        logits = self._logits_to_host(logits)
+            out, self.caches = self._decode_fn(self.params, batch,
+                                               self.caches)
+        logits = self._logits_to_host(self._take_counters(out))
         with TraceAnnotation("serve.sample"):
             tok = self._sample(logits)
             for slot in ready:

@@ -14,14 +14,13 @@ them; they only make the *oracle comparison* exact):
   token identity to the static sla2 prefill requires the routed mask to
   cover everything (then alpha is auto-forced to 1 on the empty
   complement).  Static sla2 prompt lengths must divide block_q=32.
-- deepseek additionally needs DROPLESS MoE (``capacity_factor =
-  num_experts``): GShard capacity ``C = ceil(T*k/E * f)`` depends on the
-  number of tokens routed per call, so chunked prefill (32-token calls)
-  and batched decode (B-token calls) drop different tokens than the
-  static oracle's full-prompt / single-token calls unless capacity can
-  never bind — and a float32 page pool (EngineConfig.page_dtype +
-  generate_sequential cache_dtype): the MoE gates amplify bf16 page
-  rounding into expert flips.
+- deepseek serves through the dropless expert layer (models/moe.
+  moe_layer: no capacity, so chunked prefill, batched decode and the
+  static oracle's calls route alike; the smoke preset's
+  ``capacity_factor`` is training's only) and additionally needs a
+  float32 page pool (EngineConfig.page_dtype + generate_sequential
+  cache_dtype): the MoE gates amplify bf16 page rounding into expert
+  flips.
 """
 import numpy as np
 import pytest

@@ -185,3 +185,47 @@ def test_scheduler_priority_and_swap_accounting():
     assert pool.used == 0 and pool.n_swapped == 0
     with pytest.raises(KeyError):
         pool.pop(0)
+
+
+# engine settings, and the counter that shows the case's path ran
+DONATED_CASES = {
+    "swap": (dict(max_slots=3, num_pages=8), "swap_outs"),
+    "recompute": (dict(max_slots=3, num_pages=8, swap_pages=0),
+                  "recomputes"),
+    "prefix_cache": (dict(max_slots=1, prefix_cache=True), "prefix_hits"),
+    "speculative": (dict(max_slots=3, speculative="ngram", draft_len=3),
+                    "spec_steps"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DONATED_CASES))
+def test_donated_pool_serves_the_same(case, make_prompts, serve_mixed,
+                                      monkeypatch):
+    """The step programs donate the pool on an accelerator and not on the
+    CPU (``engine.pool_donation``).  Forced on the CPU, the donated path
+    serves the same tokens through preemption (swap and recompute), the
+    prefix cache's page copies and speculative verify/commit: no step
+    reads a pool a program has consumed."""
+    from repro.configs import get_smoke_config
+    from repro.models.api import build_model
+    from repro.serve import engine as engine_mod
+    cfg = get_smoke_config("qwen3_14b")
+    shared = make_prompts(cfg, [64], seed=5)[0]
+    prompts = [np.concatenate([shared, p]) if case == "prefix_cache" else p
+               for p in make_prompts(cfg, [20, 35, 28, 40], seed=4)]
+    ecfg, counter = DONATED_CASES[case]
+    outs = []
+    for donate in ((), (2,)):
+        monkeypatch.setattr(engine_mod, "pool_donation", lambda d=donate: d)
+        model = build_model(cfg)        # its own jitted step programs
+        params = model.init(jax.random.PRNGKey(0))
+        out, eng = serve_mixed(model, params, prompts, late_idx=3,
+                               **ecfg)
+        assert eng.stats[counter] > 0
+        outs.append(out)
+        old = jax.tree.leaves(eng.caches)[0]
+        fresh = make_prompts(cfg, [20], seed=6)[0]    # no prefix hit
+        eng.submit(Request(uid=9, prompt=fresh, max_new_tokens=2))
+        eng.run_to_completion()
+        assert old.is_deleted() == bool(donate)
+    assert outs[0] == outs[1]

@@ -233,3 +233,55 @@ def test_sharded_serving_keeps_single_chip_matmuls(spec, monkeypatch, topo):
             for t in (one[name], four[name])]
         assert sizes[0] and sizes[0] == sizes[1], (name, sizes)
         assert not _DOT_ALL_REDUCE.search(four[name]), name
+
+
+# deepseek-v2-lite serving (bench/configs/deepseek_v2_lite.json): d 2048,
+# 16 heads, latent 512 + 64, 8 of 64 experts held, expert width 1408,
+# top-6; 32 decode slots, 256-token prefill chunks, 5,120-token slots
+DS_D, DS_FF, DS_HELD, DS_TOPK, DS_EXPERTS = 2048, 1408, 8, 6, 64
+DS_SLOTS, DS_CHUNK, DS_MAXP = 32, 256, 5120 // 64
+
+
+@pytest.mark.parametrize("rows", [DS_SLOTS * DS_TOPK, DS_CHUNK * DS_TOPK])
+def test_moe_experts_compiles_at_deepseek_width(spec, rows):
+    """The grouped expert FFN for a decode step's and a prefill chunk's
+    routed pairs, reading one layer's experts out of the 26-layer stack
+    in HBM: two slots of a whole expert's weights (3 x 2048 x 1408 bf16)
+    fit the VMEM the kernel asks for."""
+    from repro.kernels import moe_experts as K
+    bf = jnp.bfloat16
+    _compile(lambda x, wi, wo, g, layer: K.moe_experts(
+        x, wi, wo, g, layer, tm=K.tile_rows(rows, DS_EXPERTS),
+        interpret=False),
+        spec((rows, DS_D), bf), spec((26, DS_HELD, DS_D, 2 * DS_FF), bf),
+        spec((26, DS_HELD, DS_FF, DS_D), bf), spec((DS_HELD,), jnp.int32),
+        spec((), jnp.int32))
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_mla_programs_compile_at_deepseek_width(spec, monkeypatch, program):
+    """The MLA latent prefill-chunk and decode programs at published
+    widths (the dense first layer and one expert layer), with the expert
+    kernel in the program: the path the chip serves."""
+    from repro.models.api import build_model
+    from repro.models.moe import MoEConfig
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_config("deepseek_v2_lite", n_layers=2, moe=MoEConfig(
+        num_experts=DS_EXPERTS, top_k=DS_TOPK, d_ff_expert=DS_FF,
+        num_shared=2, held_experts=DS_HELD))
+    model = build_model(cfg)
+    place = lambda t: jax.tree.map(lambda a: spec(a.shape, a.dtype), t)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = place(jax.eval_shape(
+        lambda: model.init_paged_caches(DS_SLOTS, 2 * DS_MAXP + 1)))
+    i32 = lambda *s: spec(s, jnp.int32)
+    if program == "prefill":
+        fn, batch = model.prefill_chunk, {
+            "tokens": i32(1, DS_CHUNK), "page_row": i32(DS_MAXP),
+            "offset": i32(), "chunk_len": i32(), "slot": i32()}
+    else:
+        fn, batch = model.decode_paged, {
+            "token": i32(DS_SLOTS), "page_table": i32(DS_SLOTS, DS_MAXP),
+            "lengths": i32(DS_SLOTS), "active": spec((DS_SLOTS,), jnp.bool_)}
+    text = _compile(fn, params, batch, caches).as_text()
+    assert "moe_experts_bfloat16" in text
