@@ -231,10 +231,11 @@ def cache_specs(cache_shape, mesh: Mesh):
     """Decode caches.  Big sequence-length tensors (KV blocks, pooled keys)
     are sequence-sharded flash-decoding style; when the batch does not cover
     the DP axes (long_500k has B=1) the sequence takes ALL mesh axes.
-    Paged pools (k_pages/v_pages/pooled_pages, no batch dim) shard their
-    page axis over ALL mesh axes — the paged analogue of sequence sharding:
-    the page table is replicated host state and a slot's logical blocks
-    scatter across devices like flash-decoding splits."""
+    Paged pools (k_pages/v_pages/pooled_pages/pooled_lat, no batch dim)
+    shard their page axis over ALL mesh axes — the paged analogue of
+    sequence sharding: the page table is replicated host state and a
+    slot's logical blocks scatter across devices like flash-decoding
+    splits."""
     dp = dp_axes(mesh)
     dp_size = int(np.prod([mesh.shape[a] for a in dp]))
     dp_ax = dp if len(dp) > 1 else dp[0]
@@ -252,7 +253,8 @@ def cache_specs(cache_shape, mesh: Mesh):
             return P(*([None] * nd))
         # paged pools: leading (post-stack) axis is the physical page id
         if re.search(r"/(k_pages|v_pages)$", name) and nd - off == 4 \
-                or re.search(r"/pooled_pages$", name) and nd - off == 3:
+                or re.search(r"/pooled_pages$", name) and nd - off == 3 \
+                or re.search(r"/pooled_lat$", name) and nd - off == 2:
             spec = [None] * nd
             spec[off] = all_ax
             return _fit_to_shape(P(*spec), leaf.shape, mesh)

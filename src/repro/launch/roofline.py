@@ -102,7 +102,9 @@ def mla_latent_page_bytes(latent_dim: int, page_tokens: int,
     fp32 scale per token row and per pooled key, unquantized pools keep
     the pooled key in fp32.  Compare against ``kv_page_bytes(hkv=heads,
     ...)`` for the dense-cache equivalent — the paged-MLA memory win the
-    fig14 family benchmark plots."""
+    fig14 family benchmark plots.  These are the latent's own bytes: the
+    pool stores each row padded to whole 128-lane tiles (models/mla.py),
+    640 values for DeepSeek-V2's 576."""
     el = KV_QUANT_BYTES[kv_quant]
     total = page_tokens * latent_dim * el           # k_pages rows
     if kv_quant != "none":

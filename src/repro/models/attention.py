@@ -366,8 +366,8 @@ def init_paged_cache(cfg: AttentionConfig, num_pages: int, batch: int,
 # rewrites the trash page — both harmless, so callers can keep a static
 # (max_pages,) shape and the extract/insert functions jit-compile once.
 
-_PAGE_KEYS = ("k_pages", "v_pages", "pooled_pages",
-              "k_scale", "v_scale", "pooled_scale")
+_PAGE_KEYS = ("k_pages", "v_pages", "pooled_pages", "pooled_lat",
+              "k_scale", "v_scale", "pooled_scale", "pooled_lat_scale")
 # Per-slot state leaves: the SLA2 linear-branch totals plus the recurrent-
 # mixer state checkpoints (ssm.py names them with an "s_" prefix).  One
 # name list means the swap / prefix-snapshot / extract-insert machinery

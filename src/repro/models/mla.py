@@ -311,35 +311,89 @@ def mla_decode_step(params: dict, x_t: jax.Array, cache: dict, *,
 # Paged serving: latent page pool
 # ---------------------------------------------------------------------------
 # MLA's paged cache stores the COMPRESSED latent [c_kv; k_rope] — one
-# (block_k, latent_dim) tile per page with a dummy kv-head axis of 1 so the
-# leaf shapes line up with the engine's page-axis bookkeeping
-# (_PAGE_AXIS_FROM_END) and the attention._PAGE_KEYS swap machinery carries
-# them unchanged.  There is NO v_pages: the values are the c_kv slice of
-# the latent (``lat[..., :kv_lora_rank]``), which is what makes the latent
+# (block_k, latent_dim) tile per page in ``k_pages`` with a dummy kv-head
+# axis of 1, so its shape lines up with the engine's page-axis bookkeeping
+# (_PAGE_AXIS_FROM_END), and one pooled router latent per page in
+# ``pooled_lat`` (P, latent_dim); the attention._PAGE_KEYS swap machinery
+# carries both.  There is NO v_pages: the values are the c_kv slice of the
+# latent (``lat[..., :kv_lora_rank]``), which is what makes the latent
 # pool a fraction of a dense pool's bytes (launch/roofline.py
 # mla_latent_page_bytes).  The gather-path jnp implementations below are
 # the only implementations (no fused MLA page kernels yet) and serve as
 # the oracle for any future kernel work.
+#
+# Every pool access goes through the accessors below (_lat_read,
+# _store_lat_rows, _store_lat_pooled, _tot, _add_tot).  A layer of the
+# transformer's scanned groups hands its mixer the group's STACKED pool
+# and its ``layer`` index: each read is one gather at ``[layer, idx]`` and
+# each write one scatter at ``[layer, phys, ...]`` into the carried
+# stack, so no layer's pool is sliced out, converted or written back.  A
+# prefix layer's own cache passes ``layer=None`` and is indexed as is.
+#
+# The stored layout is the one those gathers ask for.  A TPU tiles an
+# array's two minor dims (sublanes x 128 lanes), and where they fit the
+# tiles badly its compact layout moves another axis in: for a minor dim
+# of 576 the PAGE axis goes onto the lanes, and a (1, d) pooled row gets
+# one-sublane tiles.  Either way every page gather and row scatter needs
+# the whole stack converted to another layout first.  So each latent row
+# is stored padded to whole lanes (576 -> 640; the pad is never read) and
+# the pooled latents carry no kv-head axis: each page is then a run of
+# whole tiles, read and written in place.
+_LANES = 128
+
+
+def _ix(layer, *idx):
+    """The index of ``idx`` in a pool leaf: into a layer's own cache when
+    ``layer`` is None, else into the stacked pool at that layer, as ONE
+    combined index (``a[layer, idx]``, never ``a[layer][idx]``, which
+    would slice the layer out first)."""
+    return idx if layer is None else (layer, *idx)
+
+
+def _stored(d_lat: int) -> int:
+    """The pool's stored width of a latent row: ``d_lat`` in whole lanes."""
+    return -(-d_lat // _LANES) * _LANES
+
+
+def _d_lat(cache: dict) -> int:
+    """The latent width of a pool's rows (``z_tot`` holds it unpadded)."""
+    return cache["z_tot"].shape[-1]
+
+
+def _tot(cache: dict, name: str, layer):
+    """One layer's per-slot linear totals (``h_tot``/``z_tot``)."""
+    return cache[name] if layer is None else cache[name][layer]
+
+
+def _add_tot(cache: dict, name: str, layer, add):
+    """``name`` with ``add`` summed into one layer's totals, in place in
+    the stacked pool."""
+    if layer is None:
+        return cache[name] + add
+    return cache[name].at[layer].add(add)
+
 
 def init_mla_paged_cache(mcfg: MLAConfig, num_pages: int, batch: int,
                          block_k: int, *, kv_quant: str = "none",
                          dtype=jnp.bfloat16) -> dict:
-    """Latent page pool for one MLA layer: k_pages (P, 1, bk, d_lat)
-    [+ per-row f32 scales when quantized], per-page pooled router latents,
-    and the per-slot SLA2 linear totals h_tot/z_tot."""
+    """Latent page pool for one MLA layer: k_pages (P, 1, bk, d_lat
+    stored in whole lanes) [+ per-row f32 scales when quantized], per-page
+    pooled router latents (likewise stored), and the per-slot SLA2 linear
+    totals h_tot/z_tot."""
     d_lat, r = mcfg.latent_dim, mcfg.kv_lora_rank
+    d_st = _stored(d_lat)
     if kv_quant != "none":
         qdt = ops.kv_pool_dtype(kv_quant)
         cache = {
-            "k_pages": jnp.zeros((num_pages, 1, block_k, d_lat), qdt),
+            "k_pages": jnp.zeros((num_pages, 1, block_k, d_st), qdt),
             "k_scale": jnp.zeros((num_pages, 1, block_k), jnp.float32),
-            "pooled_pages": jnp.zeros((num_pages, 1, d_lat), qdt),
-            "pooled_scale": jnp.zeros((num_pages, 1), jnp.float32),
+            "pooled_lat": jnp.zeros((num_pages, d_st), qdt),
+            "pooled_lat_scale": jnp.zeros((num_pages,), jnp.float32),
         }
     else:
         cache = {
-            "k_pages": jnp.zeros((num_pages, 1, block_k, d_lat), dtype),
-            "pooled_pages": jnp.zeros((num_pages, 1, d_lat), jnp.float32),
+            "k_pages": jnp.zeros((num_pages, 1, block_k, d_st), dtype),
+            "pooled_lat": jnp.zeros((num_pages, d_st), jnp.float32),
         }
     cache.update({
         "h_tot": jnp.zeros((batch, d_lat, r), jnp.float32),
@@ -348,55 +402,62 @@ def init_mla_paged_cache(mcfg: MLAConfig, num_pages: int, batch: int,
     return cache
 
 
-def _lat_read(cache: dict, name: str, idx):
-    """``cache[name][idx]`` dequantized to f32 (the latent-pool twin of
-    attention._kv_read; the scale broadcasts per row)."""
-    out = cache[name][idx]
-    sk = {"k_pages": "k_scale", "pooled_pages": "pooled_scale"}[name]
+def _lat_read(cache: dict, name: str, idx, layer=None):
+    """``cache[name][idx]`` (at ``layer`` of a stacked pool) dequantized
+    to f32 (the latent-pool twin of attention._kv_read; the scale
+    broadcasts per row)."""
+    out = cache[name][_ix(layer, idx)][..., :_d_lat(cache)]
+    sk = {"k_pages": "k_scale", "pooled_lat": "pooled_lat_scale"}[name]
     if sk in cache:
-        return ops.dequant_rows(out, cache[sk][idx])
+        return ops.dequant_rows(out, cache[sk][_ix(layer, idx)])
     return out.astype(jnp.float32)
 
 
-def _store_lat_rows(cache: dict, kv_quant: str, phys, rows, lat_new):
-    """Write latent token rows at ``[phys, :, rows]``, quantizing exactly
-    once at write time.  ``lat_new``: (..., 1, d_lat) with leading shape ==
-    phys/rows.  Returns (cache, lat_eff) where lat_eff is the f32 value a
-    page read observes — block states derive from THESE so prefill-time
-    state matches decode-time recompute from pages."""
+def _store_lat_rows(cache: dict, kv_quant: str, phys, rows, lat_new,
+                    layer=None):
+    """Write latent token rows at ``[phys, :, rows]`` (of ``layer``),
+    quantizing exactly once at write time.  ``lat_new``: (..., 1, d_lat)
+    with leading shape == phys/rows.  Returns (cache, lat_eff) where
+    lat_eff is the f32 value a page read observes — block states derive
+    from THESE so prefill-time state matches decode-time recompute from
+    pages."""
+    at = _ix(layer, phys, slice(None), rows)
+    at_lat = at + (slice(0, _d_lat(cache)),)
     if kv_quant == "none":
-        cache["k_pages"] = cache["k_pages"].at[phys, :, rows].set(
+        cache["k_pages"] = cache["k_pages"].at[at_lat].set(
             lat_new.astype(cache["k_pages"].dtype))
         return cache, lat_new.astype(jnp.float32)
     k_c, k_s = ops.quantize_rows(lat_new, kv_quant)
-    cache["k_pages"] = cache["k_pages"].at[phys, :, rows].set(k_c)
-    cache["k_scale"] = cache["k_scale"].at[phys, :, rows].set(k_s)
+    cache["k_pages"] = cache["k_pages"].at[at_lat].set(k_c)
+    cache["k_scale"] = cache["k_scale"].at[at].set(k_s)
     return cache, ops.dequant_rows(k_c, k_s)
 
 
-def _store_lat_pooled(cache: dict, kv_quant: str, phys, pooled, keep):
-    """Write pooled router latents (f32, (..., 1, d_lat)) at pages
-    ``phys``; rows where ``keep`` is False retain the existing page content
-    (the masked-write idiom of the trash-page scheme)."""
+def _store_lat_pooled(cache: dict, kv_quant: str, phys, pooled, keep,
+                      layer=None):
+    """Write pooled router latents (f32, (..., d_lat)) at pages ``phys``
+    (of ``layer``); rows where ``keep`` is False retain the existing page
+    content (the masked-write idiom of the trash-page scheme)."""
+    at = _ix(layer, phys)
+    at_lat = at + (slice(0, _d_lat(cache)),)
     if kv_quant == "none":
-        cache["pooled_pages"] = cache["pooled_pages"].at[phys].set(
-            jnp.where(keep[..., None, None],
-                      pooled.astype(cache["pooled_pages"].dtype),
-                      cache["pooled_pages"][phys]))
+        cache["pooled_lat"] = cache["pooled_lat"].at[at_lat].set(
+            jnp.where(keep[..., None],
+                      pooled.astype(cache["pooled_lat"].dtype),
+                      cache["pooled_lat"][at_lat]))
         return cache
     codes, scale = ops.quantize_rows(pooled, kv_quant)
-    cache["pooled_pages"] = cache["pooled_pages"].at[phys].set(
-        jnp.where(keep[..., None, None], codes,
-                  cache["pooled_pages"][phys]))
-    cache["pooled_scale"] = cache["pooled_scale"].at[phys].set(
-        jnp.where(keep[..., None], scale, cache["pooled_scale"][phys]))
+    cache["pooled_lat"] = cache["pooled_lat"].at[at_lat].set(
+        jnp.where(keep[..., None], codes, cache["pooled_lat"][at_lat]))
+    cache["pooled_lat_scale"] = cache["pooled_lat_scale"].at[at].set(
+        jnp.where(keep, scale, cache["pooled_lat_scale"][at]))
     return cache
 
 
 def mla_prefill_chunk_paged(params: dict, x: jax.Array, cache: dict, *,
                             mcfg: MLAConfig, num_heads: int, block_k: int,
                             kv_quant: str = "none", page_row, offset,
-                            chunk_len, slot):
+                            chunk_len, slot, layer=None):
     """Prefill one chunk of ONE slot's prompt into the latent page pool.
 
     Mirrors attention.chunk_prefill_paged: exact dense latent attention
@@ -404,7 +465,9 @@ def mla_prefill_chunk_paged(params: dict, x: jax.Array, cache: dict, *,
     sparse/linear split applies to decode), K/V rows land at
     ``page_row[pos // bk]``, and the chunk's complete blocks fold into the
     per-slot linear totals (reset when ``offset == 0``).  x: (1, C,
-    d_model); returns (y, cache)."""
+    d_model); ``layer``: this layer's index when ``cache`` is a scanned
+    group's stacked pool (read and written in place there), None for a
+    layer's own cache.  Returns (y, cache)."""
     _, c, _ = x.shape
     h = num_heads
     bk = block_k
@@ -422,10 +485,10 @@ def mla_prefill_chunk_paged(params: dict, x: jax.Array, cache: dict, *,
     rows = tok_pos % bk
     cache = dict(cache)
     cache, k_eff = _store_lat_rows(cache, kv_quant, phys, rows,
-                                   k_t[0][:, None])     # (C, 1, d_lat)
+                                   k_t[0][:, None], layer)  # (C, 1, d_lat)
 
     # --- exact dense latent attention: chunk queries over history + chunk --
-    g = _lat_read(cache, "k_pages", page_row[None])     # (1, maxP, 1, bk, d)
+    g = _lat_read(cache, "k_pages", page_row[None], layer)  # (1,maxP,1,bk,d)
     k_all = g.reshape(1, max_p * bk, d_lat)
     s = jnp.einsum("bhnd,bmd->bhnm", q, k_all) / jnp.sqrt(d_lat)
     vis = masklib.token_causal_mask(c, max_p * bk, offset)
@@ -447,8 +510,8 @@ def mla_prefill_chunk_paged(params: dict, x: jax.Array, cache: dict, *,
     blk_ids = jnp.minimum(offset // bk + jnp.arange(t_c), max_p - 1)
     has_tok = w.sum(1) > 0
     phys_blk = jnp.where(has_tok, page_row[blk_ids], 0)
-    cache = _store_lat_pooled(cache, kv_quant, phys_blk, pooled[:, None],
-                              has_tok)
+    cache = _store_lat_pooled(cache, kv_quant, phys_blk, pooled, has_tok,
+                              layer)
     complete = w.sum(1) == bk
     kf = phi(kb) * w[..., None]
     vb = kb[..., :r] * w[..., None]
@@ -456,17 +519,18 @@ def mla_prefill_chunk_paged(params: dict, x: jax.Array, cache: dict, *,
              * complete[:, None, None]).sum(0)
     z_add = (kf.sum(1) * complete[:, None]).sum(0)
     fresh = offset == 0
-    cache["h_tot"] = cache["h_tot"].at[slot].set(
-        jnp.where(fresh, 0.0, cache["h_tot"][slot]) + h_add)
-    cache["z_tot"] = cache["z_tot"].at[slot].set(
-        jnp.where(fresh, 0.0, cache["z_tot"][slot]) + z_add)
+    at = _ix(layer, slot)
+    cache["h_tot"] = cache["h_tot"].at[at].set(
+        jnp.where(fresh, 0.0, cache["h_tot"][at]) + h_add)
+    cache["z_tot"] = cache["z_tot"].at[at].set(
+        jnp.where(fresh, 0.0, cache["z_tot"][at]) + z_add)
     return o @ params["w_o"], cache
 
 
 def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
                           mcfg: MLAConfig, num_heads: int, k_frac: float,
                           block_k: int, kv_quant: str = "none", page_table,
-                          lengths, active):
+                          lengths, active, layer=None):
     """Batched one-token MLA-SLA2 decode over the latent page pool.
 
     The paged twin of ``mla_decode_step``: the current block's stats are
@@ -475,7 +539,7 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
     head over the pooled latent pages, and the linear branch subtracts the
     routed complete blocks from the slot totals.  x_t: (B, 1, d_model);
     ``active`` rows gate every cache write (inactive rows hit the trash
-    page)."""
+    page); ``layer`` as in ``mla_prefill_chunk_paged``."""
     b = x_t.shape[0]
     h = num_heads
     bk = block_k
@@ -492,24 +556,25 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
     rows = lengths % bk
     cache = dict(cache)
     cache, _ = _store_lat_rows(cache, kv_quant, phys_w, rows,
-                               k_new[:, 0][:, None])
+                               k_new[:, 0][:, None], layer)
     t_new = lengths + 1
 
     # --- current-block stats recomputed from pages ---
-    kblk = _lat_read(cache, "k_pages", phys_w)[:, 0]    # (B, bk, d_lat)
+    kblk = _lat_read(cache, "k_pages", phys_w, layer)[:, 0]  # (B,bk,d_lat)
     in_blk = (cur_blk[:, None] * bk + jnp.arange(bk)[None, :]) < t_new[:, None]
     w = in_blk.astype(jnp.float32)[..., None]           # (B, bk, 1)
     pooled_cur = (kblk * w).sum(1) / jnp.maximum(w.sum(1), 1.0)
-    cache = _store_lat_pooled(cache, kv_quant, phys_w, pooled_cur[:, None],
-                              active)
+    cache = _store_lat_pooled(cache, kv_quant, phys_w, pooled_cur, active,
+                              layer)
     completed = (t_new % bk) == 0
     kf_cur = phi(kblk) * w
     h_cur = jnp.einsum("bkd,bkr->bdr", kf_cur, kblk[..., :r] * w)
     z_cur = kf_cur.sum(1)
     upd = completed & active
-    cache["h_tot"] = cache["h_tot"] + jnp.where(upd[:, None, None], h_cur,
-                                                0.0)
-    cache["z_tot"] = cache["z_tot"] + jnp.where(upd[:, None], z_cur, 0.0)
+    cache["h_tot"] = _add_tot(cache, "h_tot", layer,
+                              jnp.where(upd[:, None, None], h_cur, 0.0))
+    cache["z_tot"] = _add_tot(cache, "z_tot", layer,
+                              jnp.where(upd[:, None], z_cur, 0.0))
 
     with jax.named_scope("sla2.router"):
         # --- route per q head over pooled latent pages ---
@@ -517,7 +582,7 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
         rp = sla2_p.get("router", {})
         qr = q1
         # (B, T, d_lat)
-        pk = _lat_read(cache, "pooled_pages", page_table)[:, :, 0]
+        pk = _lat_read(cache, "pooled_lat", page_table, layer)
         if rp:
             qr = qr @ rp["proj_q"].astype(jnp.float32)
             pk = pk @ rp["proj_k"].astype(jnp.float32)
@@ -539,7 +604,7 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
     with jax.named_scope("sla2.sparse"):
         # --- sparse branch over gathered latent pages ---
         # (B, H, K_sel, bk, d_lat)
-        kg = _lat_read(cache, "k_pages", phys_sel)[..., 0, :, :]
+        kg = _lat_read(cache, "k_pages", phys_sel, layer)[..., 0, :, :]
         s = jnp.einsum("bhd,bhjkd->bhjk", q1, kg) / jnp.sqrt(d_lat)
         pos = idx[..., None] * bk + jnp.arange(bk)[None, None, None, :]
         vis = (pos < t_new[:, None, None, None]) & valid[..., None]
@@ -555,8 +620,9 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
         ls = jnp.einsum("bhd,bhjkd->bhjk", qf, kf_sel) * selc[..., None]
         sub_num = jnp.einsum("bhjk,bhjkr->bhr", ls, vg)
         sub_den = ls.sum(axis=(-1, -2))
-        den_tot = jnp.einsum("bhd,bd->bh", qf, cache["z_tot"])
-        num = jnp.einsum("bhd,bdr->bhr", qf, cache["h_tot"]) - sub_num
+        den_tot = jnp.einsum("bhd,bd->bh", qf, _tot(cache, "z_tot", layer))
+        num = jnp.einsum("bhd,bdr->bhr", qf, _tot(cache, "h_tot", layer)) \
+            - sub_num
         den = den_tot - sub_den
         den = jnp.where(den > 1e-4 * den_tot + 1e-12, den, 0.0)[..., None]
         o_l = jnp.where(den > 0, num / jnp.maximum(den, 1e-12), 0.0)
@@ -579,14 +645,15 @@ def mla_decode_step_paged(params: dict, x_t: jax.Array, cache: dict, *,
 def mla_decode_window_paged(params: dict, x_w: jax.Array, cache: dict, *,
                             mcfg: MLAConfig, num_heads: int, k_frac: float,
                             block_k: int, kv_quant: str = "none", page_table,
-                            lengths, active, window_len):
+                            lengths, active, window_len, layer=None):
     """Verify pass of speculative decoding over the latent pool: W query
     rows per slot with all block state TRANSIENT (the paged twin of
     attention._sla2_decode_window with per-q-head routing) — pooled keys
     for span blocks are computed per row from page content, each row's
     linear totals add span blocks completing earlier in the window, and
     nothing is committed: ``mla_commit_window`` follows host acceptance.
-    x_w: (B, W, d_model); returns (y (B, W, d_model), cache)."""
+    x_w: (B, W, d_model); ``layer`` as in ``mla_prefill_chunk_paged``;
+    returns (y (B, W, d_model), cache)."""
     from repro.models.attention import window_span
     b, wdw, _ = x_w.shape
     h = num_heads
@@ -607,7 +674,7 @@ def mla_decode_window_paged(params: dict, x_w: jax.Array, cache: dict, *,
     rows = tok_pos % bk
     cache = dict(cache)
     cache, _ = _store_lat_rows(cache, kv_quant, phys_w, rows,
-                               k_new[..., None, :])
+                               k_new[..., None, :], layer)
     t_new = tok_pos + 1                                 # (B, W)
 
     # --- transient stats for the blocks the window can touch ---
@@ -616,7 +683,8 @@ def mla_decode_window_paged(params: dict, x_w: jax.Array, cache: dict, *,
     genuine = span_ids_raw < t_n
     span_ids = jnp.minimum(span_ids_raw, t_n - 1)
     span_phys = jnp.take_along_axis(page_table, span_ids, 1)
-    kblk = _lat_read(cache, "k_pages", span_phys)[:, :, 0]  # (B,S,bk,d_lat)
+    # (B, S, bk, d_lat)
+    kblk = _lat_read(cache, "k_pages", span_phys, layer)[:, :, 0]
     pos_blk = span_ids[:, :, None] * bk + jnp.arange(bk)    # (B,S,bk)
     msk = (pos_blk[:, None] < t_new[:, :, None, None]) \
         .astype(jnp.float32)                                # (B,W,S,bk)
@@ -628,16 +696,16 @@ def mla_decode_window_paged(params: dict, x_w: jax.Array, cache: dict, *,
     cmplt = (genuine[:, None]
              & ((span_ids[:, None] + 1) * bk <= t_new[:, :, None])) \
         .astype(jnp.float32)                                # (B,W,S)
-    h_eff = cache["h_tot"][:, None] \
+    h_eff = _tot(cache, "h_tot", layer)[:, None] \
         + jnp.einsum("bws,bsdr->bwdr", cmplt, h_span)
-    z_eff = cache["z_tot"][:, None] \
+    z_eff = _tot(cache, "z_tot", layer)[:, None] \
         + jnp.einsum("bws,bsd->bwd", cmplt, z_span)
 
     # --- route per row, per q head, transient pooled keys for the span ---
     sla2_p = params["sla2"]
     rp = sla2_p.get("router", {})
     qr = q
-    pk = _lat_read(cache, "pooled_pages", page_table)[:, :, 0]
+    pk = _lat_read(cache, "pooled_lat", page_table, layer)
     pw = pooled_ws
     if rp:
         qr = qr @ rp["proj_q"].astype(jnp.float32)
@@ -669,7 +737,7 @@ def mla_decode_window_paged(params: dict, x_w: jax.Array, cache: dict, *,
         .astype(jnp.float32)
 
     # --- sparse branch over gathered latent pages ---
-    kg = _lat_read(cache, "k_pages", phys_sel)[..., 0, :, :]
+    kg = _lat_read(cache, "k_pages", phys_sel, layer)[..., 0, :, :]
     qw = q.transpose(0, 2, 1, 3)                            # (B,W,H,d_lat)
     s = jnp.einsum("bwhd,bwhjkd->bwhjk", qw, kg) / jnp.sqrt(d_lat)
     pos = idx[..., None] * bk + jnp.arange(bk)              # (B,W,H,K,bk)
@@ -732,8 +800,7 @@ def mla_commit_window(cache: dict, *, mcfg: MLAConfig, block_k: int,
         / jnp.maximum(msk.sum(-1), 1.0)[..., None]
     upd_phys = jnp.where(has_tok, span_phys, 0)
     cache = dict(cache)
-    cache = _store_lat_pooled(cache, kv_quant, upd_phys, pooled[:, :, None],
-                              has_tok)
+    cache = _store_lat_pooled(cache, kv_quant, upd_phys, pooled, has_tok)
     newc = (live & ((span_ids + 1) * bk <= new_len[:, None])
             & ((span_ids + 1) * bk > lengths[:, None])).astype(jnp.float32)
     kf = phi(kblk)

@@ -649,23 +649,50 @@ def copy_kv_page(cfg: ModelConfig, caches: dict, src, dst) -> dict:
     return new
 
 
+# layer kinds whose paged mixers read and write a scanned group's stacked
+# pool in place at the layer index (models/mla.py's pool accessors); the
+# other kinds' mixers hand one layer's pool to Pallas kernels through
+# BlockSpecs, so ``_mix_stacked`` slices it out for them
+_IN_PLACE_KINDS = ("mla_dense", "mla_moe")
+
+
+def _mix_stacked(mix_fn, kind, lp, h, cache, layer):
+    """The mixer, under its ``lm.attn`` scope.  ``cache`` is a layer's own
+    cache when ``layer`` is None, else a scanned group's stacked cache and
+    ``layer`` the scan's index: an in-place kind indexes the stack itself;
+    any other kind gets its layer's slice, and the slice it returns is
+    written back into the stack, both outside the scope: they are the
+    scan's data movement, not the mixer's."""
+    if layer is not None and kind not in _IN_PLACE_KINDS:
+        sub = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            cache)
+        with jax.named_scope("lm.attn"):
+            y, sub = mix_fn(kind, lp, h, sub, None)
+        return y, jax.tree.map(
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, layer, 0),
+            cache, sub)
+    with jax.named_scope("lm.attn"):
+        return mix_fn(kind, lp, h, cache, layer)
+
+
 def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn, rows,
-                 stack=None):
+                 stack=None, layer=None):
     """Shared block body around a paged mixer call, dispatched on the layer
     kind; recurrent-core kinds (mlstm/slstm) have no ln2/FFN half.  On a
     serving mesh the residual stream stays whole on every device
     (distributed/shard_paged.replicate).  Returns (x, cache, counters):
     the expert layer's counters (``MOE.moe_layer``), None for the other
-    kinds.  ``rows`` (B, S) bool marks the real tokens the experts route;
-    ``stack`` is the expert layer's whole-stack weights and index
-    (``MOE.moe_layer``), for a layer of the scanned groups."""
+    kinds.  ``rows`` (B, S) bool marks the real tokens the experts route.
+    For a layer of the scanned groups, ``layer`` is its index, ``lc`` the
+    group's stacked cache (``_mix_stacked``) and ``stack`` the expert
+    layer's whole-stack weights and index (``MOE.moe_layer``)."""
     from repro.distributed.shard_paged import replicate
     x = replicate(x, cfg.mesh)
     with jax.named_scope("lm.norm"):
         h = L.rmsnorm(lp["ln1"], x)
     key = KIND_CACHE_KEY[kind]
-    with jax.named_scope("lm.attn"):
-        y, c = mix_fn(kind, lp, h, lc[key])
+    y, c = _mix_stacked(mix_fn, kind, lp, h, lc[key], layer)
     x = replicate(x + y, cfg.mesh)
     if kind in ("mlstm", "slstm"):
         return x, {key: c}, None
@@ -692,8 +719,10 @@ def _add_counters(total, counters):
 
 def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn, rows=None):
     """Run the layer stack (prefix layers + scanned groups) with ``mix_fn``
-    (kind, layer_params, h, sub_cache) -> (y, sub_cache) as the mixer
-    body; returns (final hidden, new caches, counters).  ``counters`` sums
+    (kind, layer_params, h, sub_cache, layer) -> (y, sub_cache) as the
+    mixer body, where ``layer`` is None for a layer's own cache and the
+    scan's layer index for a group's stacked one (``_mix_stacked``);
+    returns (final hidden, new caches, counters).  ``counters`` sums
     the expert layers' counters over the stack (None for a stack without
     expert layers, whose programs gain no outputs)."""
     caches = dict(caches)
@@ -720,26 +749,22 @@ def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn, rows=None):
     def body(carry, pair):
         x, pool = carry
         gp, g = pair
-        gc = jax.tree.map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, g, keepdims=False),
-            pool)
-        new_gc, group = {}, None
+        pool, group = dict(pool), None
         for i, kind in enumerate(cfg.layer_kinds):
             stack = (whole[f"l{i}"], g) if f"l{i}" in whole else None
-            x, lc, n = _layer_paged(gp[f"l{i}"], cfg, kind, x, gc[f"l{i}"],
-                                    mix_fn, rows, stack)
-            new_gc[f"l{i}"] = lc
+            x, pool[f"l{i}"], n = _layer_paged(
+                gp[f"l{i}"], cfg, kind, x, pool[f"l{i}"], mix_fn, rows,
+                stack, layer=g)
             group = _add_counters(group, n)
-        pool = jax.tree.map(
-            lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, g, 0),
-            pool, new_gc)
         return (x, pool), group
 
-    # the stacked pool rides the carry and each group's slice is written
-    # back in place, so a step holds one pool (a donated one: the
-    # engine's); ops of the scan outside the layer body's scopes are the
-    # loop's own: slicing each layer's weights and pool out of the stack
-    # and writing the updated pool back
+    # the stacked pool rides the carry, so a step holds one pool (a
+    # donated one: the engine's).  Each layer's mixer gets the whole stack
+    # and the layer index: MLA layers read and write it in place, the
+    # other kinds slice their layer out and write it back in
+    # ``_mix_stacked``.  Ops of the scan outside the layer body's scopes
+    # are the loop's own: slicing each layer's weights, and a non-MLA
+    # layer's pool, out of the stack and writing that pool back
     with jax.named_scope("lm.layers"):
         (x, new_groups), per_group = maps.scan(
             body, (x, caches["groups"]),
@@ -771,7 +796,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens, caches, *,
     if cfg.embed_scale:
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
 
-    def mix_fn(kind, lp, h, lc):
+    def mix_fn(kind, lp, h, lc, layer):
         if kind in ("dense", "moe"):
             return A.chunk_prefill_paged(
                 lp["attn"], acfg, h, lc, page_row=page_row, offset=offset,
@@ -781,7 +806,7 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens, caches, *,
                 lp["mla"], h, lc, mcfg=cfg.mla, num_heads=cfg.num_heads,
                 block_k=cfg.block_k, kv_quant=cfg.kv_quant,
                 page_row=page_row, offset=offset, chunk_len=chunk_len,
-                slot=slot)
+                slot=slot, layer=layer)
         if kind == "hybrid":
             return HY.hybrid_prefill_chunk_paged(
                 lp["mixer"], acfg, cfg.ssm, h, lc, page_row=page_row,
@@ -809,7 +834,7 @@ def decode_paged(params: dict, cfg: ModelConfig, token_t, caches, *,
     if cfg.embed_scale:
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
 
-    def mix_fn(kind, lp, h, lc):
+    def mix_fn(kind, lp, h, lc, layer):
         if kind in ("dense", "moe"):
             return A.decode_step_paged(lp["attn"], acfg, h, lc,
                                        page_table=page_table,
@@ -819,7 +844,7 @@ def decode_paged(params: dict, cfg: ModelConfig, token_t, caches, *,
                 lp["mla"], h, lc, mcfg=cfg.mla, num_heads=cfg.num_heads,
                 k_frac=cfg.k_frac, block_k=cfg.block_k,
                 kv_quant=cfg.kv_quant, page_table=page_table,
-                lengths=lengths, active=active)
+                lengths=lengths, active=active, layer=layer)
         if kind == "hybrid":
             return HY.hybrid_decode_step_paged(
                 lp["mixer"], acfg, cfg.ssm, h, lc, page_table=page_table,
@@ -847,7 +872,7 @@ def decode_verify(params: dict, cfg: ModelConfig, tokens_w, caches, *,
     if cfg.embed_scale:
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
 
-    def mix_fn(kind, lp, h, lc):
+    def mix_fn(kind, lp, h, lc, layer):
         if kind in ("dense", "moe"):
             return A.decode_window_paged(lp["attn"], acfg, h, lc,
                                          page_table=page_table,
@@ -858,7 +883,8 @@ def decode_verify(params: dict, cfg: ModelConfig, tokens_w, caches, *,
                 lp["mla"], h, lc, mcfg=cfg.mla, num_heads=cfg.num_heads,
                 k_frac=cfg.k_frac, block_k=cfg.block_k,
                 kv_quant=cfg.kv_quant, page_table=page_table,
-                lengths=lengths, active=active, window_len=window_len)
+                lengths=lengths, active=active, window_len=window_len,
+                layer=layer)
         if kind == "hybrid":
             return HY.hybrid_decode_window_paged(
                 lp["mixer"], acfg, cfg.ssm, h, lc, page_table=page_table,
@@ -945,7 +971,7 @@ def draft_step(params: dict, cfg: ModelConfig, token_t, states, *,
     if cfg.embed_scale:
         x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
 
-    def mix_fn(kind, lp, h, lc):
+    def mix_fn(kind, lp, h, lc, layer):
         return A.linear_draft_attention(lp["attn"], acfg, h, lc,
                                         positions=positions, active=active)
 

@@ -272,12 +272,14 @@ class _ResumeState:
 # zeros on swap-in (the padded rows only ever write the trash page).  Page
 # axes are located name-by-position-from-the-end, matching the leaf layout
 # of models/attention.extract_paged_state regardless of leading (e.g. group)
-# axes: k/v pages are (..., P, Hkv, bk, Dh), pooled keys (..., P, Hkv, Dh);
-# quantized pools add per-row scales (..., P, Hkv, bk) / (..., P, Hkv) that
-# swap with their pages (codes + scales together keep the round trip
-# bit-exact within the quantized representation).
+# axes: k/v pages are (..., P, Hkv, bk, Dh), pooled keys (..., P, Hkv, Dh),
+# MLA's pooled latents (..., P, d); quantized pools add per-row scales
+# (..., P, Hkv, bk) / (..., P, Hkv) / (..., P) that swap with their pages
+# (codes + scales together keep the round trip bit-exact within the
+# quantized representation).
 _PAGE_AXIS_FROM_END = {"k_pages": 4, "v_pages": 4, "pooled_pages": 3,
-                       "k_scale": 3, "v_scale": 3, "pooled_scale": 2}
+                       "pooled_lat": 2, "k_scale": 3, "v_scale": 3,
+                       "pooled_scale": 2, "pooled_lat_scale": 1}
 
 
 def _map_page_leaves(state, fn):

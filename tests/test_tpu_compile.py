@@ -70,8 +70,8 @@ def spec(topo):
     cc.reset_cache()
 
 
-def _compile(fn, *args):
-    compiled = jax.jit(fn).lower(*args).compile()
+def _compile(fn, *args, donate=()):
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
@@ -172,6 +172,7 @@ def test_dense_decode_compiles_at_qwen3_width(spec, window, quant_bits,
 
 # bf16 matmul outputs and float all-reduces fed by a dot in compiled HLO
 _MATMUL = re.compile(r"= bf16\[([0-9,]*)\]\S* convolution\(")
+_COPY = re.compile(r"= \w+\[([0-9,]*)\]\S* copy\(")
 _DOT_ALL_REDUCE = re.compile(
     r"= (?:f32|bf16)\[[0-9,]*\]\S* all-reduce\(.*op_name=\"[^\"]*dot_general")
 
@@ -237,9 +238,10 @@ def test_sharded_serving_keeps_single_chip_matmuls(spec, monkeypatch, topo):
 
 # deepseek-v2-lite serving (bench/configs/deepseek_v2_lite.json): d 2048,
 # 16 heads, latent 512 + 64, 8 of 64 experts held, expert width 1408,
-# top-6; 32 decode slots, 256-token prefill chunks, 5,120-token slots
+# top-6; 32 decode slots, 256-token prefill chunks, 5,120-token slots,
+# the decode instance's pool of 2,144 pages (bench/traffic/decode_instance)
 DS_D, DS_FF, DS_HELD, DS_TOPK, DS_EXPERTS = 2048, 1408, 8, 6, 64
-DS_SLOTS, DS_CHUNK, DS_MAXP = 32, 256, 5120 // 64
+DS_SLOTS, DS_CHUNK, DS_MAXP, DS_PAGES = 32, 256, 5120 // 64, 2144
 
 
 @pytest.mark.parametrize("rows", [DS_SLOTS * DS_TOPK, DS_CHUNK * DS_TOPK])
@@ -261,19 +263,21 @@ def test_moe_experts_compiles_at_deepseek_width(spec, rows):
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_mla_programs_compile_at_deepseek_width(spec, monkeypatch, program):
     """The MLA latent prefill-chunk and decode programs at published
-    widths (the dense first layer and one expert layer), with the expert
-    kernel in the program: the path the chip serves."""
+    widths (the dense first layer and two scanned expert layers), with the
+    expert kernel in the program: the path the chip serves.  The scan
+    reads and writes the latent pool in place, so no op copies a layer's
+    pool, or the stack of them, into another layout."""
     from repro.models.api import build_model
     from repro.models.moe import MoEConfig
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = get_config("deepseek_v2_lite", n_layers=2, moe=MoEConfig(
+    cfg = get_config("deepseek_v2_lite", n_layers=3, moe=MoEConfig(
         num_experts=DS_EXPERTS, top_k=DS_TOPK, d_ff_expert=DS_FF,
         num_shared=2, held_experts=DS_HELD))
     model = build_model(cfg)
     place = lambda t: jax.tree.map(lambda a: spec(a.shape, a.dtype), t)
     params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     caches = place(jax.eval_shape(
-        lambda: model.init_paged_caches(DS_SLOTS, 2 * DS_MAXP + 1)))
+        lambda: model.init_paged_caches(DS_SLOTS, DS_PAGES)))
     i32 = lambda *s: spec(s, jnp.int32)
     if program == "prefill":
         fn, batch = model.prefill_chunk, {
@@ -283,5 +287,10 @@ def test_mla_programs_compile_at_deepseek_width(spec, monkeypatch, program):
         fn, batch = model.decode_paged, {
             "token": i32(DS_SLOTS), "page_table": i32(DS_SLOTS, DS_MAXP),
             "lengths": i32(DS_SLOTS), "active": spec((DS_SLOTS,), jnp.bool_)}
-    text = _compile(fn, params, batch, caches).as_text()
+    # the pool donated, as the engine's step programs donate it
+    text = _compile(fn, params, batch, caches, donate=2).as_text()
     assert "moe_experts_bfloat16" in text
+    pool_copies = [c for c in _COPY.findall(text)
+                   if str(DS_PAGES) in c.split(",") and math.prod(
+                       map(int, c.split(","))) >= DS_PAGES * cfg.block_k]
+    assert not pool_copies, pool_copies
