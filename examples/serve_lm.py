@@ -3,13 +3,11 @@
     PYTHONPATH=src python examples/serve_lm.py
 
 Trains a tiny model briefly (so generations aren't pure noise), then serves
-a mixed-length workload twice: through the continuous-batching ServeEngine
-(block-paged KV cache, per-slot offsets, chunked prefill) and through the
-retired StaticWaveEngine (all slots join at sequence start, the wave drains
-before refilling — kept ONLY as this comparison baseline; every LM family,
-including MLA, recurrent and hybrid stacks, serves through ServeEngine).
-The long prompt in the mix stalls the static waves but interleaves with
-ongoing decode under the paged engine.
+a mixed-length workload through the continuous-batching ServeEngine
+(block-paged KV cache, per-slot offsets, chunked prefill): once with plain
+decode and once with self-speculative decoding, whose greedy outputs must
+be token-identical.  The long prompt in the mix is prefilled in chunks
+that interleave with ongoing decode.
 """
 import tempfile
 import time
@@ -18,8 +16,7 @@ from repro.configs import get_smoke_config
 from repro.data import make_dataset
 from repro.models.api import build_model
 from repro.optim import AdamWConfig
-from repro.serve import (EngineConfig, ServeEngine, StaticWaveEngine,
-                         make_mixed_requests)
+from repro.serve import EngineConfig, ServeEngine, make_mixed_requests
 from repro.train import TrainConfig, Trainer, TrainerConfig
 
 # mixed lengths on both ends: mostly short prompts plus one long one, and
@@ -89,14 +86,6 @@ def main():
           f"({steps / steps_s:.2f}x fewer), {dt_s:.2f}s, "
           f"acceptance {spec.stats['spec_accepted'] / drafted:.2f}, "
           "outputs token-identical to plain decode")
-
-    print("\n== static generation waves (baseline) ==")
-    wave = StaticWaveEngine(model, ecfg)
-    wave.load(params)
-    reqs_w = make_requests(cfg)
-    toks_w, dt_w, _ = drive(wave, reqs_w)
-    print(f"{toks_w} tokens in {dt_w:.2f}s  ({toks_w / dt_w:.1f} tok/s)")
-    print(f"\ncontinuous/static throughput: {(toks / dt) / (toks_w / dt_w):.2f}x")
 
 
 if __name__ == "__main__":

@@ -25,8 +25,7 @@ of keeping per-slot attention math EXACTLY the arithmetic of the
 single-device engine — no cross-device softmax combine, no float
 reassociation, so greedy outputs stay token-identical (asserted by
 tests/test_mesh_serving.py).  A production kernel would DMA only the
-pages the slot's table references; the roofline treats the pool bytes as
-HBM-local either way (benchmarks/fig13_mesh_scaling.py).
+pages the slot's table references.
 
 Only the kernel itself runs split.  Its other operands and its output,
 and in the model the residual stream, the rope positions and q/k/v, are

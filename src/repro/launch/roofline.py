@@ -62,133 +62,6 @@ def arch_param_counts(arch: str) -> dict:
     return {"total": total, "active": active}
 
 
-# ---------------------------------------------------------------------------
-# Quantized page-pool sizing (EngineConfig.kv_quant)
-# ---------------------------------------------------------------------------
-# Bytes per stored K/V element by pool storage mode.  'none' is the bf16
-# serving baseline; int8/fp8 pools store 1-byte codes plus one fp32 scale
-# per (page, kv head, token row) — the scale overhead is Dh elements'
-# worth of f32 per row, i.e. 4/Dh relative, ~3% at Dh=128.
-
-KV_QUANT_BYTES = {"none": 2, "int8": 1, "fp8": 1}
-
-
-def kv_page_bytes(hkv: int, page_tokens: int, head_dim: int,
-                  kv_quant: str = "none", *, sla2: bool = False) -> int:
-    """HBM bytes of ONE physical page of ONE layer's pool.
-
-    K + V codes (2 * hkv * page_tokens * head_dim elements) at the
-    storage width, plus — when quantized — the per-row fp32 scales
-    (2 * hkv * page_tokens).  ``sla2=True`` adds the per-page pooled
-    router key (hkv * head_dim codes + hkv fp32 scales when quantized)."""
-    el = KV_QUANT_BYTES[kv_quant]
-    n_kv = 2 * hkv * page_tokens * head_dim
-    total = n_kv * el
-    if kv_quant != "none":
-        total += 2 * hkv * page_tokens * 4          # k_scale + v_scale rows
-    if sla2:
-        total += hkv * head_dim * el                # pooled router key
-        if kv_quant != "none":
-            total += hkv * 4                        # pooled_scale
-    return total
-
-
-def mla_latent_page_bytes(latent_dim: int, page_tokens: int,
-                          kv_quant: str = "none") -> int:
-    """HBM bytes of ONE physical latent page of ONE MLA layer's pool
-    (models.mla.init_mla_paged_cache): the compressed latent rows
-    (page_tokens * latent_dim codes — stored ONCE, not as separate K and
-    V) plus the per-page pooled router latent; quantized pools add one
-    fp32 scale per token row and per pooled key, unquantized pools keep
-    the pooled key in fp32.  Compare against ``kv_page_bytes(hkv=heads,
-    ...)`` for the dense-cache equivalent — the paged-MLA memory win the
-    fig14 family benchmark plots.  These are the latent's own bytes: the
-    pool stores each row padded to whole 128-lane tiles (models/mla.py),
-    640 values for DeepSeek-V2's 576."""
-    el = KV_QUANT_BYTES[kv_quant]
-    total = page_tokens * latent_dim * el           # k_pages rows
-    if kv_quant != "none":
-        total += page_tokens * 4                    # k_scale
-        total += latent_dim * el + 4                # pooled codes + scale
-    else:
-        total += latent_dim * 4                     # pooled key kept f32
-    return total
-
-
-def pool_pages_for_hbm(budget_bytes: float, n_layers: int, hkv: int,
-                       page_tokens: int, head_dim: int,
-                       kv_quant: str = "none", *, sla2: bool = False) -> int:
-    """Physical pages an HBM budget holds when every layer keeps a pool
-    (the serving allocator sizes all layers' pools to the same page
-    count)."""
-    per_page = n_layers * kv_page_bytes(hkv, page_tokens, head_dim,
-                                        kv_quant, sla2=sla2)
-    return int(budget_bytes // per_page)
-
-
-def sharded_pool_slots(n_hosts: int, hbm_per_host: float,
-                       weight_bytes: float, n_layers: int, hkv: int,
-                       page_tokens: int, head_dim: int,
-                       pages_per_slot: int, kv_quant: str = "none", *,
-                       sla2: bool = False) -> dict:
-    """Page-pool capacity of an ``n_hosts`` serving mesh — the
-    fig13_mesh_scaling model.
-
-    Every host keeps a full weight replica (serving params shard the
-    model axis only — ``distributed.sharding.serving_param_specs`` — and
-    the host mesh has model=1) and gives the rest of its HBM to its page
-    pool shard (``cache_specs``: page axis over all mesh axes).  Total
-    concurrent slots therefore scale with hosts at fixed per-slot page
-    demand: slots = n_hosts * pages_per_host // pages_per_slot."""
-    per_host_budget = max(0.0, hbm_per_host - weight_bytes)
-    pages_host = pool_pages_for_hbm(per_host_budget, n_layers, hkv,
-                                    page_tokens, head_dim, kv_quant,
-                                    sla2=sla2)
-    total_pages = n_hosts * pages_host
-    return {"hosts": n_hosts, "pages_per_host": pages_host,
-            "total_pages": total_pages,
-            "slots": total_pages // max(1, pages_per_slot)}
-
-
-# ---------------------------------------------------------------------------
-# Diffusion attention traffic (serve/diffusion.DiffusionEngine hot loop)
-# ---------------------------------------------------------------------------
-
-def diffusion_attention_bytes(n: int, head_dim: int, *,
-                              sparsity: float = 0.0, method: str = "full",
-                              block_q: int = 128, block_k: int = 64,
-                              el_bytes: int = 2) -> float:
-    """HBM bytes of ONE bidirectional self-attention forward per head at
-    ``n`` latent tokens — the denoise-step hot loop modeled by
-    benchmarks/fig12_diffusion.py.
-
-    All methods are flash-style (no N^2 materialisation): Q is read once
-    and O written once.  'full' additionally streams all of K and V;
-    the sparse branch streams only the selected ``(1 - sparsity)``
-    fraction of K/V tiles; sla/sla2 add one full K/V pass for the linear
-    states plus the phi(Q) side, and every routed method pays the router:
-    the block-pooled K (n/block_k rows) and the (n/block_q, n/block_k)
-    score/Top-k map, recomputed every denoise step."""
-    qo = 2 * n * head_dim * el_bytes                 # Q read + O write
-    if method == "full":
-        return qo + 2 * n * head_dim * el_bytes      # all of K + V
-    kv = (1.0 - sparsity) * 2 * n * head_dim * el_bytes
-    router = (n / block_k) * head_dim * el_bytes \
-        + (n / block_q) * (n / block_k) * 4
-    total = qo + kv + router
-    if method in ("sla", "sla2"):
-        total += 3 * n * head_dim * el_bytes         # linear K,V pass + phiQ
-    return total
-
-
-def attention_roofline_s(flops: float, bytes_: float) -> float:
-    """max(compute, memory) seconds on one v5e.  Quantized-MXU speedup
-    is modeled upstream by ``benchmarks.common.attention_flops``'s
-    ``quant_speed`` (it divides the sparse-branch FLOPs), so the peaks
-    here stay bf16."""
-    return max(flops / V5E_PEAKS.flops_bf16, bytes_ / V5E_PEAKS.hbm_bw)
-
-
 _NOTES = {
     "compute": ("compute-bound: raise MXU utilisation — larger per-chip "
                 "tiles (bigger microbatch or less model parallelism), int8 "

@@ -38,7 +38,7 @@ def main():
     params = model.init(jax.random.PRNGKey(args.seed))
     ecfg = EngineConfig(max_slots=args.slots, max_len=args.max_len)
     # every LM family serves paged: attention K/V pages, MLA latent pages,
-    # recurrent state checkpoints (StaticWaveEngine is benchmark-only)
+    # recurrent state checkpoints
     eng = ServeEngine(model, ecfg)
     eng.load(params)
     rng = np.random.default_rng(args.seed)

@@ -112,46 +112,40 @@ class Model:
 # ---------------------------------------------------------------------------
 
 def _lm_model(cfg: T.ModelConfig) -> Model:
-    paged = {}
-    if T.supports_paged(cfg):
-        paged = dict(
-            init_paged_caches=lambda batch, num_pages, **kw:
-                T.init_paged_caches(cfg, batch, num_pages, **kw),
-            prefill_chunk=lambda p, b, c: T.prefill_chunk(
-                p, cfg, b["tokens"], c, page_row=b["page_row"],
-                offset=b["offset"], chunk_len=b["chunk_len"],
-                slot=b["slot"]),
-            decode_paged=lambda p, b, c: T.decode_paged(
-                p, cfg, b["token"], c, page_table=b["page_table"],
-                lengths=b["lengths"], active=b["active"]),
-            swap_out=lambda c, page_row, slot: T.swap_out_slot(
-                cfg, c, page_row, slot),
-            swap_in=lambda c, page_row, slot, state: T.swap_in_slot(
-                cfg, c, page_row, slot, state),
-            extract_totals=lambda c, slot: T.extract_linear_totals(
-                cfg, c, slot),
-            insert_totals=lambda c, slot, st: T.insert_linear_totals(
-                cfg, c, slot, st),
-            copy_page=lambda c, src, dst: T.copy_kv_page(cfg, c, src, dst),
-            decode_verify=lambda p, b, c: T.decode_verify(
-                p, cfg, b["tokens"], c, page_table=b["page_table"],
-                lengths=b["lengths"], active=b["active"],
-                window_len=b["window_len"]),
-            commit_window=lambda c, page_table, lengths, accepted, active,
-                window: T.commit_window(cfg, c, page_table, lengths,
-                                        accepted, active, window),
+    paged = dict(
+        init_paged_caches=lambda batch, num_pages, **kw:
+            T.init_paged_caches(cfg, batch, num_pages, **kw),
+        prefill_chunk=lambda p, b, c: T.prefill_chunk(
+            p, cfg, b["tokens"], c, page_row=b["page_row"],
+            offset=b["offset"], chunk_len=b["chunk_len"], slot=b["slot"]),
+        decode_paged=lambda p, b, c: T.decode_paged(
+            p, cfg, b["token"], c, page_table=b["page_table"],
+            lengths=b["lengths"], active=b["active"]),
+        swap_out=lambda c, page_row, slot: T.swap_out_slot(
+            cfg, c, page_row, slot),
+        swap_in=lambda c, page_row, slot, state: T.swap_in_slot(
+            cfg, c, page_row, slot, state),
+        extract_totals=lambda c, slot: T.extract_linear_totals(cfg, c, slot),
+        insert_totals=lambda c, slot, st: T.insert_linear_totals(
+            cfg, c, slot, st),
+        copy_page=lambda c, src, dst: T.copy_kv_page(cfg, c, src, dst),
+        decode_verify=lambda p, b, c: T.decode_verify(
+            p, cfg, b["tokens"], c, page_table=b["page_table"],
+            lengths=b["lengths"], active=b["active"],
+            window_len=b["window_len"]),
+        commit_window=lambda c, page_table, lengths, accepted, active,
+            window: T.commit_window(cfg, c, page_table, lengths, accepted,
+                                    active, window),
+        has_slot_state=T.has_slot_state(cfg),
+    )
+    if T.has_linear_drafter(cfg):
+        paged.update(
+            draft_init=lambda c, b: T.draft_init(
+                cfg, c, b["page_table"], b["lengths"], b["active"]),
+            draft_step=lambda p, b, st: T.draft_step(
+                p, cfg, b["token"], st, positions=b["positions"],
+                active=b["active"]),
         )
-        paged["has_slot_state"] = T.has_slot_state(cfg)
-        kinds = tuple(cfg.first_kinds) + tuple(cfg.layer_kinds)
-        attn_only = all(k in ("dense", "moe") for k in kinds)
-        if cfg.mechanism == "sla2" and attn_only:
-            paged.update(
-                draft_init=lambda c, b: T.draft_init(
-                    cfg, c, b["page_table"], b["lengths"], b["active"]),
-                draft_step=lambda p, b, st: T.draft_step(
-                    p, cfg, b["token"], st, positions=b["positions"],
-                    active=b["active"]),
-            )
     return Model(
         kind="lm", cfg=cfg,
         init=lambda key: T.init_model(key, cfg),

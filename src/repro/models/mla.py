@@ -317,10 +317,9 @@ def mla_decode_step(params: dict, x_t: jax.Array, cache: dict, *,
 # ``pooled_lat`` (P, latent_dim); the attention._PAGE_KEYS swap machinery
 # carries both.  There is NO v_pages: the values are the c_kv slice of the
 # latent (``lat[..., :kv_lora_rank]``), which is what makes the latent
-# pool a fraction of a dense pool's bytes (launch/roofline.py
-# mla_latent_page_bytes).  The gather-path jnp implementations below are
-# the only implementations (no fused MLA page kernels yet) and serve as
-# the oracle for any future kernel work.
+# pool a fraction of a dense pool's bytes.  The gather-path jnp
+# implementations below are the only implementations (no fused MLA page
+# kernels yet) and serve as the oracle for any future kernel work.
 #
 # Every pool access goes through the accessors below (_lat_read,
 # _store_lat_rows, _store_lat_pooled, _tot, _add_tot).  A layer of the

@@ -8,14 +8,11 @@ so heterogeneous stacks (llama4's alternating dense/MoE, deepseek's leading
 dense-FFN layer, xlstm's 7:1 mLSTM:sLSTM pattern) scan over a homogeneous
 *group* while keeping the HLO compact (one group body regardless of depth).
 
-Layer kinds:
-    dense     pre-norm attention + gated MLP
-    moe       pre-norm attention + MoE FFN (EP-sharded)
-    mla_dense DeepSeek MLA attention + gated MLP
-    mla_moe   DeepSeek MLA attention + MoE FFN
-    hybrid    Hymba parallel attention+Mamba mixer + MLP
-    mlstm     xLSTM matrix-memory block (no FFN)
-    slstm     xLSTM scalar-memory block (no FFN)
+A layer kind names a pre-norm block: a token mixer (``Mixer``) and an FFN
+(``FFN``, or none).  ``LAYER_KINDS`` is the one table of kinds: each entry
+holds every operation the stack asks of its mixer and FFN, from init and
+the training forward to the paged serving programs, so the code below
+looks a kind up and never branches on its name.
 
 Params are plain nested dicts (stacked on a leading group axis inside
 "groups"); sharding is assigned by key-path in distributed/sharding.py.
@@ -24,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -42,7 +39,7 @@ from repro.core import maps
 class ModelConfig:
     """One decoder-only architecture: layer layout, attention mechanism and
     masking, SLA2 knobs, paged-serving switches, and training/system
-    fields.  See the module docstring for the layer-kind vocabulary."""
+    fields.  ``LAYER_KINDS`` holds the layer-kind vocabulary."""
     name: str = "model"
     family: str = "dense"           # dense|moe|ssm|hybrid|vlm|audio|dit
     n_layers: int = 2
@@ -141,36 +138,247 @@ class ModelConfig:
 
 
 # ===========================================================================
+# layer kinds: one token mixer and one FFN (or none) each
+# ===========================================================================
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    """Every operation the stack asks of one token mixer.  ``p`` is the
+    mixer's own params (``lp[key]``), ``c`` its own cache; the paged ops
+    take the program's keyword batch and ``layer``, the scan's layer index
+    when ``c`` is a scanned group's stacked cache (``in_place`` mixers
+    only; the others get their layer's slice and None)."""
+    key: str                # the param / cache key its leaves live under
+    cache_kind: str         # paged cache geometry (docs/paths.md)
+    slot_state: bool        # per-slot state beyond K/V pages, which the
+                            # prefix cache snapshots and restores
+    init: Callable          # (rng, cfg) -> params
+    forward: Callable       # (cfg, p, h, positions) -> y
+    init_cache: Callable    # (cfg, batch, max_len, dtype) -> static cache
+    prefill: Callable       # (cfg, p, h, c, positions) -> (y, c)
+    decode: Callable        # (cfg, p, h, c) -> (y, c)
+    init_paged: Callable    # (cfg, batch, num_pages, window, dtype) -> c
+    prefill_chunk: Callable  # (cfg, p, h, c, layer, **batch) -> (y, c)
+    decode_paged: Callable  # as prefill_chunk
+    decode_verify: Callable  # as prefill_chunk
+    commit_window: Callable  # (cfg, c, **batch) -> c
+    # a composite cache's sub-caches, each "pages" (K/V pages with their
+    # slot totals) or "slot" (slot state alone); None: the whole cache is
+    # of the first sort
+    parts: Optional[dict] = None
+    in_place: bool = False  # reads and writes the stacked cache in place
+    # the SLA2 linear drafter (speculative 'linear'), attention only
+    draft_init: Optional[Callable] = None   # (cfg, c, **batch) -> state
+    draft_step: Optional[Callable] = None   # as prefill_chunk
+
+
+@dataclasses.dataclass(frozen=True)
+class FFN:
+    """The block's second half: training runs ``train``, serving
+    ``serve``; ``split`` separates what a layer scan slices per layer
+    from the weights the FFN reads out of the whole stack."""
+    key: str
+    init: Callable          # (rng, cfg) -> params
+    train: Callable         # (cfg, p, h) -> (y, aux loss or None)
+    serve: Callable         # (cfg, p, h, rows, stack) -> (y, counters|None)
+    split: Optional[Callable] = None
+
+
+class Layer(NamedTuple):
+    """One layer kind: its token mixer and its FFN (None: no FFN half)."""
+    mixer: Mixer
+    ffn: Optional[FFN]
+
+
+_ATTN = Mixer(
+    key="attn", cache_kind="paged-kv", slot_state=False,
+    init=lambda k, cfg: A.init_attention(k, cfg.attention_config(),
+                                         cfg.param_dtype),
+    forward=lambda cfg, p, h, pos: A.attention_forward(
+        p, cfg.attention_config(), h, pos),
+    init_cache=lambda cfg, b, n, dt: A.init_cache(cfg.attention_config(),
+                                                  b, n, dt),
+    prefill=lambda cfg, p, h, c, pos: A.prefill_cache(
+        p, cfg.attention_config(), h, c),
+    decode=lambda cfg, p, h, c: A.decode_step(p, cfg.attention_config(), h,
+                                              c),
+    init_paged=lambda cfg, b, n, w, dt: A.init_paged_cache(
+        cfg.attention_config(), n, b, dt),
+    prefill_chunk=lambda cfg, p, h, c, layer, **kw: A.chunk_prefill_paged(
+        p, cfg.attention_config(), h, c, **kw),
+    decode_paged=lambda cfg, p, h, c, layer, **kw: A.decode_step_paged(
+        p, cfg.attention_config(), h, c, **kw),
+    decode_verify=lambda cfg, p, h, c, layer, **kw: A.decode_window_paged(
+        p, cfg.attention_config(), h, c, **kw),
+    commit_window=lambda cfg, c, **kw: A.commit_paged_window(
+        cfg.attention_config(), c, **kw),
+    draft_init=lambda cfg, c, **kw: A.linear_draft_state(
+        cfg.attention_config(), c, **kw),
+    draft_step=lambda cfg, p, h, c, layer, **kw: A.linear_draft_attention(
+        p, cfg.attention_config(), h, c, **kw))
+
+
+def _mla_seq(cfg):
+    """MLA's full-sequence knobs (training forward, static prefill)."""
+    return dict(mcfg=cfg.mla, num_heads=cfg.num_heads,
+                mechanism=cfg.mechanism, sla2_cfg=cfg.sla2_config())
+
+
+def _mla_paged(cfg, **kw):
+    """MLA's paged knobs; the decode ops add ``k_frac``."""
+    return dict(mcfg=cfg.mla, num_heads=cfg.num_heads, block_k=cfg.block_k,
+                kv_quant=cfg.kv_quant, **kw)
+
+
+_MLA = Mixer(
+    key="mla", cache_kind="paged-latent", slot_state=True, in_place=True,
+    init=lambda k, cfg: MLA.init_mla(
+        k, cfg.d_model, cfg.num_heads, cfg.mla, mechanism=cfg.mechanism,
+        sla2_cfg=cfg.sla2_config(),
+        n_q_blocks=max(1, cfg.max_target_len // cfg.block_q),
+        dtype=cfg.param_dtype),
+    forward=lambda cfg, p, h, pos: MLA.mla_forward(p, h, pos,
+                                                   **_mla_seq(cfg)),
+    init_cache=lambda cfg, b, n, dt: MLA.init_mla_cache(
+        cfg.mla, cfg.num_heads, b, n, cfg.block_k, dt),
+    prefill=lambda cfg, p, h, c, pos: MLA.mla_prefill(p, h, pos, c,
+                                                      **_mla_seq(cfg)),
+    decode=lambda cfg, p, h, c: MLA.mla_decode_step(
+        p, h, c, mcfg=cfg.mla, num_heads=cfg.num_heads, k_frac=cfg.k_frac,
+        block_k=cfg.block_k),
+    init_paged=lambda cfg, b, n, w, dt: MLA.init_mla_paged_cache(
+        cfg.mla, n, b, cfg.block_k, kv_quant=cfg.kv_quant, dtype=dt),
+    prefill_chunk=lambda cfg, p, h, c, layer, **kw:
+        MLA.mla_prefill_chunk_paged(p, h, c, layer=layer,
+                                    **_mla_paged(cfg, **kw)),
+    decode_paged=lambda cfg, p, h, c, layer, **kw: MLA.mla_decode_step_paged(
+        p, h, c, k_frac=cfg.k_frac, layer=layer, **_mla_paged(cfg, **kw)),
+    decode_verify=lambda cfg, p, h, c, layer, **kw:
+        MLA.mla_decode_window_paged(p, h, c, k_frac=cfg.k_frac, layer=layer,
+                                    **_mla_paged(cfg, **kw)),
+    commit_window=lambda cfg, c, **kw: MLA.mla_commit_window(
+        c, mcfg=cfg.mla, block_k=cfg.block_k, kv_quant=cfg.kv_quant, **kw))
+
+_HYBRID = Mixer(
+    key="mixer", cache_kind="paged-kv + state", slot_state=True,
+    parts={"attn": "pages", "ssm": "slot"},
+    init=lambda k, cfg: HY.init_hybrid(k, cfg.attention_config(), cfg.ssm,
+                                       cfg.param_dtype),
+    forward=lambda cfg, p, h, pos: HY.hybrid_forward(
+        p, cfg.attention_config(), cfg.ssm, h, pos),
+    init_cache=lambda cfg, b, n, dt: HY.init_hybrid_cache(
+        cfg.attention_config(), cfg.ssm, b, n, dt),
+    prefill=lambda cfg, p, h, c, pos: HY.hybrid_prefill(
+        p, cfg.attention_config(), cfg.ssm, h, c, pos),
+    decode=lambda cfg, p, h, c: HY.hybrid_decode_step(
+        p, cfg.attention_config(), cfg.ssm, h, c),
+    init_paged=lambda cfg, b, n, w, dt: HY.init_hybrid_paged_cache(
+        cfg.attention_config(), cfg.ssm, n, b, window=w, dtype=dt),
+    prefill_chunk=lambda cfg, p, h, c, layer, **kw:
+        HY.hybrid_prefill_chunk_paged(p, cfg.attention_config(), cfg.ssm,
+                                      h, c, **kw),
+    decode_paged=lambda cfg, p, h, c, layer, **kw:
+        HY.hybrid_decode_step_paged(p, cfg.attention_config(), cfg.ssm, h,
+                                    c, **kw),
+    decode_verify=lambda cfg, p, h, c, layer, **kw:
+        HY.hybrid_decode_window_paged(p, cfg.attention_config(), cfg.ssm,
+                                      h, c, **kw),
+    commit_window=lambda cfg, c, **kw: HY.hybrid_commit_window(
+        cfg.attention_config(), cfg.ssm, c, **kw))
+
+
+def _core(name, init, forward, decode, init_state) -> Mixer:
+    """An xLSTM block's recurrent core: no pages, one state checkpoint per
+    slot (``SSM.init_paged_state``)."""
+    return Mixer(
+        key="core", cache_kind="state", slot_state=True,
+        init=lambda k, cfg: init(k, cfg.d_model, cfg.ssm, cfg.param_dtype),
+        forward=lambda cfg, p, h, pos: forward(p, h, cfg.ssm)[0],
+        init_cache=lambda cfg, b, n, dt: init_state(cfg.ssm, b),
+        prefill=lambda cfg, p, h, c, pos: forward(p, h, cfg.ssm),
+        decode=lambda cfg, p, h, c: decode(p, h, cfg.ssm, c),
+        init_paged=lambda cfg, b, n, w, dt: SSM.init_paged_state(
+            name, cfg.ssm, b, w),
+        prefill_chunk=lambda cfg, p, h, c, layer, *, page_row, **kw:
+            SSM.ssm_prefill_paged(name, p, cfg.ssm, h, c, **kw),
+        decode_paged=lambda cfg, p, h, c, layer, *, active, **_:
+            SSM.ssm_decode_paged(name, p, cfg.ssm, h, c, active=active),
+        decode_verify=lambda cfg, p, h, c, layer, *, active, window_len,
+            **_: SSM.ssm_decode_window_paged(
+                name, p, cfg.ssm, h, c, active=active, window_len=window_len),
+        commit_window=lambda cfg, c, *, accepted, active, window, **_:
+            SSM.ssm_commit_window(name, cfg.ssm, c, accepted=accepted,
+                                  active=active, window=window))
+
+
+_MLP = FFN(
+    key="mlp",
+    init=lambda k, cfg: L.init_mlp(k, cfg.d_model, cfg.d_ff,
+                                   gated=cfg.mlp_gated,
+                                   dtype=cfg.param_dtype),
+    train=lambda cfg, p, h: (L.mlp(p, h, activation=cfg.mlp_activation),
+                             None),
+    serve=lambda cfg, p, h, rows=None, stack=None: (
+        L.mlp(p, h, activation=cfg.mlp_activation), None))
+
+# training's GShard layer over all experts; serving's dropless layer over
+# the held experts, with its counters
+_MOE = FFN(
+    key="moe",
+    init=lambda k, cfg: MOE.init_moe(k, cfg.d_model, cfg.moe,
+                                     cfg.param_dtype),
+    train=lambda cfg, p, h: MOE.moe_ffn(p, h, cfg.moe, ep_axis=cfg.ep_axis),
+    serve=lambda cfg, p, h, rows=None, stack=None: MOE.moe_layer(
+        p, h, cfg.moe, rows, stack),
+    split=MOE.split_stack)
+
+# Every layer kind serves through the paged engine; tools/gen_path_matrix.py
+# renders this table into docs/paths.md.
+LAYER_KINDS = {
+    "dense": Layer(_ATTN, _MLP),           # attention + gated MLP
+    "moe": Layer(_ATTN, _MOE),             # attention + expert FFN
+    "mla_dense": Layer(_MLA, _MLP),        # DeepSeek MLA + gated MLP
+    "mla_moe": Layer(_MLA, _MOE),          # DeepSeek MLA + expert FFN
+    "hybrid": Layer(_HYBRID, _MLP),        # Hymba attention+Mamba + MLP
+    "mlstm": Layer(_core("mlstm", SSM.init_mlstm, SSM.mlstm_forward,
+                        SSM.mlstm_decode_step, SSM.mlstm_init_state), None),
+    "slstm": Layer(_core("slstm", SSM.init_slstm, SSM.slstm_forward,
+                        SSM.slstm_decode_step, SSM.slstm_init_state), None),
+}
+
+
+def _kinds(cfg: ModelConfig) -> tuple:
+    return tuple(cfg.first_kinds) + tuple(cfg.layer_kinds)
+
+
+def has_slot_state(cfg: ModelConfig) -> bool:
+    """True when any layer keeps per-slot state the serving prefix cache
+    must snapshot on insert and restore on hit — SLA2 linear totals
+    (mechanism 'sla2', incl. MLA) or recurrent-mixer checkpoints."""
+    return cfg.mechanism == "sla2" or any(
+        LAYER_KINDS[k].mixer.slot_state for k in _kinds(cfg))
+
+
+def has_linear_drafter(cfg: ModelConfig) -> bool:
+    """True when the SLA2 linear drafter can serve every layer: mechanism
+    'sla2' and only mixers with a draft path (attention)."""
+    return cfg.mechanism == "sla2" and all(
+        LAYER_KINDS[k].mixer.draft_step is not None for k in _kinds(cfg))
+
+
+# ===========================================================================
 # init
 # ===========================================================================
 
 def _init_layer(key, cfg: ModelConfig, kind: str) -> dict:
+    mixer, ffn = LAYER_KINDS[kind]
     ks = jax.random.split(key, 4)
     d, dt = cfg.d_model, cfg.param_dtype
-    p: dict[str, Any] = {"ln1": L.init_rmsnorm(d, dt)}
-    if kind in ("dense", "moe"):
-        p["attn"] = A.init_attention(ks[0], cfg.attention_config(), dt)
-    elif kind in ("mla_dense", "mla_moe"):
-        p["mla"] = MLA.init_mla(
-            ks[0], d, cfg.num_heads, cfg.mla, mechanism=cfg.mechanism,
-            sla2_cfg=cfg.sla2_config(),
-            n_q_blocks=max(1, cfg.max_target_len // cfg.block_q), dtype=dt)
-    elif kind == "hybrid":
-        p["mixer"] = HY.init_hybrid(ks[0], cfg.attention_config(), cfg.ssm, dt)
-    elif kind == "mlstm":
-        p["core"] = SSM.init_mlstm(ks[0], d, cfg.ssm, dt)
-        return p
-    elif kind == "slstm":
-        p["core"] = SSM.init_slstm(ks[0], d, cfg.ssm, dt)
-        return p
-    else:
-        raise ValueError(kind)
-    p["ln2"] = L.init_rmsnorm(d, dt)
-    if kind.endswith("moe"):
-        p["moe"] = MOE.init_moe(ks[1], d, cfg.moe, dt)
-    else:
-        p["mlp"] = L.init_mlp(ks[1], d, cfg.d_ff, gated=cfg.mlp_gated,
-                              dtype=dt)
+    p: dict[str, Any] = {"ln1": L.init_rmsnorm(d, dt),
+                         mixer.key: mixer.init(ks[0], cfg)}
+    if ffn is not None:
+        p["ln2"] = L.init_rmsnorm(d, dt)
+        p[ffn.key] = ffn.init(ks[1], cfg)
     return p
 
 
@@ -208,33 +416,27 @@ def init_model(key, cfg: ModelConfig) -> dict:
 # forward
 # ===========================================================================
 
+def _embed(params: dict, cfg: ModelConfig, tokens, inputs_embeds=None):
+    if inputs_embeds is None:
+        x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
+    else:
+        x = inputs_embeds.astype(cfg.param_dtype)
+    if cfg.embed_scale:
+        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
+    return x
+
+
 def _layer_forward(lp: dict, cfg: ModelConfig, kind: str, x, positions):
     """One block. Returns (x, aux_loss)."""
+    mixer, ffn = LAYER_KINDS[kind]
     aux = jnp.zeros((), jnp.float32)
     h = L.rmsnorm(lp["ln1"], x)
-    if kind in ("dense", "moe"):
-        x = x + A.attention_forward(lp["attn"], cfg.attention_config(), h,
-                                    positions)
-    elif kind in ("mla_dense", "mla_moe"):
-        x = x + MLA.mla_forward(
-            lp["mla"], h, positions, mcfg=cfg.mla, num_heads=cfg.num_heads,
-            mechanism=cfg.mechanism, sla2_cfg=cfg.sla2_config())
-    elif kind == "hybrid":
-        x = x + HY.hybrid_forward(lp["mixer"], cfg.attention_config(),
-                                  cfg.ssm, h, positions)
-    elif kind == "mlstm":
-        y, _ = SSM.mlstm_forward(lp["core"], h, cfg.ssm)
-        return x + y, aux
-    elif kind == "slstm":
-        y, _ = SSM.slstm_forward(lp["core"], h, cfg.ssm)
-        return x + y, aux
+    x = x + mixer.forward(cfg, lp[mixer.key], h, positions)
+    if ffn is None:
+        return x, aux
     h2 = L.rmsnorm(lp["ln2"], x)
-    if kind.endswith("moe"):
-        y, aux = MOE.moe_ffn(lp["moe"], h2, cfg.moe, ep_axis=cfg.ep_axis)
-        x = x + y
-    else:
-        x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
-    return x, aux
+    y, a = ffn.train(cfg, lp[ffn.key], h2)
+    return x + y, aux if a is None else a
 
 
 def _group_forward(gp: dict, cfg: ModelConfig, x, positions):
@@ -256,12 +458,7 @@ def _sp_constraint(cfg: ModelConfig, x):
 def forward(params: dict, cfg: ModelConfig, tokens=None, *,
             inputs_embeds=None, positions=None):
     """Full-sequence forward. Returns (hidden (B,N,d) pre-unembed, aux)."""
-    if inputs_embeds is None:
-        x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
-    else:
-        x = inputs_embeds.astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
+    x = _embed(params, cfg, tokens, inputs_embeds)
     b, n, _ = x.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(n), (b, n))
@@ -337,171 +534,91 @@ def lm_loss(params: dict, cfg: ModelConfig, batch: dict):
 # caches / prefill / decode
 # ===========================================================================
 
-def _init_layer_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
-                      dtype=jnp.bfloat16):
-    if kind in ("dense", "moe"):
-        return {"attn": A.init_cache(cfg.attention_config(), batch, max_len,
-                                     dtype)}
-    if kind in ("mla_dense", "mla_moe"):
-        return {"mla": MLA.init_mla_cache(cfg.mla, cfg.num_heads, batch,
-                                          max_len, cfg.block_k, dtype)}
-    if kind == "hybrid":
-        return {"mixer": HY.init_hybrid_cache(cfg.attention_config(),
-                                              cfg.ssm, batch, max_len, dtype)}
-    if kind == "mlstm":
-        return {"core": SSM.mlstm_init_state(cfg.ssm, batch)}
-    if kind == "slstm":
-        return {"core": SSM.slstm_init_state(cfg.ssm, batch)}
-    raise ValueError(kind)
+def _layer_caches(cfg: ModelConfig, make) -> dict:
+    """Every layer's cache ``make(mixer)``, laid out as the params are
+    (prefix layers unrolled, groups stacked for scan)."""
+    def one_layer(kind):
+        mixer = LAYER_KINDS[kind].mixer
+        return {mixer.key: make(mixer)}
 
-
-def init_caches(cfg: ModelConfig, batch: int, max_len: int,
-                dtype=jnp.bfloat16) -> dict:
-    """Static (non-paged) decode caches for every layer, mirroring the
-    param layout (prefix layers unrolled, groups stacked for scan)."""
     caches: dict[str, Any] = {}
     if cfg.first_kinds:
-        caches["prefix_layers"] = [
-            _init_layer_cache(cfg, kind, batch, max_len, dtype)
-            for kind in cfg.first_kinds]
-    one = {f"l{i}": _init_layer_cache(cfg, kind, batch, max_len, dtype)
+        caches["prefix_layers"] = [one_layer(k) for k in cfg.first_kinds]
+    one = {f"l{i}": one_layer(kind)
            for i, kind in enumerate(cfg.layer_kinds)}
     caches["groups"] = jax.tree.map(
         lambda a: jnp.tile(a[None], (cfg.n_groups,) + (1,) * a.ndim), one)
     return caches
 
 
-def _layer_prefill(lp, cfg: ModelConfig, kind, x, lc, positions):
+def init_caches(cfg: ModelConfig, batch: int, max_len: int,
+                dtype=jnp.bfloat16) -> dict:
+    """Static (non-paged) decode caches for every layer."""
+    return _layer_caches(
+        cfg, lambda m: m.init_cache(cfg, batch, max_len, dtype))
+
+
+def _layer_static(lp, cfg: ModelConfig, kind, x, lc, op, *args):
+    """One block over its static cache; ``op`` is the mixer's 'prefill'
+    (``args``: positions) or 'decode'."""
+    mixer, ffn = LAYER_KINDS[kind]
     h = L.rmsnorm(lp["ln1"], x)
-    if kind in ("dense", "moe"):
-        y, c = A.prefill_cache(lp["attn"], cfg.attention_config(), h,
-                               lc["attn"])
-        x = x + y
-        lc = {"attn": c}
-    elif kind in ("mla_dense", "mla_moe"):
-        y, c = MLA.mla_prefill(lp["mla"], h, positions, lc["mla"],
-                               mcfg=cfg.mla, num_heads=cfg.num_heads,
-                               mechanism=cfg.mechanism,
-                               sla2_cfg=cfg.sla2_config())
-        x = x + y
-        lc = {"mla": c}
-    elif kind == "hybrid":
-        y, c = HY.hybrid_prefill(lp["mixer"], cfg.attention_config(),
-                                 cfg.ssm, h, lc["mixer"], positions)
-        x = x + y
-        lc = {"mixer": c}
-    elif kind == "mlstm":
-        y, st = SSM.mlstm_forward(lp["core"], h, cfg.ssm)
-        return x + y, {"core": st}
-    elif kind == "slstm":
-        y, st = SSM.slstm_forward(lp["core"], h, cfg.ssm)
-        return x + y, {"core": st}
-    h2 = L.rmsnorm(lp["ln2"], x)
-    if kind.endswith("moe"):
-        y, _ = MOE.moe_layer(lp["moe"], h2, cfg.moe)
-        x = x + y
-    else:
-        x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
-    return x, lc
+    y, c = getattr(mixer, op)(cfg, lp[mixer.key], h, lc[mixer.key], *args)
+    x = x + y
+    if ffn is not None:
+        h2 = L.rmsnorm(lp["ln2"], x)
+        x = x + ffn.serve(cfg, lp[ffn.key], h2)[0]
+    return x, {mixer.key: c}
 
 
-def _layer_decode(lp, cfg: ModelConfig, kind, x_t, lc):
-    h = L.rmsnorm(lp["ln1"], x_t)
-    if kind in ("dense", "moe"):
-        y, c = A.decode_step(lp["attn"], cfg.attention_config(), h,
-                             lc["attn"])
-        x_t = x_t + y
-        lc = {"attn": c}
-    elif kind in ("mla_dense", "mla_moe"):
-        y, c = MLA.mla_decode_step(lp["mla"], h, lc["mla"], mcfg=cfg.mla,
-                                   num_heads=cfg.num_heads,
-                                   k_frac=cfg.k_frac, block_k=cfg.block_k)
-        x_t = x_t + y
-        lc = {"mla": c}
-    elif kind == "hybrid":
-        y, c = HY.hybrid_decode_step(lp["mixer"], cfg.attention_config(),
-                                     cfg.ssm, h, lc["mixer"])
-        x_t = x_t + y
-        lc = {"mixer": c}
-    elif kind == "mlstm":
-        y, st = SSM.mlstm_decode_step(lp["core"], h, cfg.ssm, lc["core"])
-        return x_t + y, {"core": st}
-    elif kind == "slstm":
-        y, st = SSM.slstm_decode_step(lp["core"], h, cfg.ssm, lc["core"])
-        return x_t + y, {"core": st}
-    h2 = L.rmsnorm(lp["ln2"], x_t)
-    if kind.endswith("moe"):
-        y, _ = MOE.moe_layer(lp["moe"], h2, cfg.moe)
-        x_t = x_t + y
-    else:
-        x_t = x_t + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
-    return x_t, lc
+def _static_stack(params, cfg: ModelConfig, x, caches, op, *args):
+    """Run the layer stack over the static caches; returns (final-normed
+    hidden, caches)."""
+    caches = dict(caches)
+    if cfg.first_kinds:
+        new_pref = []
+        for i, kind in enumerate(cfg.first_kinds):
+            x, lc = _layer_static(params["prefix_layers"][i], cfg, kind, x,
+                                  caches["prefix_layers"][i], op, *args)
+            new_pref.append(lc)
+        caches["prefix_layers"] = new_pref
+
+    def body(x, pair):
+        gp, gc = pair
+        new_gc = {}
+        for i, kind in enumerate(cfg.layer_kinds):
+            x, new_gc[f"l{i}"] = _layer_static(gp[f"l{i}"], cfg, kind, x,
+                                               gc[f"l{i}"], op, *args)
+        return x, new_gc
+
+    x, new_groups = maps.scan(body, x, (params["groups"],
+                                        caches["groups"]))
+    caches["groups"] = new_groups
+    return L.rmsnorm(params["final_norm"], x), caches
+
+
+def prefill(params: dict, cfg: ModelConfig, tokens, caches, *,
+            inputs_embeds=None):
+    """Run the prompt through the model, filling every cache.
+    Returns (logits_last (B, V), caches)."""
+    x = _embed(params, cfg, tokens, inputs_embeds)
+    b, n, _ = x.shape
+    positions = jnp.broadcast_to(jnp.arange(n), (b, n))
+    x, caches = _static_stack(params, cfg, x, caches, "prefill", positions)
+    logits = logits_from_hidden(params, cfg, x[:, -1:])[:, 0]
+    return logits, caches
+
+
+def decode_step(params: dict, cfg: ModelConfig, token_t, caches):
+    """One decode step. token_t: (B,) int32. Returns (logits (B, V), caches)."""
+    x = _embed(params, cfg, token_t[:, None])
+    x, caches = _static_stack(params, cfg, x, caches, "decode")
+    return logits_from_hidden(params, cfg, x)[:, 0], caches
 
 
 # ---------------------------------------------------------------------------
 # Paged caches / chunked prefill / per-slot decode (continuous batching)
 # ---------------------------------------------------------------------------
-
-# Every layer kind serves through the paged engine; what differs is the
-# SHAPE of its per-layer cache, summarized by LAYER_CACHE_KINDS:
-#   paged-kv      — block_k-token K/V pages (+ SLA2 pooled keys / totals)
-#   paged-latent  — MLA's compressed-latent pages (no v_pages; values are
-#                   the c_kv slice of the latent)
-#   state         — recurrent mixers: a degenerate "pool" of one per-slot
-#                   state checkpoint, no page keys at all
-#   paged-kv + state — hybrid blocks compose both, as a nested dict
-# tools/gen_path_matrix.py renders this table into docs/paths.md, so the
-# documented layer_kind column cannot drift from the dispatch below.
-PAGED_KINDS = ("dense", "moe", "mla_dense", "mla_moe", "hybrid",
-               "mlstm", "slstm")
-LAYER_CACHE_KINDS = {
-    "dense": "paged-kv", "moe": "paged-kv",
-    "mla_dense": "paged-latent", "mla_moe": "paged-latent",
-    "hybrid": "paged-kv + state", "mlstm": "state", "slstm": "state",
-}
-# layer kind -> the single key its params/caches live under
-KIND_CACHE_KEY = {"dense": "attn", "moe": "attn", "mla_dense": "mla",
-                  "mla_moe": "mla", "hybrid": "mixer", "mlstm": "core",
-                  "slstm": "core"}
-# kinds whose cache carries per-slot state beyond K/V pages (the engine's
-# prefix cache must snapshot/restore it on hits)
-_STATE_KINDS = ("mla_dense", "mla_moe", "hybrid", "mlstm", "slstm")
-
-
-def supports_paged(cfg: ModelConfig) -> bool:
-    """Paged serving covers every layer kind: attention pages K/V, MLA
-    pages the compressed latent, recurrent mixers checkpoint per-slot
-    state, hybrids compose both."""
-    return all(k in PAGED_KINDS
-               for k in tuple(cfg.first_kinds) + tuple(cfg.layer_kinds))
-
-
-def has_slot_state(cfg: ModelConfig) -> bool:
-    """True when any layer keeps per-slot state the serving prefix cache
-    must snapshot on insert and restore on hit — SLA2 linear totals
-    (mechanism 'sla2', incl. MLA) or recurrent-mixer checkpoints."""
-    kinds = tuple(cfg.first_kinds) + tuple(cfg.layer_kinds)
-    return cfg.mechanism == "sla2" or any(k in _STATE_KINDS for k in kinds)
-
-
-def _init_layer_paged(cfg: ModelConfig, kind: str, batch: int,
-                      num_pages: int, window: int, dtype) -> dict:
-    """One layer's paged cache, dispatched on the layer kind."""
-    if kind in ("dense", "moe"):
-        return {"attn": A.init_paged_cache(cfg.attention_config(),
-                                           num_pages, batch, dtype)}
-    if kind in ("mla_dense", "mla_moe"):
-        return {"mla": MLA.init_mla_paged_cache(
-            cfg.mla, num_pages, batch, cfg.block_k, kv_quant=cfg.kv_quant,
-            dtype=dtype)}
-    if kind == "hybrid":
-        return {"mixer": HY.init_hybrid_paged_cache(
-            cfg.attention_config(), cfg.ssm, num_pages, batch,
-            window=window, dtype=dtype)}
-    if kind in ("mlstm", "slstm"):
-        return {"core": SSM.init_paged_state(kind, cfg.ssm, batch, window)}
-    raise ValueError(kind)
-
 
 def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int, *,
                       window: int = 1, dtype=jnp.bfloat16) -> dict:
@@ -509,33 +626,55 @@ def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int, *,
     page size == cfg.block_k.  ``window`` sizes the recurrent mixers'
     speculative-verify state buffers (draft window W; 1 when the engine
     never verifies multi-token windows)."""
-    if not supports_paged(cfg):
-        raise ValueError(f"paged serving unsupported for {cfg.layer_kinds}")
-    caches: dict[str, Any] = {}
-    if cfg.first_kinds:
-        caches["prefix_layers"] = [
-            _init_layer_paged(cfg, kind, batch, num_pages, window, dtype)
-            for kind in cfg.first_kinds]
-    one = {f"l{i}": _init_layer_paged(cfg, kind, batch, num_pages, window,
-                                      dtype)
-           for i, kind in enumerate(cfg.layer_kinds)}
-    caches["groups"] = jax.tree.map(
-        lambda a: jnp.tile(a[None], (cfg.n_groups,) + (1,) * a.ndim), one)
-    return caches
+    return _layer_caches(
+        cfg, lambda m: m.init_paged(cfg, batch, num_pages, window, dtype))
 
 
 def _walk_layers(cfg: ModelConfig, caches: dict, fn) -> dict:
-    """Apply ``fn(kind, layer_cache, lead)`` over every layer cache (prefix
-    layers at lead=0, scanned groups at lead=1), preserving the layout."""
+    """Apply ``fn(mixer, layer_cache, lead)`` over every layer cache
+    (prefix layers at lead=0, scanned groups at lead=1), preserving the
+    layout."""
     out: dict[str, Any] = {}
     if cfg.first_kinds:
         out["prefix_layers"] = [
-            fn(kind, lc, 0)
+            fn(LAYER_KINDS[kind].mixer, lc, 0)
             for kind, lc in zip(cfg.first_kinds, caches["prefix_layers"])]
     out["groups"] = {
-        f"l{i}": fn(kind, caches["groups"][f"l{i}"], 1)
+        f"l{i}": fn(LAYER_KINDS[kind].mixer, caches["groups"][f"l{i}"], 1)
         for i, kind in enumerate(cfg.layer_kinds)}
     return out
+
+
+def _vmap_layers(cfg: ModelConfig, caches: dict, fn) -> dict:
+    """``fn(mixer, layer_cache)`` on every layer, vmapped over the scanned
+    groups' leading axis."""
+    out = dict(caches)
+    if cfg.first_kinds:
+        out["prefix_layers"] = [
+            fn(LAYER_KINDS[kind].mixer, lc)
+            for kind, lc in zip(cfg.first_kinds, caches["prefix_layers"])]
+    out["groups"] = {
+        f"l{i}": jax.vmap(functools.partial(fn, LAYER_KINDS[kind].mixer))(
+            caches["groups"][f"l{i}"])
+        for i, kind in enumerate(cfg.layer_kinds)}
+    return out
+
+
+def _by_part(mixer: Mixer, pages, slot, *caches):
+    """``pages`` on a cache that holds pages, ``slot`` on one that holds
+    slot state alone, through a composite cache's parts."""
+    if mixer.parts is None:
+        return pages(*caches)
+    return {k: (pages if part == "pages" else slot)(*(c[k] for c in caches))
+            for k, part in mixer.parts.items()}
+
+
+def _check_key(mixer: Mixer, state: dict, what: str):
+    if mixer.key not in state:
+        raise ValueError(
+            f"{what} for a {mixer.key!r} layer must carry {mixer.key!r} "
+            f"leaves, got {sorted(state)} — taken from a different layer "
+            "kind?")
 
 
 def swap_out_slot(cfg: ModelConfig, caches: dict, page_row, slot) -> dict:
@@ -543,14 +682,10 @@ def swap_out_slot(cfg: ModelConfig, caches: dict, page_row, slot) -> dict:
     (K/V or latent) at ``page_row`` and its per-slot states (SLA2 linear
     totals / recurrent checkpoints) at ``slot``.  The result pytree is
     what the serving SwapPool keeps on the host."""
-    def f(kind, lc, lead):
-        key = KIND_CACHE_KEY[kind]
-        if kind == "hybrid":
-            return {key: {
-                "attn": A.extract_paged_state(lc[key]["attn"], page_row,
-                                              slot, lead),
-                "ssm": A.extract_slot_state(lc[key]["ssm"], slot, lead)}}
-        return {key: A.extract_paged_state(lc[key], page_row, slot, lead)}
+    def f(m, lc, lead):
+        return {m.key: _by_part(
+            m, lambda c: A.extract_paged_state(c, page_row, slot, lead),
+            lambda c: A.extract_slot_state(c, slot, lead), lc[m.key])}
     return _walk_layers(cfg, caches, f)
 
 
@@ -558,25 +693,15 @@ def swap_in_slot(cfg: ModelConfig, caches: dict, page_row, slot,
                  state: dict) -> dict:
     """Write a swapped-out slot state back into the pools at a fresh page
     row / slot id (the physical placement may differ from swap-out)."""
-    def f(kind, pair, lead):
+    def f(m, pair, lead):
         lc, st = pair
-        key = KIND_CACHE_KEY[kind]
-        if key not in st:
-            raise ValueError(
-                f"swap state for layer kind {kind!r} must carry {key!r} "
-                f"leaves, got {sorted(st)} — state extracted from a "
-                "different layer kind?")
-        if kind == "hybrid":
-            return {key: {
-                "attn": A.insert_paged_state(lc[key]["attn"], page_row,
-                                             slot, st[key]["attn"], lead),
-                "ssm": A.insert_slot_state(lc[key]["ssm"], slot,
-                                           st[key]["ssm"], lead)}}
-        return {key: A.insert_paged_state(lc[key], page_row, slot, st[key],
-                                          lead)}
+        _check_key(m, st, "swap state")
+        return {m.key: _by_part(
+            m, lambda c, s: A.insert_paged_state(c, page_row, slot, s, lead),
+            lambda c, s: A.insert_slot_state(c, slot, s, lead),
+            lc[m.key], st[m.key])}
     new = dict(caches)
-    paired = _walk_layers(cfg, _zip_layouts(cfg, caches, state), f)
-    new.update(paired)
+    new.update(_walk_layers(cfg, _zip_layouts(cfg, caches, state), f))
     return new
 
 
@@ -597,13 +722,10 @@ def extract_linear_totals(cfg: ModelConfig, caches: dict, slot) -> dict:
     snapshot a prefix-cache trie node stores so a hit restores the slot
     without re-prefilling.  Layers without per-slot state contribute empty
     dicts (dense non-sla2 models)."""
-    def f(kind, lc, lead):
-        key = KIND_CACHE_KEY[kind]
-        if kind == "hybrid":
-            return {key: {
-                "attn": A.extract_slot_state(lc[key]["attn"], slot, lead),
-                "ssm": A.extract_slot_state(lc[key]["ssm"], slot, lead)}}
-        return {key: A.extract_slot_state(lc[key], slot, lead)}
+    def f(m, lc, lead):
+        def one(c):
+            return A.extract_slot_state(c, slot, lead)
+        return {m.key: _by_part(m, one, one, lc[m.key])}
     return _walk_layers(cfg, caches, f)
 
 
@@ -612,21 +734,13 @@ def insert_linear_totals(cfg: ModelConfig, caches: dict, slot,
     """Write an ``extract_linear_totals`` snapshot back into every layer at
     ``slot`` — the O(1) restore a prefix-cache hit performs before chunked
     prefill resumes at the first uncached page."""
-    def f(kind, pair, lead):
+    def f(m, pair, lead):
         lc, st = pair
-        key = KIND_CACHE_KEY[kind]
-        if key not in st:
-            raise ValueError(
-                f"slot totals for layer kind {kind!r} must carry {key!r} "
-                f"leaves, got {sorted(st)} — snapshot taken from a "
-                "different layer kind?")
-        if kind == "hybrid":
-            return {key: {
-                "attn": A.insert_slot_state(lc[key]["attn"], slot,
-                                            st[key]["attn"], lead),
-                "ssm": A.insert_slot_state(lc[key]["ssm"], slot,
-                                           st[key]["ssm"], lead)}}
-        return {key: A.insert_slot_state(lc[key], slot, st[key], lead)}
+        _check_key(m, st, "slot totals")
+
+        def one(c, s):
+            return A.insert_slot_state(c, slot, s, lead)
+        return {m.key: _by_part(m, one, one, lc[m.key], st[m.key])}
     new = dict(caches)
     new.update(_walk_layers(cfg, _zip_layouts(cfg, caches, totals), f))
     return new
@@ -637,76 +751,63 @@ def copy_kv_page(cfg: ModelConfig, caches: dict, src, dst) -> dict:
     another across every layer — the serving engine's copy-on-write
     primitive for pages shared through the prefix cache.  State-only
     layer caches have no page keys and pass through unchanged."""
-    def f(kind, lc, lead):
-        key = KIND_CACHE_KEY[kind]
-        if kind == "hybrid":
-            return {key: {
-                "attn": A.copy_paged_page(lc[key]["attn"], src, dst, lead),
-                "ssm": lc[key]["ssm"]}}
-        return {key: A.copy_paged_page(lc[key], src, dst, lead)}
+    def f(m, lc, lead):
+        return {m.key: _by_part(
+            m, lambda c: A.copy_paged_page(c, src, dst, lead),
+            lambda c: c, lc[m.key])}
     new = dict(caches)
     new.update(_walk_layers(cfg, caches, f))
     return new
 
 
-# layer kinds whose paged mixers read and write a scanned group's stacked
-# pool in place at the layer index (models/mla.py's pool accessors); the
-# other kinds' mixers hand one layer's pool to Pallas kernels through
-# BlockSpecs, so ``_mix_stacked`` slices it out for them
-_IN_PLACE_KINDS = ("mla_dense", "mla_moe")
-
-
-def _mix_stacked(mix_fn, kind, lp, h, cache, layer):
-    """The mixer, under its ``lm.attn`` scope.  ``cache`` is a layer's own
-    cache when ``layer`` is None, else a scanned group's stacked cache and
-    ``layer`` the scan's index: an in-place kind indexes the stack itself;
-    any other kind gets its layer's slice, and the slice it returns is
-    written back into the stack, both outside the scope: they are the
-    scan's data movement, not the mixer's."""
-    if layer is not None and kind not in _IN_PLACE_KINDS:
+def _mix_stacked(cfg: ModelConfig, mixer: Mixer, op, p, h, cache, layer,
+                 kw):
+    """The mixer's paged ``op``, under its ``lm.attn`` scope.  ``cache`` is
+    a layer's own cache when ``layer`` is None, else a scanned group's
+    stacked cache and ``layer`` the scan's index: an in-place mixer
+    indexes the stack itself; any other gets its layer's slice, and the
+    slice it returns is written back into the stack, both outside the
+    scope: they are the scan's data movement, not the mixer's."""
+    fn = getattr(mixer, op)
+    if layer is not None and not mixer.in_place:
         sub = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
             cache)
         with jax.named_scope("lm.attn"):
-            y, sub = mix_fn(kind, lp, h, sub, None)
+            y, sub = fn(cfg, p, h, sub, None, **kw)
         return y, jax.tree.map(
             lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, layer, 0),
             cache, sub)
     with jax.named_scope("lm.attn"):
-        return mix_fn(kind, lp, h, cache, layer)
+        return fn(cfg, p, h, cache, layer, **kw)
 
 
-def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, mix_fn, rows,
+def _layer_paged(lp, cfg: ModelConfig, kind, x, lc, rows, op, kw,
                  stack=None, layer=None):
-    """Shared block body around a paged mixer call, dispatched on the layer
-    kind; recurrent-core kinds (mlstm/slstm) have no ln2/FFN half.  On a
-    serving mesh the residual stream stays whole on every device
-    (distributed/shard_paged.replicate).  Returns (x, cache, counters):
-    the expert layer's counters (``MOE.moe_layer``), None for the other
-    kinds.  ``rows`` (B, S) bool marks the real tokens the experts route.
-    For a layer of the scanned groups, ``layer`` is its index, ``lc`` the
-    group's stacked cache (``_mix_stacked``) and ``stack`` the expert
-    layer's whole-stack weights and index (``MOE.moe_layer``)."""
+    """One block around its mixer's paged ``op``; a kind without an FFN
+    has no ln2 half.  On a serving mesh the residual stream stays whole on
+    every device (distributed/shard_paged.replicate).  Returns (x, cache,
+    counters): the FFN's counters (``MOE.moe_layer``), or None.  ``rows``
+    (B, S) bool marks the real tokens the experts route.  For a layer of
+    the scanned groups, ``layer`` is its index, ``lc`` the group's stacked
+    cache (``_mix_stacked``) and ``stack`` the FFN's whole-stack weights
+    and index (``MOE.moe_layer``)."""
     from repro.distributed.shard_paged import replicate
+    mixer, ffn = LAYER_KINDS[kind]
     x = replicate(x, cfg.mesh)
     with jax.named_scope("lm.norm"):
         h = L.rmsnorm(lp["ln1"], x)
-    key = KIND_CACHE_KEY[kind]
-    y, c = _mix_stacked(mix_fn, kind, lp, h, lc[key], layer)
+    y, c = _mix_stacked(cfg, mixer, op, lp[mixer.key], h, lc[mixer.key],
+                        layer, kw)
     x = replicate(x + y, cfg.mesh)
-    if kind in ("mlstm", "slstm"):
-        return x, {key: c}, None
+    if ffn is None:
+        return x, {mixer.key: c}, None
     with jax.named_scope("lm.norm"):
         h2 = L.rmsnorm(lp["ln2"], x)
-    counters = None
     with jax.named_scope("lm.mlp"):
-        if kind.endswith("moe"):
-            y2, counters = MOE.moe_layer(lp["moe"], h2, cfg.moe, rows,
-                                         stack)
-            x = x + y2
-        else:
-            x = x + L.mlp(lp["mlp"], h2, activation=cfg.mlp_activation)
-    return x, {key: c}, counters
+        y2, counters = ffn.serve(cfg, lp[ffn.key], h2, rows, stack)
+        x = x + y2
+    return x, {mixer.key: c}, counters
 
 
 def _add_counters(total, counters):
@@ -717,34 +818,33 @@ def _add_counters(total, counters):
     return jax.tree.map(jnp.add, total, counters)
 
 
-def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn, rows=None):
-    """Run the layer stack (prefix layers + scanned groups) with ``mix_fn``
-    (kind, layer_params, h, sub_cache, layer) -> (y, sub_cache) as the
-    mixer body, where ``layer`` is None for a layer's own cache and the
-    scan's layer index for a group's stacked one (``_mix_stacked``);
-    returns (final hidden, new caches, counters).  ``counters`` sums
-    the expert layers' counters over the stack (None for a stack without
-    expert layers, whose programs gain no outputs)."""
+def _paged_stack(params, cfg: ModelConfig, x, caches, rows, op, **kw):
+    """Run the layer stack (prefix layers + scanned groups), each mixer
+    through its paged ``op`` (a ``Mixer`` field) with the program's
+    keyword batch ``kw``; returns (final hidden, new caches, counters).
+    ``counters`` sums the FFN counters over the stack (None for a stack
+    without expert layers, whose programs gain no outputs)."""
     caches = dict(caches)
     total = None
     if cfg.first_kinds:
         new_pref = []
         for i, kind in enumerate(cfg.first_kinds):
             x, lc, n = _layer_paged(params["prefix_layers"][i], cfg, kind,
-                                    x, caches["prefix_layers"][i], mix_fn,
-                                    rows)
+                                    x, caches["prefix_layers"][i], rows, op,
+                                    kw)
             new_pref.append(lc)
             total = _add_counters(total, n)
         caches["prefix_layers"] = new_pref
 
-    # the scan slices each group's parameters but for what an expert layer
-    # reads out of the whole stack itself (MOE.split_stack)
+    # the scan slices each group's parameters but for what an FFN reads
+    # out of the whole stack itself (MOE.split_stack)
     groups, whole = dict(params["groups"]), {}
     for i, kind in enumerate(cfg.layer_kinds):
-        if kind.endswith("moe"):
+        ffn = LAYER_KINDS[kind].ffn
+        if ffn is not None and ffn.split is not None:
             lp = groups[f"l{i}"]
-            moe, whole[f"l{i}"] = MOE.split_stack(lp["moe"])
-            groups[f"l{i}"] = {**lp, "moe": moe}
+            sliced, whole[f"l{i}"] = ffn.split(lp[ffn.key])
+            groups[f"l{i}"] = {**lp, ffn.key: sliced}
 
     def body(carry, pair):
         x, pool = carry
@@ -753,18 +853,18 @@ def _paged_stack(params, cfg: ModelConfig, x, caches, mix_fn, rows=None):
         for i, kind in enumerate(cfg.layer_kinds):
             stack = (whole[f"l{i}"], g) if f"l{i}" in whole else None
             x, pool[f"l{i}"], n = _layer_paged(
-                gp[f"l{i}"], cfg, kind, x, pool[f"l{i}"], mix_fn, rows,
+                gp[f"l{i}"], cfg, kind, x, pool[f"l{i}"], rows, op, kw,
                 stack, layer=g)
             group = _add_counters(group, n)
         return (x, pool), group
 
     # the stacked pool rides the carry, so a step holds one pool (a
     # donated one: the engine's).  Each layer's mixer gets the whole stack
-    # and the layer index: MLA layers read and write it in place, the
-    # other kinds slice their layer out and write it back in
+    # and the layer index: in-place (MLA) mixers read and write it there,
+    # the others slice their layer out and write it back in
     # ``_mix_stacked``.  Ops of the scan outside the layer body's scopes
-    # are the loop's own: slicing each layer's weights, and a non-MLA
-    # layer's pool, out of the stack and writing that pool back
+    # are the loop's own: slicing each layer's weights, and a sliced
+    # mixer's pool, out of the stack and writing that pool back
     with jax.named_scope("lm.layers"):
         (x, new_groups), per_group = maps.scan(
             body, (x, caches["groups"]),
@@ -791,32 +891,11 @@ def prefill_chunk(params: dict, cfg: ModelConfig, tokens, caches, *,
     Returns (logits (1, V) at the last valid token, caches); with expert
     layers the logits come as ``{"logits", <counters>}``
     (``_with_counters``)."""
-    acfg = cfg.attention_config()
-    x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-
-    def mix_fn(kind, lp, h, lc, layer):
-        if kind in ("dense", "moe"):
-            return A.chunk_prefill_paged(
-                lp["attn"], acfg, h, lc, page_row=page_row, offset=offset,
-                chunk_len=chunk_len, slot=slot)
-        if kind in ("mla_dense", "mla_moe"):
-            return MLA.mla_prefill_chunk_paged(
-                lp["mla"], h, lc, mcfg=cfg.mla, num_heads=cfg.num_heads,
-                block_k=cfg.block_k, kv_quant=cfg.kv_quant,
-                page_row=page_row, offset=offset, chunk_len=chunk_len,
-                slot=slot, layer=layer)
-        if kind == "hybrid":
-            return HY.hybrid_prefill_chunk_paged(
-                lp["mixer"], acfg, cfg.ssm, h, lc, page_row=page_row,
-                offset=offset, chunk_len=chunk_len, slot=slot)
-        return SSM.ssm_prefill_paged(kind, lp["core"], cfg.ssm, h, lc,
-                                     offset=offset, chunk_len=chunk_len,
-                                     slot=slot)
-
+    x = _embed(params, cfg, tokens)
     rows = (jnp.arange(tokens.shape[1]) < chunk_len)[None]
-    x, caches, counters = _paged_stack(params, cfg, x, caches, mix_fn, rows)
+    x, caches, counters = _paged_stack(
+        params, cfg, x, caches, rows, "prefill_chunk", page_row=page_row,
+        offset=offset, chunk_len=chunk_len, slot=slot)
     last = jax.lax.dynamic_slice(x, (0, chunk_len - 1, 0),
                                  (1, 1, x.shape[-1]))
     return _with_counters(logits_from_hidden(params, cfg, last)[:, 0],
@@ -829,31 +908,10 @@ def decode_paged(params: dict, cfg: ModelConfig, token_t, caches, *,
     token_t: (B,) int32; lengths: (B,) tokens already cached per slot;
     active: (B,) bool.  Returns (logits (B, V), caches), the logits with
     the expert counters beside them as in ``prefill_chunk``."""
-    acfg = cfg.attention_config()
-    x = L.embed(params["embed"], token_t[:, None]).astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-
-    def mix_fn(kind, lp, h, lc, layer):
-        if kind in ("dense", "moe"):
-            return A.decode_step_paged(lp["attn"], acfg, h, lc,
-                                       page_table=page_table,
-                                       lengths=lengths, active=active)
-        if kind in ("mla_dense", "mla_moe"):
-            return MLA.mla_decode_step_paged(
-                lp["mla"], h, lc, mcfg=cfg.mla, num_heads=cfg.num_heads,
-                k_frac=cfg.k_frac, block_k=cfg.block_k,
-                kv_quant=cfg.kv_quant, page_table=page_table,
-                lengths=lengths, active=active, layer=layer)
-        if kind == "hybrid":
-            return HY.hybrid_decode_step_paged(
-                lp["mixer"], acfg, cfg.ssm, h, lc, page_table=page_table,
-                lengths=lengths, active=active)
-        return SSM.ssm_decode_paged(kind, lp["core"], cfg.ssm, h, lc,
-                                    active=active)
-
-    x, caches, counters = _paged_stack(params, cfg, x, caches, mix_fn,
-                                       active[:, None])
+    x = _embed(params, cfg, token_t[:, None])
+    x, caches, counters = _paged_stack(
+        params, cfg, x, caches, active[:, None], "decode_paged",
+        page_table=page_table, lengths=lengths, active=active)
     return _with_counters(logits_from_hidden(params, cfg, x)[:, 0],
                           counters), caches
 
@@ -867,35 +925,12 @@ def decode_verify(params: dict, cfg: ModelConfig, tokens_w, caches, *,
     ``prefill_chunk``.  K/V (or latent) pages are written
     for the whole window; block-state and recurrent-checkpoint commits are
     deferred to ``commit_window`` once host-side acceptance is decided."""
-    acfg = cfg.attention_config()
-    x = L.embed(params["embed"], tokens_w).astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-
-    def mix_fn(kind, lp, h, lc, layer):
-        if kind in ("dense", "moe"):
-            return A.decode_window_paged(lp["attn"], acfg, h, lc,
-                                         page_table=page_table,
-                                         lengths=lengths, active=active,
-                                         window_len=window_len)
-        if kind in ("mla_dense", "mla_moe"):
-            return MLA.mla_decode_window_paged(
-                lp["mla"], h, lc, mcfg=cfg.mla, num_heads=cfg.num_heads,
-                k_frac=cfg.k_frac, block_k=cfg.block_k,
-                kv_quant=cfg.kv_quant, page_table=page_table,
-                lengths=lengths, active=active, window_len=window_len,
-                layer=layer)
-        if kind == "hybrid":
-            return HY.hybrid_decode_window_paged(
-                lp["mixer"], acfg, cfg.ssm, h, lc, page_table=page_table,
-                lengths=lengths, active=active, window_len=window_len)
-        return SSM.ssm_decode_window_paged(kind, lp["core"], cfg.ssm, h,
-                                           lc, active=active,
-                                           window_len=window_len)
-
+    x = _embed(params, cfg, tokens_w)
     rows = (jnp.arange(tokens_w.shape[1])[None] < window_len[:, None]) \
         & active[:, None]
-    x, caches, counters = _paged_stack(params, cfg, x, caches, mix_fn, rows)
+    x, caches, counters = _paged_stack(
+        params, cfg, x, caches, rows, "decode_verify", page_table=page_table,
+        lengths=lengths, active=active, window_len=window_len)
     return _with_counters(logits_from_hidden(params, cfg, x), counters), \
         caches
 
@@ -906,59 +941,22 @@ def commit_window(cfg: ModelConfig, caches, page_table, lengths, accepted,
     block state — SLA2 pooled router keys + linear totals for attention /
     MLA layers, accepted-state promotion for recurrent mixers.  ``window``
     is the static window size the verify ran with."""
-    acfg = cfg.attention_config()
-
-    def upd(kind, lc):
-        key = KIND_CACHE_KEY[kind]
-        if kind in ("dense", "moe"):
-            return {key: A.commit_paged_window(
-                acfg, lc[key], page_table=page_table, lengths=lengths,
-                accepted=accepted, active=active, window=window)}
-        if kind in ("mla_dense", "mla_moe"):
-            return {key: MLA.mla_commit_window(
-                lc[key], mcfg=cfg.mla, block_k=cfg.block_k,
-                kv_quant=cfg.kv_quant, page_table=page_table,
-                lengths=lengths, accepted=accepted, active=active,
-                window=window)}
-        if kind == "hybrid":
-            return {key: HY.hybrid_commit_window(
-                acfg, cfg.ssm, lc[key], page_table=page_table,
-                lengths=lengths, accepted=accepted, active=active,
-                window=window)}
-        return {key: SSM.ssm_commit_window(
-            kind, cfg.ssm, lc[key], accepted=accepted, active=active,
-            window=window)}
-
-    caches = dict(caches)
-    if cfg.first_kinds:
-        caches["prefix_layers"] = [
-            upd(kind, lc) for kind, lc in zip(cfg.first_kinds,
-                                              caches["prefix_layers"])]
-    caches["groups"] = {
-        f"l{i}": jax.vmap(functools.partial(upd, kind))(
-            caches["groups"][f"l{i}"])
-        for i, kind in enumerate(cfg.layer_kinds)}
-    return caches
+    def upd(m, lc):
+        return {m.key: m.commit_window(
+            cfg, lc[m.key], page_table=page_table, lengths=lengths,
+            accepted=accepted, active=active, window=window)}
+    return _vmap_layers(cfg, caches, upd)
 
 
 def draft_init(cfg: ModelConfig, caches, page_table, lengths, active):
     """Per-layer linear draft states (running phi(k)·v totals over the full
-    cached prefix) for the speculative drafter — one {"h", "z"} pytree per
-    attention layer, mirroring the cache layout.  Attention-only stacks
-    (dense/moe kinds): the linear drafter has no analogue for MLA latents
-    or recurrent checkpoints, so api.py only wires it up for those."""
-    acfg = cfg.attention_config()
-
-    def f(lc):
-        return {"attn": A.linear_draft_state(
-            acfg, lc["attn"], page_table=page_table, lengths=lengths,
-            active=active)}
-
-    st: dict[str, Any] = {}
-    if cfg.first_kinds:
-        st["prefix_layers"] = [f(lc) for lc in caches["prefix_layers"]]
-    st["groups"] = {k: jax.vmap(f)(v) for k, v in caches["groups"].items()}
-    return st
+    cached prefix) for the speculative drafter, mirroring the cache
+    layout.  Only for stacks ``has_linear_drafter`` admits: the linear
+    drafter has no analogue for MLA latents or recurrent checkpoints."""
+    def f(m, lc):
+        return {m.key: m.draft_init(cfg, lc[m.key], page_table=page_table,
+                                    lengths=lengths, active=active)}
+    return _vmap_layers(cfg, caches, f)
 
 
 def draft_step(params: dict, cfg: ModelConfig, token_t, states, *,
@@ -966,84 +964,8 @@ def draft_step(params: dict, cfg: ModelConfig, token_t, states, *,
     """One linear-only draft decode step (no page reads — O(d^2)/token).
     token_t: (B,) int32; positions: (B,) the draft token's position.
     Returns (logits (B, V), states)."""
-    acfg = cfg.attention_config()
-    x = L.embed(params["embed"], token_t[:, None]).astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-
-    def mix_fn(kind, lp, h, lc, layer):
-        return A.linear_draft_attention(lp["attn"], acfg, h, lc,
-                                        positions=positions, active=active)
-
-    x, states, _ = _paged_stack(params, cfg, x, states, mix_fn,
-                                active[:, None])
+    x = _embed(params, cfg, token_t[:, None])
+    x, states, _ = _paged_stack(params, cfg, x, states, active[:, None],
+                                "draft_step", positions=positions,
+                                active=active)
     return logits_from_hidden(params, cfg, x)[:, 0], states
-
-
-def prefill(params: dict, cfg: ModelConfig, tokens, caches, *,
-            inputs_embeds=None):
-    """Run the prompt through the model, filling every cache.
-    Returns (logits_last (B, V), caches)."""
-    if inputs_embeds is None:
-        x = L.embed(params["embed"], tokens).astype(cfg.param_dtype)
-    else:
-        x = inputs_embeds.astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-    b, n, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(n), (b, n))
-    caches = dict(caches)
-
-    if cfg.first_kinds:
-        new_pref = []
-        for i, kind in enumerate(cfg.first_kinds):
-            x, lc = _layer_prefill(params["prefix_layers"][i], cfg, kind, x,
-                                   caches["prefix_layers"][i], positions)
-            new_pref.append(lc)
-        caches["prefix_layers"] = new_pref
-
-    def body(x, pair):
-        gp, gc = pair
-        new_gc = {}
-        for i, kind in enumerate(cfg.layer_kinds):
-            x, lc = _layer_prefill(gp[f"l{i}"], cfg, kind, x, gc[f"l{i}"],
-                                   positions)
-            new_gc[f"l{i}"] = lc
-        return x, new_gc
-
-    x, new_groups = maps.scan(body, x, (params["groups"],
-                                        caches["groups"]))
-    caches["groups"] = new_groups
-    x = L.rmsnorm(params["final_norm"], x)
-    logits = logits_from_hidden(params, cfg, x[:, -1:])[:, 0]
-    return logits, caches
-
-
-def decode_step(params: dict, cfg: ModelConfig, token_t, caches):
-    """One decode step. token_t: (B,) int32. Returns (logits (B, V), caches)."""
-    x = L.embed(params["embed"], token_t[:, None]).astype(cfg.param_dtype)
-    if cfg.embed_scale:
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
-    caches = dict(caches)
-
-    if cfg.first_kinds:
-        new_pref = []
-        for i, kind in enumerate(cfg.first_kinds):
-            x, lc = _layer_decode(params["prefix_layers"][i], cfg, kind, x,
-                                  caches["prefix_layers"][i])
-            new_pref.append(lc)
-        caches["prefix_layers"] = new_pref
-
-    def body(x, pair):
-        gp, gc = pair
-        new_gc = {}
-        for i, kind in enumerate(cfg.layer_kinds):
-            x, lc = _layer_decode(gp[f"l{i}"], cfg, kind, x, gc[f"l{i}"])
-            new_gc[f"l{i}"] = lc
-        return x, new_gc
-
-    x, new_groups = maps.scan(body, x, (params["groups"],
-                                        caches["groups"]))
-    caches["groups"] = new_groups
-    x = L.rmsnorm(params["final_norm"], x)
-    return logits_from_hidden(params, cfg, x)[:, 0], caches

@@ -1,9 +1,9 @@
-"""Serving package: continuous-batching engines, preemption scheduler,
+"""Serving package: the continuous-batching engine, preemption scheduler,
 copy-on-write prefix caching and the self-speculative decoding helpers
 (drafting + rejection sampling)."""
 from repro.serve.engine import (EngineConfig, PageAllocator, Request,
-                                Scheduler, ServeEngine, StaticWaveEngine,
-                                SwapPool, generate_sequential,
+                                Scheduler, ServeEngine, SwapPool,
+                                generate_sequential,
                                 make_mixed_requests)
 from repro.serve.prefix_cache import PrefixCache, PrefixNode
 from repro.serve.speculative import (LinearDrafter, NGramDrafter,

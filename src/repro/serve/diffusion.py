@@ -36,8 +36,7 @@ Batched interleaved serving is **bit-identical** to per-request
 sequential denoising (``denoise_sequential``): every op in the denoise
 step is independent per batch row, the oracle runs the engine's compiled
 batch width, and the cached constants are computed per request with
-batch-1 shapes in both paths.  tests/test_diffusion.py
-and every benchmarks/fig12_diffusion.py run assert this with
+batch-1 shapes in both paths.  tests/test_diffusion.py asserts this with
 ``np.array_equal``.
 """
 from __future__ import annotations

@@ -23,22 +23,18 @@ and on pool exhaustion the ``Scheduler`` preempts the youngest slot
 ``SwapPool`` (page-granular numpy mirror, plus the SLA2 per-slot linear
 totals so the linear branch resumes exactly) or, when swap space is also
 full, dropped and recomputed from the prompt + tokens generated so far.
-Either way a resumed request continues token-identically.  The legacy
-worst-case reservation policy is kept as ``admission='conservative'`` (the
-benchmark baseline in benchmarks/fig7_preemption.py).  See docs/serving.md
-for the full state machine.  On CPU this serves small models end-to-end
-(examples/serve_lm.py); on TPU the same jitted step functions shard per
-distributed/sharding.cache_specs (page-axis sharded pools).
+Either way a resumed request continues token-identically.  See
+docs/serving.md for the full state machine.  On CPU this serves small
+models end-to-end (examples/serve_lm.py); on TPU the same jitted step
+functions shard per distributed/sharding.cache_specs (page-axis sharded
+pools).
 
 Paged serving covers every LM layer kind: attention layers page K/V, MLA
-layers page their COMPRESSED LATENT (the c_kv+k_rope vector per token —
-``launch/roofline.mla_latent_page_bytes`` vs the dense equivalent),
+layers page their COMPRESSED LATENT (the c_kv+k_rope vector per token),
 recurrent mixers (mamba/mlstm/slstm, incl. Hymba hybrid blocks) ride the
 same swap/recompute plumbing with per-slot state checkpoints instead of
-pages.  ``StaticWaveEngine`` is RETIRED from the hot path: nothing in the
-serving stack constructs it any more; it survives only as the
-generation-wave baseline the mixed-length benchmark in
-benchmarks/fig5_e2e_latency.py measures against.
+pages.  ``generate_sequential`` is the unbatched static-cache oracle the
+engine's outputs are tested against.
 """
 from __future__ import annotations
 
@@ -70,9 +66,8 @@ class Request:
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    """Engine knobs shared by ServeEngine and StaticWaveEngine; field
-    comments below are the authoritative reference (docs/serving.md
-    walks through the serving-relevant ones)."""
+    """ServeEngine's knobs; field comments below are the authoritative
+    reference (docs/serving.md walks through the serving-relevant ones)."""
     max_slots: int = 4
     max_len: int = 512                 # per-slot logical capacity
     page_size: Optional[int] = None    # defaults to model block_k
@@ -89,8 +84,8 @@ class EngineConfig:
     # page-pool STORAGE dtype ('none' | 'int8' | 'fp8'): K/V (and SLA2
     # pooled-key) pages held as low-bit codes with per-row f32 scales —
     # pool bytes, swap traffic and decode-step HBM reads shrink ~2x at
-    # int8/fp8, so the same HBM budget holds ~2x the pages/slots (see
-    # launch/roofline.kv_page_bytes).  None keeps the model config.
+    # int8/fp8, so the same HBM budget holds ~2x the pages/slots.  None
+    # keeps the model config.
     kv_quant: Optional[str] = None
     # unquantized page-pool element dtype (e.g. 'float32'); None keeps the
     # model default (bfloat16).  'float32' makes paged prefill read back
@@ -99,11 +94,6 @@ class EngineConfig:
     # expert gates amplify bf16 page rounding into argmax flips (the
     # cross-family identity tests rely on this; ignored under kv_quant)
     page_dtype: Optional[str] = None
-    # 'optimistic' admits against actual outstanding pages and preempts the
-    # youngest slot on pool exhaustion (swap to host, else recompute);
-    # 'conservative' keeps the legacy worst-case page reservation (never
-    # preempts — the fig7 benchmark baseline)
-    admission: str = "optimistic"
     # host swap-pool capacity in pages; None mirrors the device pool size,
     # 0 disables swapping (preemption always recomputes from the prompt)
     swap_pages: Optional[int] = None
@@ -524,8 +514,6 @@ class ServeEngine:
         self.cfg = ecfg
         self.params = None
         self.caches = None
-        if ecfg.admission not in ("optimistic", "conservative"):
-            raise ValueError(f"unknown admission policy {ecfg.admission!r}")
         self.allocator = PageAllocator(num_pages)
         self.scheduler = Scheduler()
         swap_cap = (num_pages - 1 if ecfg.swap_pages is None
@@ -841,11 +829,7 @@ class ServeEngine:
             raise ValueError(
                 f"request {req.uid}: {n}+{req.max_new_tokens} tokens exceed "
                 f"max_len {self.max_len}")
-        # UNCLAMPED worst case: _worst_pages clamps at max_pages (correct
-        # for outstanding-page accounting, where a slot can never map more
-        # than max_pages logical blocks), but the reject gate must compare
-        # the request's true page demand against the pool — the clamp let
-        # an oversized request slip past whenever max_pages <= usable pages
+        # the request's true, unclamped page demand against the pool
         if -(-(n + req.max_new_tokens) // self.page_size) \
                 > self.allocator.num_pages - 1:
             raise ValueError(
@@ -855,18 +839,10 @@ class ServeEngine:
         self.scheduler.enqueue(req)
 
     # ------------------------------------------------------------------
-    def _worst_pages(self, n_prompt: int, max_new: int) -> int:
-        return min(self.max_pages,
-                   -(-(n_prompt + max_new) // self.page_size))
-
-    def _outstanding_pages(self) -> int:
-        return sum(self._worst_pages(len(s.tokens), s.req.max_new_tokens)
-                   - s.n_pages for s in self._slots.values())
-
     def _pages_needed_now(self, req: Request,
                           resume: Optional[_ResumeState]) -> int:
         """Pages a request needs to make progress right after admission —
-        the optimistic-admission gate (vs the conservative worst case)."""
+        the admission gate."""
         if resume is not None and resume.mode == "swap":
             s = resume.slot
             # the shared prefix is re-mapped by incref, not allocation —
@@ -1150,21 +1126,13 @@ class ServeEngine:
     def _admit_fcfs(self, limit: int):
         free = [s for s in range(self.cfg.max_slots)
                 if s not in self._slots][:limit]
-        conservative = self.cfg.admission == "conservative"
         for slot in free:
             req = self.scheduler.head()
             if req is None:
                 break
-            if conservative:
-                need = self._worst_pages(len(req.prompt), req.max_new_tokens)
-                if self._available_pages() - self._outstanding_pages() \
-                        < need:
-                    break                   # pool can't cover it yet (FCFS)
-            else:
-                resume = self.scheduler.peek_resume(req)
-                if self._available_pages() \
-                        < self._pages_needed_now(req, resume):
-                    break                   # not enough to progress (FCFS)
+            resume = self.scheduler.peek_resume(req)
+            if self._available_pages() < self._pages_needed_now(req, resume):
+                break                       # not enough to progress (FCFS)
             self.scheduler.pop_head()
             resume = self.scheduler.take_resume(req)
             if resume is not None and resume.mode == "swap":
@@ -1525,8 +1493,7 @@ class ServeEngine:
         (single-token or speculative window — see
         docs/serving.md#engine-step-granularity).  Returns the number of
         occupied slots.  Steps that had work to do are counted in
-        ``stats['engine_steps']`` (trailing no-op calls are not) — the
-        benchmarks' deterministic throughput denominator."""
+        ``stats['engine_steps']`` (trailing no-op calls are not)."""
         with TraceAnnotation("serve.step"):
             if self._slots or self._queue:
                 self.stats["engine_steps"] += 1
@@ -1580,154 +1547,6 @@ class ServeEngine:
 
 
 # ===========================================================================
-# Static generation-wave engine (legacy path / benchmark baseline)
-# ===========================================================================
-
-def _static_fns(model):
-    """Jitted prefill/decode for the static cache path, cached on the model
-    (prefill re-traces per prompt length)."""
-    if not hasattr(model, "_static_step_fns"):
-        def static_prefill(p, b, c):
-            return model.prefill(p, b, c)
-
-        def static_decode(p, b, c):
-            return model.decode(p, b, c)
-
-        model._static_step_fns = (jax.jit(static_prefill),
-                                  jax.jit(static_decode))
-    return model._static_step_fns
-
-
-class StaticWaveEngine:
-    """Static-shape batched decode over Model.prefill/Model.decode.
-
-    All slots share one cache with a single sequence offset, so requests can
-    only join together at sequence start: the engine admits a wave when every
-    slot is idle, pads each prompt (LEFT, with token 0 — the pad tokens stay
-    visible to attention, so outputs depend on wave composition) to a common
-    length, and drains the wave before admitting again.  A long prompt
-    therefore stalls its whole wave — the regime ServeEngine's per-slot
-    offsets remove.
-
-    .. deprecated:: every LM family (dense/moe attention, MLA latent
-       pages, recurrent mixers, hybrids) now serves through ServeEngine;
-       no hot path constructs this class.  It is kept ONLY as the
-       generation-wave baseline benchmarks/fig5_e2e_latency.py measures
-       paged serving against."""
-
-    def __init__(self, model, ecfg: EngineConfig):
-        self.model = model
-        self.cfg = ecfg
-        self.params = None
-        self._queue: list[Request] = []
-        self._active: dict[int, Request] = {}      # slot -> request
-        self._tokens = np.zeros((ecfg.max_slots,), np.int32)
-        self._budget = np.zeros((ecfg.max_slots,), np.int32)
-        self.caches = None
-        self._rng = np.random.default_rng(ecfg.seed)
-        self.completed: list[Request] = []
-        self.stats = {"engine_steps": 0}
-        self._prefill, self._decode = _static_fns(model)
-
-    # ------------------------------------------------------------------
-    def load(self, params):
-        """Install model params (caches are rebuilt per wave)."""
-        self.params = params
-        self.caches = None
-
-    def submit(self, req: Request):
-        """Validate and enqueue a request for the next generation wave."""
-        n = len(req.prompt)
-        bq = getattr(self.model.cfg, "block_q", 32)
-        n_pad = max(bq, -(-n // bq) * bq)
-        if n == 0:
-            raise ValueError(f"request {req.uid}: empty prompt")
-        if n_pad + req.max_new_tokens > self.cfg.max_len:
-            raise ValueError(
-                f"request {req.uid}: padded prompt {n_pad} + "
-                f"{req.max_new_tokens} new tokens exceed max_len "
-                f"{self.cfg.max_len}")
-        req.output = []
-        self._queue.append(req)
-
-    def _admit(self):
-        """Admit a wave: joint prefill of up to max_slots queued requests,
-        padded to one shared length (wave semantics: only when idle).  The
-        wave is cut FCFS where the SHARED padding would push any member's
-        decode past max_len (a short prompt next to a long one starts its
-        decode at the long prompt's padded length)."""
-        if self._active or not self._queue:
-            return
-        bq = getattr(self.model.cfg, "block_q", 32)
-        pad = lambda n: max(bq, -(-n // bq) * bq)
-        wave: list[Request] = []
-        n_pad = 0
-        while self._queue and len(wave) < self.cfg.max_slots:
-            cand = self._queue[0]
-            cand_pad = max(n_pad, pad(len(cand.prompt)))
-            if any(cand_pad + r.max_new_tokens > self.cfg.max_len
-                   for r in wave + [cand]):
-                break
-            n_pad = cand_pad
-            wave.append(self._queue.pop(0))
-        # submit() guarantees each request fits alone, so wave is non-empty
-        prompt = np.zeros((self.cfg.max_slots, n_pad), np.int32)
-        for slot, req in enumerate(wave):
-            prompt[slot, -len(req.prompt):] = req.prompt   # left-pad with 0
-        self.caches = self.model.init_caches(
-            self.cfg.max_slots, self.cfg.max_len)
-        logits, self.caches = self._prefill(
-            self.params, {"tokens": jnp.asarray(prompt)}, self.caches)
-        tok = self._sample(np.asarray(logits))
-        for slot, req in enumerate(wave):
-            t = int(tok[slot])
-            req.output.append(t)
-            if req.max_new_tokens <= 1 or (req.eos_id is not None
-                                           and t == req.eos_id):
-                self.completed.append(req)     # done at the first token
-                continue
-            self._tokens[slot] = t
-            self._budget[slot] = req.max_new_tokens - 1
-            self._active[slot] = req
-
-    def _sample(self, logits: np.ndarray) -> np.ndarray:
-        return _sample_tokens(logits, self.cfg.temperature, self._rng)
-
-    # ------------------------------------------------------------------
-    def step(self) -> int:
-        """One engine step. Returns number of active slots.  Working steps
-        are counted in ``stats['engine_steps']`` as in ServeEngine."""
-        if self._active or self._queue:
-            self.stats["engine_steps"] += 1
-        self._admit()
-        if not self._active:
-            return 0
-        batch = {"token": jnp.asarray(self._tokens)}
-        logits, self.caches = self._decode(self.params, batch, self.caches)
-        tok = self._sample(np.asarray(logits))
-        done_slots = []
-        for slot, req in self._active.items():
-            t = int(tok[slot])
-            req.output.append(t)
-            self._budget[slot] -= 1
-            if self._budget[slot] <= 0 or (req.eos_id is not None
-                                           and t == req.eos_id):
-                done_slots.append(slot)
-            else:
-                self._tokens[slot] = t
-        for slot in done_slots:
-            self.completed.append(self._active.pop(slot))
-        return len(self._active)
-
-    def run_to_completion(self, max_steps: int = 10_000) -> list[Request]:
-        """Step until every submitted request drained (or max_steps)."""
-        for _ in range(max_steps):
-            if self.step() == 0 and not self._queue:
-                break
-        return self.completed
-
-
-# ===========================================================================
 # Reference decode (regression oracle)
 # ===========================================================================
 
@@ -1741,7 +1560,17 @@ def generate_sequential(model, params, prompt: np.ndarray, *,
     ``cache_dtype`` overrides the static cache element dtype — pass
     'float32' alongside EngineConfig.page_dtype='float32' so oracle and
     engine store identical values on both sides of the comparison."""
-    prefill, decode = _static_fns(model)
+    # jitted once per model (prefill re-traces per prompt length)
+    if not hasattr(model, "_static_step_fns"):
+        def static_prefill(p, b, c):
+            return model.prefill(p, b, c)
+
+        def static_decode(p, b, c):
+            return model.decode(p, b, c)
+
+        model._static_step_fns = (jax.jit(static_prefill),
+                                  jax.jit(static_decode))
+    prefill, decode = model._static_step_fns
     kw = {} if cache_dtype is None else {"dtype": jnp.dtype(cache_dtype)}
     caches = model.init_caches(1, max_len, **kw)
     logits, caches = prefill(

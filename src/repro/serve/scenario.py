@@ -1,16 +1,14 @@
-"""Small paged-attention state builders shared by tests and benchmarks.
+"""Small paged-attention state builders shared by the tests.
 
 ``make_paged_attention_state`` drives the REAL chunked-prefill path
 (``models/attention.chunk_prefill_paged``) to populate a multi-slot page
 pool with ragged per-slot lengths — the canonical fixture for fused-vs-
-gather parity checks (tests/test_parity.py) and the interpret-mode kernel
-smoke in benchmarks/fig6_paged_decode.py, so both always exercise the same
-state layout.
+gather parity checks (tests/test_parity.py, tests/test_quant_pool.py).
 
 ``overcommit_workload`` builds the forced-preemption serving scenario for
-benchmarks/fig7_preemption.py and the scheduler tests: a mixed-length
-(prompt, max_new) work list plus a page-pool size deliberately below the
-workload's worst-case concurrent page demand by an ``overcommit`` factor.
+the scheduler tests: a mixed-length (prompt, max_new) work list plus a
+page-pool size deliberately below the workload's worst-case concurrent
+page demand by an ``overcommit`` factor.
 """
 from __future__ import annotations
 
@@ -31,14 +29,12 @@ def overcommit_workload(*, max_slots: int, page_size: int,
     (prompt_len, max_new_tokens) pairs (feed to ``make_mixed_requests``)
     and ``num_pages`` sizes the engine pool (including the trash page) so
     that ``max_slots`` concurrent requests need ~overcommit x the usable
-    pages — guaranteeing the optimistic scheduler preempts while the
-    conservative baseline serializes admission."""
+    pages — guaranteeing the scheduler preempts."""
     rng = np.random.default_rng(seed)
     work = []
     for _ in range(n_requests):
         # decode-heavy mix: sub-page prompts, 2-4 pages of decode — page
-        # demand grows lazily during decode, which is exactly the regime
-        # where conservative worst-case reservation idles the pool hardest
+        # demand grows lazily during decode
         n_prompt = int(rng.integers(6, page_size))
         max_new = int(rng.integers(2, 5)) * page_size
         work.append((n_prompt, max_new))

@@ -1,6 +1,7 @@
 """Cross-family serving identity matrix: the paged ServeEngine must be
 token-identical to ``generate_sequential`` for every cache family it
-serves — MLA latent pages (deepseek_v2_lite), recurrent state checkpoints
+serves — MLA latent pages (deepseek_v2_lite), attention K/V pages beside
+an expert FFN (llama4_maverick_400b), recurrent state checkpoints
 (xlstm_350m) and hybrid attention+SSM stacks (hymba_1_5b) — under mixed
 batching, late joiners, slot recycling, forced preemption (swap-out /
 swap-in and recompute-replay) and n-gram speculative decoding.
@@ -8,16 +9,17 @@ swap-in and recompute-replay) and n-gram speculative decoding.
 Exactness knobs per family (the engine itself runs identically without
 them; they only make the *oracle comparison* exact):
 
-- sla2-mechanism families (deepseek, hymba) run at ``k_frac=1.0`` and
-  ``quant_bits='none'``: the paged MLA/attention prefill is exact dense
-  over the slot's pages (the sparse/linear split applies to decode), so
-  token identity to the static sla2 prefill requires the routed mask to
-  cover everything (then alpha is auto-forced to 1 on the empty
-  complement).  Static sla2 prompt lengths must divide block_q=32.
-- deepseek serves through the dropless expert layer (models/moe.
-  moe_layer: no capacity, so chunked prefill, batched decode and the
+- sla2-mechanism families (deepseek, llama4, hymba) run at
+  ``k_frac=1.0`` and ``quant_bits='none'``: the paged MLA/attention
+  prefill is exact dense over the slot's pages (the sparse/linear split
+  applies to decode), so token identity to the static sla2 prefill
+  requires the routed mask to cover everything (then alpha is
+  auto-forced to 1 on the empty complement).  Static sla2 prompt lengths
+  must divide block_q=32.
+- deepseek and llama4 serve through the dropless expert layer (models/
+  moe.moe_layer: no capacity, so chunked prefill, batched decode and the
   static oracle's calls route alike; the smoke preset's
-  ``capacity_factor`` is training's only) and additionally needs a
+  ``capacity_factor`` is training's only) and additionally need a
   float32 page pool (EngineConfig.page_dtype + generate_sequential
   cache_dtype): the MoE gates amplify bf16 page rounding into expert
   flips.
@@ -46,6 +48,12 @@ FAMILIES = {
             k_frac=1.0, quant_bits="none",
             moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=32,
                           num_shared=2, capacity_factor=8.0)),
+        engine_kw=dict(page_dtype="float32"),
+        oracle_kw=dict(cache_dtype="float32"),
+        lengths=(32, 64, 32, 32), squeeze_pages=8),
+    "moe": dict(
+        arch="llama4_maverick_400b",
+        overrides=dict(k_frac=1.0, quant_bits="none"),
         engine_kw=dict(page_dtype="float32"),
         oracle_kw=dict(cache_dtype="float32"),
         lengths=(32, 64, 32, 32), squeeze_pages=8),

@@ -1,12 +1,13 @@
 """The paged layer scan (``models/transformer._paged_stack``) over the
 stacked pool it carries.
 
-MLA layers read and write the carried stack in place at the scan's layer
-index; every other kind gets its layer's slice and writes it back
-(``_mix_stacked``).  Checked on the CPU at smoke size: the traced scan body
-holds no slice of one layer's MLA pool (and still holds one for a dense
-pool), and the in-place MLA programs give logits and pools bit-identical
-to a Python loop over the layers on hand-sliced caches.
+MLA layers (an ``in_place`` mixer) read and write the carried stack in
+place at the scan's layer index; every other mixer gets its layer's slice
+and writes it back (``_mix_stacked``).  Checked on the CPU at smoke size:
+the traced scan body holds no slice of one layer's MLA pool (and still
+holds one for a dense pool), and the in-place MLA programs give logits
+and pools bit-identical to a Python loop over the layers on hand-sliced
+caches.
 """
 import jax
 import jax.numpy as jnp
@@ -138,14 +139,14 @@ def test_scan_body_slices_no_mla_pool(request, stack, program):
         assert shapes.count(per_layer) >= 2, (per_layer, shapes)
 
 
-def _sliced_stack(params, cfg, x, caches, mix_fn, rows=None):
+def _sliced_stack(params, cfg, x, caches, rows, op, **kw):
     """The layer stack as a Python loop over the layers, each mixer on its
     own hand-sliced params and cache: the semantics the scan keeps."""
     caches, total = dict(caches), None
     new_pref = []
     for i, kind in enumerate(cfg.first_kinds):
         x, lc, n = T._layer_paged(params["prefix_layers"][i], cfg, kind, x,
-                                  caches["prefix_layers"][i], mix_fn, rows)
+                                  caches["prefix_layers"][i], rows, op, kw)
         new_pref.append(lc)
         total = T._add_counters(total, n)
     if cfg.first_kinds:
@@ -157,7 +158,7 @@ def _sliced_stack(params, cfg, x, caches, mix_fn, rows=None):
         new = {}
         for i, kind in enumerate(cfg.layer_kinds):
             x, new[f"l{i}"], n = T._layer_paged(gp[f"l{i}"], cfg, kind, x,
-                                                gc[f"l{i}"], mix_fn, rows)
+                                                gc[f"l{i}"], rows, op, kw)
             total = T._add_counters(total, n)
         groups.append(new)
     caches["groups"] = jax.tree.map(lambda *a: jnp.stack(a), *groups)

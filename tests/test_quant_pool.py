@@ -261,9 +261,9 @@ def test_quantized_swap_roundtrip_bit_exact():
 
 
 def test_engine_stats_telemetry():
-    """stats carries the pool-pressure and swap telemetry the benchmarks
-    consume: swap_bytes tracks SwapPool.used_bytes, pool_peak_pages the
-    allocator high-water mark."""
+    """stats carries the pool-pressure and swap telemetry: swap_bytes
+    tracks SwapPool.used_bytes, pool_peak_pages the allocator high-water
+    mark."""
     from repro.serve.scenario import overcommit_workload
 
     model, params = _smoke_model()

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.serve import (EngineConfig, PageAllocator, Request, ServeEngine,
-                        StaticWaveEngine, generate_sequential)
+                         generate_sequential)
 
 MAX_LEN = 192
 MAX_NEW = 8
@@ -64,19 +64,16 @@ def test_sla2_batching_is_output_invariant(qwen3_smoke, qwen3_params,
 
 def test_small_page_pool_defers_admission(full_attn_smoke, make_prompts,
                                           serve_mixed):
-    """Conservative admission with a pool too small for all requests at
-    once waits for pages to free instead of deadlocking (never preempts);
-    outputs stay exact.  (The optimistic default on the same pool is
-    covered by tests/test_preemption.py.)"""
+    """Admission with a pool too small for all requests at once defers
+    (or preempts) instead of deadlocking; outputs stay exact and every
+    usable page comes back free."""
     cfg, model, params = full_attn_smoke
     prompts = make_prompts(cfg, [20, 30, 25, 40], seed=2)
     ref = [generate_sequential(model, params, p, max_new_tokens=MAX_NEW,
                                max_len=MAX_LEN) for p in prompts]
     # worst case per request is ceil((40+8)/16)=3 pages; pool of 7 usable
     # pages can hold at most two such requests concurrently
-    out, eng = serve_mixed(model, params, prompts, max_slots=4, num_pages=8,
-                           admission="conservative")
-    assert eng.stats["preemptions"] == 0
+    out, eng = serve_mixed(model, params, prompts, max_slots=4, num_pages=8)
     for i in range(len(prompts)):
         assert out[i] == ref[i]
     assert eng.allocator.available == 7
@@ -119,24 +116,6 @@ def test_eos_frees_slot_early(full_attn_smoke, make_prompts):
     done = eng.run_to_completion(max_steps=200)
     assert done[0].output == ref[:3]
     assert eng.allocator.available == eng.allocator.num_pages - 1
-
-
-def test_static_wave_engine_still_serves(qwen3_smoke, qwen3_params):
-    """The retired wave engine stays importable and functional as the
-    benchmark BASELINE only (benchmarks/fig12_serving.py) — no serving hot
-    path constructs it; every LM family goes through the paged
-    ServeEngine."""
-    cfg, model = qwen3_smoke
-    eng = StaticWaveEngine(model, EngineConfig(max_slots=2, max_len=128))
-    eng.load(qwen3_params)
-    reqs = [Request(uid=i, prompt=np.arange(1, 7, dtype=np.int32),
-                    max_new_tokens=5) for i in range(3)]
-    for r in reqs:
-        eng.submit(r)
-    eng.run_to_completion(max_steps=100)
-    for r in reqs:
-        assert len(r.output) == 5
-        assert all(0 <= t < cfg.vocab_size for t in r.output)
 
 
 def test_fused_and_gather_paged_paths_agree_in_engine(qwen3_smoke,

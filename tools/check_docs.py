@@ -15,7 +15,7 @@ via tests/test_docs.py; the fourth runs in the CI docs job):
      docstring (ast-based, no imports needed).
   4. --run — actually execute the cheap commands the docs promise: every
      command line in a bash block matching the RUNNABLE allowlist
-     (pytest --collect-only, benchmark --smoke, gen_path_matrix --check)
+     (pytest --collect-only, serving --smoke, gen_path_matrix --check)
      is run from the repo root with PYTHONPATH=src and must exit 0 — so
      the docs/paths.md support matrix failing --check fails the docs job.
 

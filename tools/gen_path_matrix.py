@@ -8,10 +8,10 @@ The matrix is DERIVED, not hand-written, so it cannot drift from the code:
     gather oracle per cell;
   * ``models/attention.AUTO_GATHER_BACKENDS`` + ``resolve_paged_impl`` —
     the ``paged_impl='auto'`` resolution rule;
-  * ``models/transformer.PAGED_KINDS`` / ``LAYER_CACHE_KINDS`` /
-    ``KIND_CACHE_KEY`` — every LM layer kind's paged cache kind (K/V
-    pages, MLA latent pages, recurrent state checkpoints, hybrid
-    composites) and the cache key its leaves live under;
+  * ``models/transformer.LAYER_KINDS`` — every LM layer kind's mixer:
+    its paged cache kind (K/V pages, MLA latent pages, recurrent state
+    checkpoints, hybrid composites), the cache key its leaves live under
+    and whether it keeps per-slot state;
   * ``serve/engine.EngineConfig`` — which speculative drafters exist and
     what they require (probed by constructing the drafters' gates);
   * ``models/dit.MECHANISM_ATTENTION`` + ``serve/diffusion.ATTN_IMPLS`` —
@@ -42,9 +42,6 @@ END = "<!-- END GENERATED path-matrix -->"
 MECHANISMS = ("full", "sla2", "sla", "sparse_only")
 PHASE_LABEL = {"prefill": "chunked prefill", "decode": "decode",
                "verify": "verify window"}
-# layer kinds a ModelConfig can carry (transformer.py's vocabulary)
-LAYER_KINDS = ("dense", "moe", "mla_dense", "mla_moe", "hybrid", "mlstm",
-               "slstm")
 
 
 def generate() -> str:
@@ -133,24 +130,20 @@ def generate() -> str:
     lines += [
         "### Layer kinds (paged cache geometry)",
         "",
-        "Derived from `models/transformer.LAYER_CACHE_KINDS` / "
-        "`KIND_CACHE_KEY` / `PAGED_KINDS`: every LM layer kind serves "
-        "through the paged `ServeEngine` — attention layers page K/V, "
-        "MLA layers page the compressed latent, recurrent mixers keep "
-        "per-slot state checkpoints behind the same swap/prefix-cache "
-        "plumbing, hybrids compose both. `StaticWaveEngine` is retired "
-        "to a benchmark baseline (`benchmarks/fig5_e2e_latency.py`, "
-        "`fig6_paged_decode.py`, `fig9_dense_paged.py`).",
+        "Derived from `models/transformer.LAYER_KINDS`, the one table of "
+        "layer kinds: every LM layer kind serves through the paged "
+        "`ServeEngine` — attention layers page K/V, MLA layers page the "
+        "compressed latent, recurrent mixers keep per-slot state "
+        "checkpoints behind the same swap/prefix-cache plumbing, hybrids "
+        "compose both.",
         "",
         "| layer kind | paged cache kind | cache key | per-slot state |",
         "|---|---|---|---|",
     ]
-    for kind in LAYER_KINDS:
-        assert kind in T.PAGED_KINDS, f"{kind} lost its paged path"
-        state = "yes" if kind in T._STATE_KINDS else "sla2 totals only"
+    for kind, (mixer, _) in T.LAYER_KINDS.items():
+        state = "yes" if mixer.slot_state else "sla2 totals only"
         lines.append(
-            f"| `{kind}` | {T.LAYER_CACHE_KINDS[kind]} | "
-            f"`{T.KIND_CACHE_KEY[kind]}` | {state} |")
+            f"| `{kind}` | {mixer.cache_kind} | `{mixer.key}` | {state} |")
 
     # --- speculative drafters -------------------------------------------
     # import the drafters so a rename/removal breaks --check loudly
